@@ -19,11 +19,12 @@ from repro.core import ExchangeConfig, HSSConfig, hss_sort
 from repro.core.tagging import pack_tagged
 from repro.data.distributions import make_adversarial
 from repro.sort import SortSpec, sort as api_sort
+from repro.parallel.compat import make_mesh
 
 
 def run(n_per: int = 65536, eps: float = 0.05):
     p = min(8, len(jax.devices()))
-    mesh = jax.make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+    mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
     n = p * n_per
     rng = np.random.default_rng(1)
     raw = rng.permutation(n).astype(np.int32)  # distinct keys, 19 bits @ 8x64k

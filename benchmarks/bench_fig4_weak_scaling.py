@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from benchmarks.common import timeit
 from repro.core import (ExchangeConfig, HSSConfig, ams_sort, hss_sort,
                         sample_sort)
+from repro.parallel.compat import make_mesh
 
 
 def run(n_per: int = 65536, eps: float = 0.05):
@@ -20,7 +21,7 @@ def run(n_per: int = 65536, eps: float = 0.05):
     for p in (2, 4, 8):
         if p > len(jax.devices()):
             continue
-        mesh = jax.make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+        mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
         x = jnp.asarray(rng.permutation(p * n_per).astype(np.int32))
 
         us_h = timeit(lambda x=x, m=mesh: hss_sort(

@@ -16,6 +16,7 @@ from repro.core import ExchangeConfig, HSSConfig, hss_sort
 from repro.core.tagging import pack_tagged
 from repro.data.distributions import (ADVERSARIAL, DISTRIBUTIONS,
                                       make_adversarial, make_distribution)
+from repro.parallel.compat import make_mesh
 
 
 def _tagged_row(label, keys, *, p, n_per, mesh, eps):
@@ -43,7 +44,7 @@ def _tagged_row(label, keys, *, p, n_per, mesh, eps):
 def run(n_per: int = 32768, eps: float = 0.05):
     rows = []
     p = min(8, len(jax.devices()))
-    mesh = jax.make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+    mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
     n = p * n_per
     for name in sorted(DISTRIBUTIONS):
         # 12-bit keys leave room for the 18 tag bits in int32 packing

@@ -13,11 +13,11 @@ from benchmarks.common import timeit
 from repro.core import HSSConfig
 from repro.core.splitters import hss_splitters
 from repro.core import simulator as sim
-from repro.parallel.compat import shard_map
+from repro.parallel.compat import make_mesh, shard_map
 
 
 def _splitter_time(p: int, n_per: int, eps: float) -> float:
-    mesh = jax.make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+    mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
     rng = np.random.default_rng(0)
     xs = jnp.sort(jnp.asarray(
         rng.permutation(p * n_per).astype(np.int32)).reshape(p, n_per), axis=1)

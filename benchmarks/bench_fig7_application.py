@@ -13,11 +13,12 @@ import jax.numpy as jnp
 
 from benchmarks.common import timeit
 from repro.core import ExchangeConfig, HSSConfig, hss_sort
+from repro.parallel.compat import make_mesh
 
 
 def run(n_per: int = 32768, eps: float = 0.05, steps: int = 4):
     p = min(8, len(jax.devices()))
-    mesh = jax.make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+    mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
     n = p * n_per
     rng = np.random.default_rng(5)
     x = rng.permutation(n * 8)[:n].astype(np.int32)

@@ -13,12 +13,13 @@ from benchmarks.common import timeit
 from repro.configs import smoke_config
 from repro.models.moe import moe_ffn
 from repro.parallel.ctx import ParallelCtx
+from repro.parallel.compat import make_mesh
 
 
 def run():
     rows = []
     p = min(8, len(jax.devices()))
-    mesh = jax.make_mesh((1, p), ("data", "model"))
+    mesh = make_mesh((1, p), ("data", "model"))
     cfg = smoke_config("phi3.5-moe-42b-a6.6b")
     cfg = dataclasses.replace(cfg, n_experts=8, top_k=2, d_model=128,
                               d_ff_expert=256)

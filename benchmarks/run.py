@@ -5,7 +5,8 @@
 Prints ``name,us_per_call,derived`` CSV rows (None time => analytic bench).
 
 A parallel-sorting paper's benches need shards: ask XLA for 8 host devices
-(NOT the dry-run's 512 — that stays in launch/dryrun.py's own process).
+(NOT the dry-run's 512 — that stays in launch/dryrun.py's own process, and
+`benchmarks.bench_sort_collectives` runs alone for the same reason).
 """
 from __future__ import annotations
 
@@ -38,7 +39,6 @@ BENCHES = [
     ("sort", "benchmarks.bench_sort"),
     ("serve", "benchmarks.bench_serve"),
     ("moe", "benchmarks.bench_moe_dispatch"),
-    ("sortcoll", "benchmarks.bench_sort_collectives"),
     ("roofline", "benchmarks.roofline"),
 ]
 
@@ -63,6 +63,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for key, module in BENCHES:

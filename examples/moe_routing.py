@@ -24,9 +24,10 @@ from repro.configs import smoke_config
 from repro.models.moe import moe_ffn
 from repro.parallel.ctx import ParallelCtx
 from repro.sort import SortSpec, sort
+from repro.parallel.compat import make_mesh
 
 p = min(8, len(jax.devices()))
-mesh = jax.make_mesh((1, p), ("data", "model"))
+mesh = make_mesh((1, p), ("data", "model"))
 ctx = ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")
 
 cfg = dataclasses.replace(smoke_config("phi3.5-moe-42b-a6.6b"),

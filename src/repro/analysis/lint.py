@@ -21,30 +21,26 @@ import argparse
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 
 REQUIRED_DEVICES = 8
-_REEXEC_FLAG = "REPRO_ANALYSIS_REEXEC"
 
 
-def _ensure_devices() -> None:
-    """shard_map programs need p=8 devices even to *trace*; re-exec with
-    forced host devices when the interpreter started without them."""
+def _use_host_devices() -> None:
+    """The lint is a CPU-only tool: it traces (and, for the purity audits,
+    runs) the program matrix on REQUIRED_DEVICES simulated host devices.
+    Pin the CPU platform and the device count before the backend starts,
+    in this process — an accelerator on the machine stays untouched."""
     import jax
-    if jax.device_count() >= REQUIRED_DEVICES:
-        return
-    if os.environ.get(_REEXEC_FLAG):
-        print(f"repro.analysis.lint: {REQUIRED_DEVICES} devices required, "
-              f"have {jax.device_count()} even after re-exec", file=sys.stderr)
-        sys.exit(2)
-    env = dict(os.environ)
     flag = f"--xla_force_host_platform_device_count={REQUIRED_DEVICES}"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
-    env[_REEXEC_FLAG] = "1"
-    sys.exit(subprocess.call(
-        [sys.executable, "-m", "repro.analysis.lint", *sys.argv[1:]],
-        env=env))
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
+    jax.config.update("jax_platforms", "cpu")
+    if jax.device_count() < REQUIRED_DEVICES:
+        print(f"repro.analysis.lint: {REQUIRED_DEVICES} host devices "
+              f"required, have {jax.device_count()} (the backend started "
+              "before the lint could size it)", file=sys.stderr)
+        sys.exit(2)
 
 
 ALGOS = ("hss", "sample_random", "sample_regular", "ams")
@@ -81,18 +77,12 @@ def run_contracts(results) -> None:
     from repro.analysis import comms, contracts
     from repro.analysis.contracts import CommsContract
     from repro.analysis.programs import (
-        available_exchanges, make_topk_program, partitioner_program,
-        splitters_program)
+        make_topk_program, partitioner_program, splitters_program)
     from repro.core.exchange import (
         BATCH_FUSED_STRATEGIES, EXCHANGE_COLLECTIVES)
     from repro.sort.partitioners import MULTISTAGE_BASE_COLLECTIVES
 
-    exchanges = available_exchanges()
-    skipped = [s for s in EXCHANGE_COLLECTIVES if s not in exchanges]
-    if skipped:
-        print(f"  note: exchange strategies skipped (primitive unavailable "
-              f"in this jax): {skipped}")
-        results["skipped_exchanges"] = skipped
+    exchanges = tuple(EXCHANGE_COLLECTIVES)
 
     print("contracts: splitter phase")
     for algo in ALGOS:
@@ -276,7 +266,7 @@ def main(argv=None) -> int:
                     help="trace-only: skip execution-based purity audits")
     args = ap.parse_args(argv)
 
-    _ensure_devices()
+    _use_host_devices()
 
     import jax
 

@@ -8,40 +8,30 @@ builds them exactly the way `repro.sort.driver` does: same compat
 shard_map wrapper, same in/out specs, same mesh factoring (multistage gets
 its 2-D mesh from `Partitioner.mesh_axes`).
 
-Tracing happens on whatever platform runs the lint; strategies whose
-primitives do not exist in the installed jax (`ragged_all_to_all` predates
-jax 0.4.37's lax surface) are reported by :func:`available_exchanges`.
+Tracing happens on whatever platform runs the lint: it needs no backend
+support, so even the `ragged` exchange, which only XLA:TPU compiles, is
+traced on the CPU.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import jax.random as jr
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
+from repro.parallel.compat import make_mesh, shard_map
 from repro.sort.partitioners import ShardCtx, get_partitioner
 from repro.sort.spec import SortSpec
 
 __all__ = [
-    "available_exchanges",
     "partitioner_program",
     "splitters_program",
     "make_topk_program",
 ]
-
-
-def available_exchanges() -> Tuple[str, ...]:
-    """Exchange strategies traceable on the installed jax. The ragged
-    strategy needs `jax.lax.ragged_all_to_all` (TPU toolchains)."""
-    out = ["dense", "dense_spill", "allgather"]
-    if hasattr(jax.lax, "ragged_all_to_all"):
-        out.insert(2, "ragged")
-    return tuple(out)
 
 
 def _mesh_for(part, spec: SortSpec, p: int):
@@ -49,7 +39,7 @@ def _mesh_for(part, spec: SortSpec, p: int):
     names = tuple(a for a, _ in axes)
     sizes = tuple(s for _, s in axes)
     assert math.prod(sizes) == p, (axes, p)
-    return jax.make_mesh(sizes, names), names, sizes
+    return make_mesh(sizes, names), names, sizes
 
 
 def partitioner_program(algo: str, *, exchange: str = "dense",

@@ -1,19 +1,23 @@
 """Static VMEM budgets for the three Pallas kernel families.
 
 The kernel block/tile constants are justified by the DESIGN.md Section 2.5
-math ("a VMEM pair-merge of runs of length R holds 2R keys plus double
-buffering: 4*2R*itemsize"); this module *evaluates* that math for a
-candidate configuration against a per-platform budget, so an oversized
-block fails at lint time with the arithmetic in the message instead of at
-Mosaic compile time (or, worse, only on hardware).
+math; this module *evaluates* that math for a candidate configuration
+against a per-platform budget, so an oversized block fails at lint time
+with the arithmetic in the message instead of at Mosaic compile time (or,
+worse, only on hardware).
 
-Footprints model the per-grid-step VMEM residency of each kernel:
+Footprints model the per-grid-step VMEM residency of each kernel (B keys
+of w bytes per block):
 
-bitonic block sort   one block of B keys, double buffered      2*B*w
-VMEM pair merge      a 2R-key pair, double buffered            4*2R*w
-HBM strided pass     a (2, cols) tile, double buffered         2*2*cols*w
-probe histogram      (T,) keys + (M,) probes + (T, M) int32
-                     compare matrix + (M,) int32 accumulator
+network pass         in+out blocks double buffered (4*B*w), six live
+(block sort, VMEM    key temporaries per compare-exchange step — the
+ merge)              block, two rolled copies, partner, min, max
+                     (6*B*w) — and two int32 index planes (2*B*4)
+HBM strided pass     a pair of cols-key tiles, in+out double buffered
+                     (8*cols*w), plus min and max (2*cols*w)
+probe histogram      (T,) key tile double buffered (2*T*w), the (M, 128)
+                     probe block and (M, 128) int32 accumulator, each
+                     double buffered, and two (M, 128) temporaries
 
 All sizes are rounded up to the platform's native tile (8x128 lanes on
 TPU) before costing, the way Mosaic lays them out.
@@ -33,7 +37,7 @@ __all__ = [
     "KernelFootprint",
     "vmem_budget_bytes",
     "block_sort_footprint",
-    "pair_merge_footprint",
+    "merge_block_footprint",
     "hbm_pass_footprint",
     "histogram_footprint",
     "check_kernel_budgets",
@@ -85,47 +89,48 @@ class KernelFootprint:
         return dataclasses.asdict(self)
 
 
+def _network_footprint(family: str, block: int,
+                       itemsize: int) -> KernelFootprint:
+    b = _tiled(block)
+    return KernelFootprint(
+        family=family, config=f"block={block},w={itemsize}",
+        vmem_bytes=10 * b * itemsize + 2 * b * 4,
+        formula=f"10*{b}*{itemsize} + 2*{b}*4")
+
+
 def block_sort_footprint(block: int, itemsize: int = 4) -> KernelFootprint:
-    """One bitonic sort block resident, double buffered: 2*B*w."""
-    nbytes = 2 * _tiled(block) * itemsize
-    return KernelFootprint(
-        family="bitonic_sort", config=f"block={block},w={itemsize}",
-        vmem_bytes=nbytes, formula=f"2*{_tiled(block)}*{itemsize}")
+    """The bitonic block sort: one network pass over B-key blocks."""
+    return _network_footprint("bitonic_sort", block, itemsize)
 
 
-def pair_merge_footprint(run: int, itemsize: int = 4) -> KernelFootprint:
-    """VMEM pair merge of runs of length R: 2R keys, in+out double
-    buffered — the DESIGN.md 4*2R*w term."""
-    nbytes = 4 * _tiled(2 * run) * itemsize
-    return KernelFootprint(
-        family="merge", config=f"run={run},w={itemsize}",
-        vmem_bytes=nbytes, formula=f"4*{_tiled(2 * run)}*{itemsize}")
+def merge_block_footprint(block: int, itemsize: int = 4) -> KernelFootprint:
+    """The VMEM merge stages: one network pass over B-key blocks."""
+    return _network_footprint("merge", block, itemsize)
 
 
 def hbm_pass_footprint(cols: int, itemsize: int = 4) -> KernelFootprint:
-    """Strided HBM pass: a (2, cols) tile, in+out double buffered."""
-    cols_t = -(-cols // TILE_LANES) * TILE_LANES
-    rows_t = TILE_SUBLANES   # the (2, cols) tile pads sublanes to 8
-    nbytes = 2 * rows_t * cols_t * itemsize   # padded tile, in + out
+    """Strided HBM pass: a pair of cols-key tiles in and out, double
+    buffered, plus min and max."""
+    c = _tiled(cols)
     return KernelFootprint(
         family="merge", config=f"hbm_pass,cols={cols},w={itemsize}",
-        vmem_bytes=nbytes, formula=f"2*{rows_t}*{cols_t}*{itemsize}")
+        vmem_bytes=10 * c * itemsize, formula=f"10*{c}*{itemsize}")
 
 
 def histogram_footprint(tile: int, m: int, itemsize: int = 4,
                         ) -> KernelFootprint:
-    """Probe-rank step: (T,) keys + (M,) probes + (T, M) int32 compare
-    matrix + (M,) int32 accumulator."""
-    t_t, m_t = _tiled(tile), _tiled(m)
-    nbytes = (t_t * itemsize          # key tile
-              + m_t * itemsize        # probe vector
-              + tile * m_t * 4        # comparison matrix (int32)
-              + m_t * 4)              # output accumulator
+    """Probe-rank step: key tile + (M, 128) probes, accumulator and two
+    temporaries."""
+    t_t = _tiled(tile)
+    plane = -(-m // TILE_SUBLANES) * TILE_SUBLANES * TILE_LANES
+    nbytes = (2 * t_t * itemsize         # key tile, double buffered
+              + 2 * plane * itemsize     # probe block, double buffered
+              + 2 * plane * 4            # int32 accumulator, double buffered
+              + 2 * plane * 4)           # compare + sum temporaries
     return KernelFootprint(
         family="histogram", config=f"tile={tile},m={m},w={itemsize}",
         vmem_bytes=nbytes,
-        formula=f"{t_t}*{itemsize} + {m_t}*{itemsize} + {tile}*{m_t}*4 "
-                f"+ {m_t}*4")
+        formula=f"2*{t_t}*{itemsize} + 2*{plane}*{itemsize} + 4*{plane}*4")
 
 
 def default_footprints(p: int = 256, itemsize: int = 4,
@@ -138,7 +143,7 @@ def default_footprints(p: int = 256, itemsize: int = 4,
     """
     return (
         block_sort_footprint(bitonic_ops.DEFAULT_BLOCK, itemsize),
-        pair_merge_footprint(bitonic_ops.MAX_RUN // 2, itemsize),
+        merge_block_footprint(bitonic_ops.MAX_RUN, itemsize),
         hbm_pass_footprint(merge_kernel.DEFAULT_COLS, itemsize),
         histogram_footprint(histogram_ops.DEFAULT_TILE, int(p), itemsize),
     )

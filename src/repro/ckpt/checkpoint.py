@@ -27,12 +27,10 @@ import threading
 import jax
 import numpy as np
 
-from repro.parallel.compat import tree_flatten_with_path
-
 
 def _flat(tree):
     flat = {}
-    for path, leaf in tree_flatten_with_path(tree)[0]:
+    for path, leaf in jax.tree.flatten_with_path(tree)[0]:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
         flat[key] = leaf

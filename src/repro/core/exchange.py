@@ -14,9 +14,9 @@ Four strategies, selected by `ExchangeConfig.strategy` (DESIGN.md Section 2):
             dropped keys. This is the `SortSpec(on_overflow="spill")`
             trace; CPU-compilable (no ragged opcode needed), overflow can
             only come from receive-side truncation.
-  ragged    jax.lax.ragged_all_to_all — exact alltoallv. XLA:TPU only (the CPU
-            ThunkEmitter lacks the opcode as of jax 0.8.2), so it is the
-            production path on hardware but excluded from CPU tests/dry-run.
+  ragged    jax.lax.ragged_all_to_all — exact alltoallv. XLA:TPU only (XLA:CPU
+            lacks the opcode), so it is the production path on hardware but
+            excluded from CPU tests/dry-run.
   allgather exact and simple: gather everything, keep own range. O(N) per
             shard; for tests, tiny meshes, and final intra-stage sorts.
 
@@ -49,8 +49,8 @@ from repro.core.common import hi_sentinel, round_up
 #: fused away by construction) + spill payload/count all_gathers + one
 #: truncation psum; allgather: payload + counts all_gather + truncation
 #: psum; ragged: counts + offsets all_to_all around one ragged_all_to_all
-#: (TPU-only — the lint can only trace it on toolchains that have the
-#: primitive). The batched variants fuse the same collectives across B
+#: (only XLA:TPU compiles it; the lint traces it anywhere). The batched
+#: variants fuse the same collectives across B
 #: for dense/allgather (B-invariant, also proven by the lint);
 #: dense_spill_batched and ragged_batched run per-row loops (documented
 #: above) and are exempt from batch invariance.
@@ -108,7 +108,7 @@ class ExchangeConfig:
 
 
 def _cap_to(merged, out_cap):
-    """Slice/pad a merged run to the static output capacity."""
+    """Slice/pad merged runs (last axis) to the static output capacity."""
     from repro.kernels.merge.ops import cap_to
     return cap_to(merged, out_cap)
 
@@ -276,36 +276,68 @@ def exchange_allgather(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
     return vals, n_out - trunc, jax.lax.psum(trunc, axis_name)
 
 
+#: Keys per row of the ragged exchange's send and receive buffers.
+RAGGED_ROW = 128
+
+
+def _exclusive_cumsum(x):
+    return jnp.cumsum(x) - x
+
+
+def row_aligned_chunks(local_sorted, starts, counts, rows: int):
+    """Restage the destination slices of a sorted shard so that each starts
+    on a row of RAGGED_ROW keys: returns the (rows, RAGGED_ROW) staging
+    buffer (slice d from row `row_starts[d]`, sentinel-filled up to the
+    next row) and each slice's row offset and row count."""
+    n = local_sorted.shape[0]
+    row_counts = -(-counts // RAGGED_ROW)
+    row_starts = _exclusive_cumsum(row_counts)
+    pos = jnp.arange(rows * RAGGED_ROW, dtype=jnp.int32)
+    src = jnp.full(pos.shape, -1, jnp.int32)
+    for d in range(starts.shape[0]):
+        first = row_starts[d] * RAGGED_ROW
+        mine = (pos >= first) & (pos < first + counts[d])
+        src = jnp.where(mine, pos - first + starts[d], src)
+    staged = jnp.where(src >= 0, local_sorted[jnp.clip(src, 0, n - 1)],
+                       hi_sentinel(local_sorted.dtype))
+    return staged.reshape(rows, RAGGED_ROW), row_starts, row_counts
+
+
 def exchange_ragged(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
                     n_valid=None):
-    """Exact alltoallv via jax.lax.ragged_all_to_all. TPU-only (see module doc)."""
+    """Exact alltoallv via jax.lax.ragged_all_to_all. TPU-only (see module doc).
+
+    XLA:TPU lays a 1-D ragged operand out as one key per 128-lane row, so
+    keys travel in rows of RAGGED_ROW: each destination slice is restaged
+    to start on a row, and each received run starts on one too."""
     n = local_sorted.shape[0]
     out_cap = cfg.out_cap(n, p, eps)
     sent_hi = hi_sentinel(local_sorted.dtype)
 
     starts, counts = destination_slices(local_sorted, splitter_keys, n_valid)
+    staged, send_starts, send_rows = row_aligned_chunks(
+        local_sorted, starts, counts, -(-n // RAGGED_ROW) + p)
     # recv_counts[s] = how many keys I receive from source s.
     recv_counts = jax.lax.all_to_all(
         counts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
-    recv_offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(recv_counts)[:-1].astype(jnp.int32)])
-    # send_offsets[d] = offset within destination d's buffer of my chunk.
+    recv_rows = -(-recv_counts // RAGGED_ROW)
+    recv_starts = _exclusive_cumsum(recv_rows)
+    # send_offsets[d] = row within destination d's buffer of my chunk.
     send_offsets = jax.lax.all_to_all(
-        recv_offsets.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
-    out = jnp.full((out_cap,), sent_hi, local_sorted.dtype)
+        recv_starts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
+    out = jnp.full((-(-out_cap // RAGGED_ROW) + p, RAGGED_ROW), sent_hi,
+                   local_sorted.dtype)
     out = jax.lax.ragged_all_to_all(
-        local_sorted, out,
-        starts.astype(jnp.int64), counts.astype(jnp.int64),
-        send_offsets.astype(jnp.int64), recv_counts.astype(jnp.int64),
+        staged, out, send_starts, send_rows, send_offsets, recv_rows,
         axis_name=axis_name)
     n_valid = jnp.sum(recv_counts)
     # p sorted runs at known (traced) offsets: k-way merge, with the
     # in-kernel full-sort fallback if a run overflows the static slot.
     dispatch, _ = _kernels()
     out = dispatch.merge_ragged(
-        out, recv_offsets, recv_counts, policy=cfg.kernel_policy,
-        slot=cfg.ragged_slot(n, p, eps))
-    return out, n_valid, jnp.zeros((), jnp.int32)
+        out.reshape(-1), recv_starts * RAGGED_ROW, recv_counts,
+        policy=cfg.kernel_policy, slot=cfg.ragged_slot(n, p, eps))
+    return out[:out_cap], n_valid, jnp.zeros((), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -324,11 +356,6 @@ def exchange_ragged(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
 # Per-request results are bit-identical to the unbatched strategy run on
 # that request's row.
 # ---------------------------------------------------------------------------
-
-
-def _cap_rows_to(merged, out_cap):
-    from repro.kernels.merge.ops import _cap_rows_to as f
-    return f(merged, out_cap)
 
 
 def _rows_valid(n_valid, b, n):
@@ -371,7 +398,7 @@ def exchange_dense_batched(local_sorted, splitter_keys, *, axis_name, p, cfg,
 
     dispatch, _ = _kernels()
     merged = dispatch.merge_runs_batched(recv, policy=cfg.kernel_policy)
-    out = _cap_rows_to(merged, out_cap)
+    out = _cap_to(merged, out_cap)
     n_recv = jnp.sum(recv_counts, axis=1)                # (B,)
     trunc = jnp.maximum(n_recv - out_cap, 0)
     overflow = overflow + jax.lax.psum(trunc, axis_name)
@@ -407,7 +434,7 @@ def exchange_allgather_batched(local_sorted, splitter_keys, *, axis_name, p,
     runs = jax.vmap(gather_runs, in_axes=(0, 0, 0, None))(
         flat, flat_starts, counts.T, n)                        # (B, p, n)
     merged = dispatch.merge_runs_batched(runs, policy=cfg.kernel_policy)
-    vals = _cap_rows_to(merged, out_cap)
+    vals = _cap_to(merged, out_cap)
     trunc = jnp.maximum(n_out - out_cap, 0)
     return vals, n_out - trunc, jax.lax.psum(trunc, axis_name)
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.parallel.compat import make_mesh
 from repro.sort import SortSpec, sort
 
 
@@ -38,8 +39,8 @@ def bucket_lengths(lengths: np.ndarray, n_shards: int, eps: float = 0.05,
     import jax
     if n_shards > len(jax.devices()):
         raise ValueError(f"n_shards={n_shards} > {len(jax.devices())} devices")
-    mesh = jax.make_mesh((n_shards,), ("sort",),
-                         devices=jax.devices()[:n_shards])
+    mesh = make_mesh((n_shards,), ("sort",),
+                     devices=jax.devices()[:n_shards])
     spec = dataclasses.replace(
         spec or SortSpec(algorithm="hss", eps=eps, exchange="allgather"),
         seed=seed, mesh=mesh, stable=True)

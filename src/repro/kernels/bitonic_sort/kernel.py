@@ -1,145 +1,101 @@
-"""Bitonic sort/merge Pallas kernels.
+"""Bitonic sorting-network Pallas kernel.
 
-Sorting networks are the TPU-native local sort: compare-exchange distances are
-static, control flow is data-independent (the VPU has no divergence penalty to
-pay and every step is a full-width vector min/max), and blocks stream
-HBM -> VMEM tile by tile via BlockSpec. A block of B keys costs
-O(B log^2 B) compares across log B stages; blocks are then pairwise-merged
-(one bitonic half-cleaner cascade per pass) until the shard is one sorted run.
+Sorting networks are the TPU-native local sort: compare-exchange distances
+are static, control flow is data-independent (every step is a full-width
+vector min/max), and blocks stream HBM -> VMEM tile by tile via BlockSpec.
 
-Layout note: refs are (B,) logical; Mosaic relayouts to (8,128) vregs. The
-compare-exchange at distance d is expressed as a (B/2d, 2, d) reshape so every
-step is two strided vector loads + min/max + interleave, which lowers to
-sublane/lane shuffles for d < 128 and to vreg moves above.
+Network. The bitonic sort of a row of L keys runs merge stages
+k = 2, 4, ..., L; stage k runs compare-exchange steps at distances
+d = k/2, ..., 1, and the pair (i, i+d) is ordered ascending iff
+(i & k) == 0. After stage k the row is a sequence of sorted runs of length
+k whose directions alternate (run j ascending iff j is even), which is
+exactly the bitonic input stage 2k needs — so the cascade of merges that
+follows a block sort needs no reversal. The last stage (k = L) orders the
+whole row ascending. `network_pass` runs any contiguous slice of those
+steps over independent VMEM blocks; `repro.kernels.merge` runs the steps
+whose distance exceeds a block as strided HBM passes.
+
+Layout. A flat key array is viewed as (n / lanes, lanes), lanes =
+min(block, 128), so a block of B >= 1024 keys is a (B/128, 128) tile
+stack. The partner at distance d is read with `pltpu.roll`: along lanes
+for d < lanes, along sublanes (rows) above. Each element selects the
+rolled copy holding its partner (i+d for the low element of a pair, i-d
+for the high one) and keeps the min or the max.
+
+Rows. Several independent rows of `row_len` keys (a power of two) may
+share one flat array — a request batch. Directions are computed from the
+index within the row, so the last stage orders each row ascending and no
+comparator crosses a row boundary as long as blocks divide rows.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
 
 
-def _compare_exchange(x: jax.Array, d: int, k: int) -> jax.Array:
-    """One network step: sort pairs (i, i+d) ascending iff (i & k) == 0."""
-    b = x.shape[0]
-    y = x.reshape(b // (2 * d), 2, d)
-    lo, hi = y[:, 0, :], y[:, 1, :]
-    mn = jnp.minimum(lo, hi)
-    mx = jnp.maximum(lo, hi)
-    row = jax.lax.broadcasted_iota(jnp.int32, (b // (2 * d), 1), 0)
-    asc = ((row * (2 * d)) & k) == 0
-    new_lo = jnp.where(asc, mn, mx)
-    new_hi = jnp.where(asc, mx, mn)
-    return jnp.stack([new_lo, new_hi], axis=1).reshape(b)
+def stages(k_from: int, k_to: int) -> tuple:
+    """The (k, d) compare-exchange steps of merge stages k_from .. k_to."""
+    steps = []
+    k = k_from
+    while k <= k_to:
+        d = k // 2
+        while d >= 1:
+            steps.append((k, d))
+            d //= 2
+        k *= 2
+    return tuple(steps)
 
 
-def bitonic_sort_network(x: jax.Array) -> jax.Array:
-    """Full bitonic sort of a power-of-two 1-D array (trace-time unrolled)."""
-    b = x.shape[0]
-    log_b = b.bit_length() - 1
-    assert 1 << log_b == b, f"block size {b} must be a power of two"
-    for m in range(log_b):
-        k = 1 << (m + 1)
-        for d_exp in range(m, -1, -1):
-            x = _compare_exchange(x, 1 << d_exp, k)
-    return x
+def _compare_exchange(x, pos, idx, k: int, d: int):
+    """One network step over a (rows, lanes) block: pos is the index within
+    the block, idx the index within the row (direction bit k)."""
+    rows, lanes = x.shape
+    if d < lanes:
+        axis, shift, size = 1, d, lanes
+    else:
+        axis, shift, size = 0, d // lanes, rows
+    up = pltpu.roll(x, size - shift, axis)     # x[i + d]
+    down = pltpu.roll(x, shift, axis)          # x[i - d]
+    low = (pos & d) == 0
+    partner = jnp.where(low, up, down)
+    keep_min = low == ((idx & k) == 0)
+    return jnp.where(keep_min, jnp.minimum(x, partner),
+                     jnp.maximum(x, partner))
 
 
-def bitonic_merge_network(x: jax.Array) -> jax.Array:
-    """Merge a bitonic sequence (= two sorted halves, 2nd reversed) ascending."""
-    b = x.shape[0]
-    log_b = b.bit_length() - 1
-    assert 1 << log_b == b
-    for d_exp in range(log_b - 1, -1, -1):
-        # k larger than b => every pair ascending
-        x = _compare_exchange(x, 1 << d_exp, 2 * b)
-    return x
-
-
-def _sort_block_kernel(x_ref, o_ref):
-    o_ref[...] = bitonic_sort_network(x_ref[...])
-
-
-def _merge_pair_kernel(x_ref, o_ref):
+def _network_kernel(x_ref, o_ref, *, steps, row_len):
+    rows, lanes = x_ref.shape
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+    pos = r * lanes + c
+    idx = (pl.program_id(0) * (rows * lanes) + pos) & (row_len - 1)
     x = x_ref[...]
-    b = x.shape[0]
-    half = b // 2
-    bitonic = jnp.concatenate([x[:half], x[half:][::-1]])
-    o_ref[...] = bitonic_merge_network(bitonic)
+    for k, d in steps:
+        x = _compare_exchange(x, pos, idx, k, d)
+    o_ref[...] = x
 
 
-def _sort_block_row_kernel(x_ref, o_ref):
-    o_ref[...] = bitonic_sort_network(x_ref[0])[None]
-
-
-def _merge_pair_row_kernel(x_ref, o_ref):
-    x = x_ref[0]
-    half = x.shape[0] // 2
-    bitonic = jnp.concatenate([x[:half], x[half:][::-1]])
-    o_ref[...] = bitonic_merge_network(bitonic)[None]
-
-
-def sort_blocks(x: jax.Array, block: int, *, interpret: bool) -> jax.Array:
-    """Sort each contiguous `block`-sized run of x independently."""
+def network_pass(x: jax.Array, *, block: int, steps: tuple, row_len: int,
+                 interpret: bool) -> jax.Array:
+    """Run `steps` on every aligned `block` of the flat array x, whose
+    rows hold `row_len` keys. Every step's distance must be < block."""
     n = x.shape[0]
-    assert n % block == 0
-    grid = (n // block,)
-    return pl.pallas_call(
-        _sort_block_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+    assert n % block == 0 and row_len % block == 0, (n, block, row_len)
+    assert all(d < block for _, d in steps), (block, steps)
+    lanes = min(block, LANES)
+    tile = (block // lanes, lanes)
+    out = pl.pallas_call(
+        functools.partial(_network_kernel, steps=steps, row_len=row_len),
+        grid=(n // block,),
+        in_specs=[pl.BlockSpec(tile, lambda i: (i, 0))],
+        out_specs=pl.BlockSpec(tile, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // lanes, lanes), x.dtype),
         interpret=interpret,
-    )(x)
-
-
-def sort_blocks_batched(x: jax.Array, block: int, *,
-                        interpret: bool) -> jax.Array:
-    """Sort each `block`-sized run of each row of a (B, n) array.
-
-    One launch for the whole batch: the grid grows a leading batch
-    dimension (B, n // block) instead of issuing B kernel calls.
-    """
-    b, n = x.shape
-    assert n % block == 0, (n, block)
-    return pl.pallas_call(
-        _sort_block_row_kernel,
-        grid=(b, n // block),
-        in_specs=[pl.BlockSpec((1, block), lambda r, i: (r, i))],
-        out_specs=pl.BlockSpec((1, block), lambda r, i: (r, i)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x)
-
-
-def merge_adjacent(x: jax.Array, run: int, *, interpret: bool) -> jax.Array:
-    """Merge adjacent sorted runs of length `run` into runs of 2*run."""
-    n = x.shape[0]
-    assert n % (2 * run) == 0
-    grid = (n // (2 * run),)
-    return pl.pallas_call(
-        _merge_pair_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((2 * run,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((2 * run,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x)
-
-
-def merge_adjacent_batched(x: jax.Array, run: int, *,
-                           interpret: bool) -> jax.Array:
-    """Per-row `merge_adjacent` of a (B, n) array in one launch (batch grid
-    dimension; runs never span rows because n % (2*run) == 0)."""
-    b, n = x.shape
-    assert n % (2 * run) == 0, (n, run)
-    return pl.pallas_call(
-        _merge_pair_row_kernel,
-        grid=(b, n // (2 * run)),
-        in_specs=[pl.BlockSpec((1, 2 * run), lambda r, i: (r, i))],
-        out_specs=pl.BlockSpec((1, 2 * run), lambda r, i: (r, i)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x)
+    )(x.reshape(n // lanes, lanes))
+    return out.reshape(n)

@@ -2,11 +2,12 @@
 
 rank[m] = #{local keys < probe[m]}. The paper does M binary searches per round
 (O(M log n) scalar work); on TPU a tiled comparison reduction is faster for
-the probe counts HSS produces (M = O(p) per round): each grid step loads a
-(T,) key tile + the full (M,) probe vector into VMEM and accumulates a
-(T x M) comparison matrix reduction into the (M,) output block — O(n*M/8/128)
-fully packed VPU ops with zero gather/scatter, and optionally routed through
-the MXU as a bf16 ones-vector matmul for the large-M regime.
+the probe counts HSS produces (M = O(p) per round). Keys arrive as a
+(rows, 128) tile per grid step; the probes arrive once, broadcast along
+lanes to an (M, 128) block. Each key row (1, 128) is compared against that
+block and the (M, 128) int32 accumulator — the output block, resident across
+the key-tile grid axis — gains one count per lane; the wrapper sums the 128
+lanes. O(n*M/1024) fully packed VPU ops with zero gather/scatter.
 
 The keys need NOT be sorted — the kernel counts, it does not search. Sentinel
 (+inf / int-max) padded keys never compare below a real probe, so capacity
@@ -18,70 +19,56 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+SUBLANES = 8
+
 
 def _probe_rank_kernel(keys_ref, probes_ref, out_ref):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    keys = keys_ref[...]      # (T,)
-    probes = probes_ref[...]  # (M,)
-    cmp = (keys[:, None] < probes[None, :])
-    out_ref[...] += jnp.sum(cmp.astype(jnp.int32), axis=0)
+    rows = keys_ref.shape[1]
+    group = min(SUBLANES, rows)
+    probes = probes_ref[0]                      # (M, 128)
+
+    def body(g, acc):
+        start = pl.multiple_of(g * group, group)
+        tile = keys_ref[0, pl.ds(start, group), :]   # (group, 128)
+        for j in range(group):
+            acc = acc + (tile[j:j + 1, :] < probes).astype(jnp.int32)
+        return acc
+
+    out_ref[0] = jax.lax.fori_loop(0, rows // group, body, out_ref[0])
 
 
 def probe_ranks_pallas(keys: jax.Array, probes: jax.Array, *, tile: int,
                        interpret: bool) -> jax.Array:
-    n, m = keys.shape[0], probes.shape[0]
-    assert n % tile == 0, (n, tile)
-    grid = (n // tile,)
-    return pl.pallas_call(
-        _probe_rank_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((m,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((m,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
-        interpret=interpret,
-    )(keys, probes)
-
-
-def _probe_rank_row_kernel(keys_ref, probes_ref, out_ref):
-    step = pl.program_id(1)
-
-    @pl.when(step == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    cmp = (keys_ref[0][:, None] < probes_ref[0][None, :])
-    out_ref[...] += jnp.sum(cmp.astype(jnp.int32), axis=0)[None]
-
-
-def probe_ranks_batched_pallas(keys: jax.Array, probes: jax.Array, *,
-                               tile: int, interpret: bool) -> jax.Array:
     """Per-row probe ranks of a (B, n) key batch against (B, M) probes.
 
-    One launch over a (B, n // tile) grid: the key-tile dimension iterates
-    fastest, so each row's (1, M) output block is revisited and accumulated
-    exactly as in the unbatched kernel, re-initialized when the tile index
-    wraps to 0 for the next row.
+    n % tile == 0, tile % 128 == 0, M % 8 == 0. One launch over a
+    (B, n // tile) grid: the key-tile axis iterates fastest, so each row's
+    (M, 128) accumulator is revisited, and re-initialized when the tile
+    index wraps to 0 for the next row. Returns (B, M) int32.
     """
     b, n = keys.shape
     m = probes.shape[1]
     assert probes.shape[0] == b, (keys.shape, probes.shape)
-    assert n % tile == 0, (n, tile)
-    return pl.pallas_call(
-        _probe_rank_row_kernel,
+    rows = tile // LANES
+    assert n % tile == 0 and tile % LANES == 0 and m % SUBLANES == 0, (
+        n, tile, m)
+    assert rows % min(SUBLANES, rows) == 0, tile
+    keys3 = keys.reshape(b, n // LANES, LANES)
+    probes3 = jnp.broadcast_to(probes[:, :, None], (b, m, LANES))
+    counts = pl.pallas_call(
+        _probe_rank_kernel,
         grid=(b, n // tile),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda r, i: (r, i)),
-            pl.BlockSpec((1, m), lambda r, i: (r, 0)),
+            pl.BlockSpec((1, rows, LANES), lambda r, i: (r, i, 0)),
+            pl.BlockSpec((1, m, LANES), lambda r, i: (r, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, m), lambda r, i: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.int32),
+        out_specs=pl.BlockSpec((1, m, LANES), lambda r, i: (r, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, m, LANES), jnp.int32),
         interpret=interpret,
-    )(keys, probes)
+    )(keys3, probes3)
+    return jnp.sum(counts, axis=2, dtype=jnp.int32)

@@ -1,24 +1,19 @@
-"""HBM-resident bitonic merge Pallas kernels.
+"""HBM-resident bitonic merge Pallas kernel.
 
-A bitonic merge of two sorted runs of length R is a fixed comparator
-network: relayout the pair into one bitonic sequence (second run reversed),
-then a half-cleaner cascade at distances R, R/2, ..., 1. `bitonic_sort`'s
-`merge_adjacent` executes the whole network with the 2R-key pair resident in
-VMEM, which caps R at MAX_RUN/2. This module splits the *same* network into
-shapes that stream through VMEM so the pair can stay in HBM:
+Merge stage k of the bitonic network (see `bitonic_sort.kernel`) runs
+compare-exchange steps at distances k/2, ..., 1. `network_pass` runs steps
+whose distance is below its VMEM block; this module runs the rest as
+strided passes so the pair of runs can stay in HBM:
 
-  strided_compare_exchange  one cascade step at distance d: the (n,) array
-                            viewed as (n/d, d) rows, where rows 2i/2i+1 are
-                            exactly the lo/hi elements d apart. Each grid
-                            step loads a (2, C) tile, writes min up / max
-                            down. O(n) HBM traffic per step, O(C) VMEM.
-  merge_bitonic_blocks      the cascade tail: once 2d <= block, every
-                            remaining comparator lands inside an aligned
-                            VMEM block, so one grid pass runs distances
-                            block/2 .. 1 to completion.
-  merge_pass_hbm            the full pass: bitonic relayout (one XLA flip,
-                            pure data movement) + strided steps while
-                            2d > vmem_block + the VMEM tail.
+  strided_compare_exchange  one step at distance d: the flat array viewed
+                            as (n/2d, 2, d/lanes, lanes), where [i, 0] and
+                            [i, 1] are the lo/hi elements d apart. Each
+                            grid step loads a (2, cols) pair of tiles and
+                            writes min/max in the direction of stage k.
+                            O(n) HBM traffic per step, O(cols) VMEM.
+  merge_level               the whole stage: strided steps while
+                            d >= vmem_block, then one `network_pass` over
+                            vmem_block-sized blocks for distances below.
 
 Correctness is a property of the comparator network, not of the chunking:
 these shapes execute exactly the comparators of the standard bitonic merge,
@@ -27,75 +22,69 @@ the `jnp.sort` oracle for any block sizes.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bitonic_sort.kernel import bitonic_merge_network
+from repro.kernels.bitonic_sort.kernel import LANES, network_pass
 
-# Column tile of a strided HBM pass: 2*DEFAULT_COLS keys of VMEM per grid
-# step (8 KiB at f32) — deliberately tiny so the pass coexists with whatever
-# else the surrounding program keeps resident.
+# Keys per half-tile of a strided HBM pass: a (2, 8, 128) pair of tiles
+# (8 KiB at 4 bytes) per grid step — deliberately tiny so the pass
+# coexists with whatever else the surrounding program keeps resident.
 DEFAULT_COLS = 1024
 
 
-def _strided_ce_kernel(x_ref, o_ref):
-    x = x_ref[...]                      # (2, C): row 0/1 are elements d apart
-    lo, hi = x[0:1, :], x[1:2, :]
-    o_ref[...] = jnp.concatenate(
-        [jnp.minimum(lo, hi), jnp.maximum(lo, hi)], axis=0)
+def _strided_ce_kernel(x_ref, o_ref, *, d: int, k: int, row_len: int):
+    lo, hi = x_ref[0, 0], x_ref[0, 1]   # elements d apart
+    mn, mx = jnp.minimum(lo, hi), jnp.maximum(lo, hi)
+    # the pair's index within its row decides the direction of stage k
+    asc = (((pl.program_id(0) * 2 * d) & (row_len - 1)) & k) == 0
+
+    @pl.when(asc)
+    def _ascending():
+        o_ref[0, 0] = mn
+        o_ref[0, 1] = mx
+
+    @pl.when(jnp.logical_not(asc))
+    def _descending():
+        o_ref[0, 0] = mx
+        o_ref[0, 1] = mn
 
 
-def strided_compare_exchange(x: jax.Array, d: int, *, cols: int,
-                             interpret: bool) -> jax.Array:
-    """One ascending compare-exchange step at distance `d` (d % cols == 0)."""
+def strided_compare_exchange(x: jax.Array, d: int, k: int, *, cols: int,
+                             row_len: int, interpret: bool) -> jax.Array:
+    """Step (k, d) of the network over the flat array x (d % cols == 0)."""
     n = x.shape[0]
-    assert n % (2 * d) == 0, (n, d)
-    assert d % cols == 0, (d, cols)
-    x2 = x.reshape(n // d, d)
+    assert n % (2 * d) == 0 and d % cols == 0, (n, d, cols)
+    lanes = min(cols, LANES)
+    x4 = x.reshape(n // (2 * d), 2, d // lanes, lanes)
+    tile = (1, 2, cols // lanes, lanes)
     out = pl.pallas_call(
-        _strided_ce_kernel,
+        functools.partial(_strided_ce_kernel, d=d, k=k, row_len=row_len),
         grid=(n // (2 * d), d // cols),
-        in_specs=[pl.BlockSpec((2, cols), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((2, cols), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        in_specs=[pl.BlockSpec(tile, lambda i, j: (i, 0, j, 0))],
+        out_specs=pl.BlockSpec(tile, lambda i, j: (i, 0, j, 0)),
+        out_shape=jax.ShapeDtypeStruct(x4.shape, x.dtype),
         interpret=interpret,
-    )(x2)
+    )(x4)
     return out.reshape(n)
 
 
-def _merge_block_kernel(x_ref, o_ref):
-    o_ref[...] = bitonic_merge_network(x_ref[...])
-
-
-def merge_bitonic_blocks(x: jax.Array, block: int, *,
-                         interpret: bool) -> jax.Array:
-    """Run the cascade at distances block/2 .. 1 within each aligned block."""
-    n = x.shape[0]
-    assert n % block == 0, (n, block)
-    return pl.pallas_call(
-        _merge_block_kernel,
-        grid=(n // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x)
-
-
-def merge_pass_hbm(x: jax.Array, run: int, *, vmem_block: int,
-                   cols: int = DEFAULT_COLS, interpret: bool) -> jax.Array:
-    """Merge adjacent sorted runs of length `run` (a power of two) into
-    sorted runs of 2*run, holding at most `vmem_block` keys in VMEM."""
-    n = x.shape[0]
-    assert n % (2 * run) == 0, (n, run)
-    assert run & (run - 1) == 0, run
-    x2 = x.reshape(-1, 2, run)
-    xb = jnp.concatenate(
-        [x2[:, 0, :], jnp.flip(x2[:, 1, :], axis=1)], axis=1).reshape(n)
+def merge_level(x: jax.Array, run: int, *, vmem_block: int, row_len: int,
+                cols: int = DEFAULT_COLS, interpret: bool) -> jax.Array:
+    """Merge stage k = 2*run: runs of length `run` in alternating directions
+    -> runs of 2*run, holding at most `vmem_block` keys in VMEM."""
+    k = 2 * run
     d = run
-    while 2 * d > vmem_block:
-        xb = strided_compare_exchange(xb, d, cols=min(d, cols),
-                                      interpret=interpret)
+    while d >= vmem_block:
+        x = strided_compare_exchange(x, d, k, cols=min(d, cols),
+                                     row_len=row_len, interpret=interpret)
         d //= 2
-    return merge_bitonic_blocks(xb, 2 * d, interpret=interpret)
+    steps = []
+    while d >= 1:
+        steps.append((k, d))
+        d //= 2
+    return network_pass(x, block=2 * steps[0][1], steps=tuple(steps),
+                        row_len=row_len, interpret=interpret)

@@ -8,16 +8,18 @@ merge_ragged_runs   runs at *traced* offsets/lengths inside a flat buffer,
 gather_runs         ragged runs -> static sentinel-padded (k, slot) buffer.
 *_batched           the same contracts with a leading request-batch axis,
                     one kernel launch per cascade pass for the whole batch
-                    (merge_cascade_rows; DESIGN.md Section 6.2).
+                    (rows laid end to end; DESIGN.md Section 6.2). The
+                    unbatched forms are batches of one.
 
 All merges are exact: given the documented layout (sorted runs, sentinel
 filled slack) and the core key contract (NaN-free, non-sentinel keys — a
 float NaN propagates through both min/max lanes of a comparator network;
 see repro.kernels.__init__) the output is bit-identical to `jnp.sort` over
-the same entries. k runs merge in log(k) levels of a pairwise bitonic-merge tree;
-each level is one streaming pass (VMEM pair-merge kernel while 2*run fits
-the VMEM budget, the HBM-resident strided pass above it), so the cascade
-never falls back to an XLA sort. The win is kernel residency and
+the same entries. k runs merge in log(k) stages of the bitonic network
+(odd runs reversed once, in XLA, so that every pair is bitonic): the
+stages that fit the VMEM budget in one kernel pass, each larger stage as
+HBM-resident strided passes plus a VMEM tail, so the cascade never falls
+back to an XLA sort. The win is kernel residency and
 full-width VPU compare-exchanges per pass — not comparator-count
 asymptotics: a bitonic merge tree is O(n log k log n) compares.
 """
@@ -29,46 +31,60 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.common import hi_sentinel, pow2_ceil
+from repro.kernels import from_kernel_keys, to_kernel_keys
 from repro.kernels import interpret_default as _interpret
 from repro.kernels.bitonic_sort import kernel as BK
 from repro.kernels.bitonic_sort import ops as bops
 from repro.kernels.merge import kernel as MK
 
 
-def merge_cascade(x, run: int, *, vmem_block: int, interpret: bool):
-    """Pairwise-merge tree: sorted runs of length `run` (pow2) -> one sorted
-    run. Also the tail of `bitonic_sort.ops.local_sort` — one cascade
-    implementation, whatever produced the runs."""
-    n = x.shape[0]
-    while run < n:
-        if 2 * run <= vmem_block:
-            x = BK.merge_adjacent(x, run, interpret=interpret)
-        else:
-            x = MK.merge_pass_hbm(x, run, vmem_block=vmem_block,
-                                  interpret=interpret)
-        run *= 2
-    return x
+def merge_cascade(x, run: int, *, vmem_block: int, row_len: int,
+                  interpret: bool):
+    """Pairwise-merge tree over the flat array x, whose rows hold `row_len`
+    keys (a power of two): each row a sequence of sorted runs of length
+    `run` in alternating directions (run j ascending iff j is even) -> each
+    row one ascending run. Also the tail of `bitonic_sort.ops.local_sort`
+    — one cascade implementation, whatever produced the runs.
 
-
-def merge_cascade_rows(x, run: int, *, vmem_block: int, interpret: bool):
-    """Per-row merge cascade of a (B, n) array, n a power of two: sorted
-    runs of length `run` in each row -> each row one sorted run.
-
-    The VMEM passes use the batched pair-merge kernel (batch grid
-    dimension); the HBM strided passes run on the flattened array — rows
-    are power-of-two length and the pass distance stays below the row
-    length, so no comparator ever crosses a row boundary. Either way every
-    pass covers all B rows in a single kernel launch.
+    All stages that fit one `vmem_block` run in a single VMEM pass; each
+    larger stage is one `merge_level` (strided HBM steps + a VMEM tail).
     """
-    b, n = x.shape
-    while run < n:
-        if 2 * run <= vmem_block:
-            x = BK.merge_adjacent_batched(x, run, interpret=interpret)
-        else:
-            x = MK.merge_pass_hbm(x.reshape(-1), run, vmem_block=vmem_block,
-                                  interpret=interpret).reshape(b, n)
+    blk = min(vmem_block, row_len)
+    if run < blk:
+        x = BK.network_pass(x, block=blk, steps=BK.stages(2 * run, blk),
+                            row_len=row_len, interpret=interpret)
+        run = blk
+    while run < row_len:
+        x = MK.merge_level(x, run, vmem_block=blk, row_len=row_len,
+                           interpret=interpret)
         run *= 2
     return x
+
+
+def _merge_rows(runs, vmem_block, interpret):
+    """(B, k, r) ascending rows -> (B, k*r) ascending."""
+    b, k, r = runs.shape
+    if k == 1:
+        return runs.reshape(b, r)
+    dtype = runs.dtype
+    runs = to_kernel_keys(runs)
+    sent = hi_sentinel(runs.dtype)
+    r2 = pow2_ceil(r)
+    k2 = max(pow2_ceil(k), bops.MIN_ROW // r2)
+    runs = jnp.concatenate(
+        [runs, jnp.full((b, k, r2 - r), sent, runs.dtype)], axis=2)
+    runs = jnp.concatenate(
+        [runs, jnp.full((b, k2 - k, r2), sent, runs.dtype)], axis=1)
+    # odd runs reversed, so that every pair of runs is bitonic
+    odd = jax.lax.broadcasted_iota(jnp.int32, runs.shape, 1) % 2 == 1
+    flat = jnp.where(odd, jnp.flip(runs, 2), runs).reshape(-1)
+    # Left free to fuse the flip into the cascade's first strided pass,
+    # XLA:TPU took over five minutes to compile a (4, 2^26) merge; apart,
+    # about two seconds.
+    flat = jax.lax.optimization_barrier(flat)
+    out = merge_cascade(flat, r2, vmem_block=vmem_block, row_len=k2 * r2,
+                        interpret=interpret)
+    return from_kernel_keys(out.reshape(b, k2 * r2)[:, :k * r], dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("vmem_block", "interpret"))
@@ -81,24 +97,8 @@ def merge_sorted_runs(runs, vmem_block: int | None = None,
     padded up to the next power internally and the pad is sliced back off —
     sentinels sort to the global tail, so the slice is exact.
     """
-    interpret = _interpret() if interpret is None else interpret
-    vmem_block = bops.MAX_RUN if vmem_block is None else vmem_block
-    k, r = runs.shape
-    if k * r == 0:
-        return jnp.zeros((k * r,), runs.dtype)
-    sent = hi_sentinel(runs.dtype)
-    k2, r2 = pow2_ceil(k), pow2_ceil(r)
-    if r2 != r:
-        runs = jnp.concatenate(
-            [runs, jnp.full((k, r2 - r), sent, runs.dtype)], axis=1)
-    if k2 != k:
-        runs = jnp.concatenate(
-            [runs, jnp.full((k2 - k, r2), sent, runs.dtype)], axis=0)
-    if k2 == 1:
-        return runs.reshape(-1)[:r]
-    out = merge_cascade(runs.reshape(-1), r2, vmem_block=vmem_block,
-                        interpret=interpret)
-    return out[:k * r]
+    return merge_sorted_runs_batched(runs[None], vmem_block=vmem_block,
+                                     interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("vmem_block", "interpret"))
@@ -106,28 +106,15 @@ def merge_sorted_runs_batched(runs, vmem_block: int | None = None,
                               interpret: bool | None = None):
     """Per-request k-way merge: (B, k, r) sorted rows -> (B, k*r) sorted.
 
-    The batched counterpart of `merge_sorted_runs` — one cascade over all B
-    requests per pass instead of B separate cascades. Rows/columns are
-    sentinel-padded to powers of two exactly as in the unbatched path.
+    The batched form of `merge_sorted_runs` — one cascade over all B
+    requests per pass instead of B separate cascades.
     """
     interpret = _interpret() if interpret is None else interpret
     vmem_block = bops.MAX_RUN if vmem_block is None else vmem_block
     b, k, r = runs.shape
     if k * r == 0:
         return jnp.zeros((b, k * r), runs.dtype)
-    sent = hi_sentinel(runs.dtype)
-    k2, r2 = pow2_ceil(k), pow2_ceil(r)
-    if r2 != r:
-        runs = jnp.concatenate(
-            [runs, jnp.full((b, k, r2 - r), sent, runs.dtype)], axis=2)
-    if k2 != k:
-        runs = jnp.concatenate(
-            [runs, jnp.full((b, k2 - k, r2), sent, runs.dtype)], axis=1)
-    if k2 == 1:
-        return runs.reshape(b, -1)[:, :r]
-    out = merge_cascade_rows(runs.reshape(b, k2 * r2), r2,
-                             vmem_block=vmem_block, interpret=interpret)
-    return out[:, :k * r]
+    return _merge_rows(runs, vmem_block, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("run", "vmem_block", "interpret"))
@@ -138,15 +125,6 @@ def merge_flat_runs(x, run: int, vmem_block: int | None = None,
     assert n % run == 0, (n, run)
     return merge_sorted_runs(x.reshape(n // run, run), vmem_block=vmem_block,
                              interpret=interpret)
-
-
-def cap_to(merged, cap: int):
-    """Slice/pad a sorted run to a static capacity (sentinel-filled tail)."""
-    if merged.shape[0] >= cap:
-        return merged[:cap]
-    return jnp.concatenate(
-        [merged, jnp.full((cap - merged.shape[0],),
-                          hi_sentinel(merged.dtype), merged.dtype)])
 
 
 def gather_runs(buf, starts, counts, slot: int):
@@ -178,33 +156,20 @@ def merge_ragged_runs(buf, starts, counts, slot: int | None = None,
     full-sort fallback via lax.cond — still exact, still kernel-resident.
     slot=None uses the provably sufficient bound (the whole buffer).
     """
-    interpret = _interpret() if interpret is None else interpret
-    cap = buf.shape[0]
-    slot = pow2_ceil(cap if slot is None else min(slot, cap))
-
-    def merge_path(b):
-        runs = gather_runs(b, starts, counts, slot)
-        merged = merge_sorted_runs(runs, vmem_block=vmem_block,
-                                   interpret=interpret)
-        return cap_to(merged, cap)
-
-    if slot >= cap:          # slot provably fits every run
-        return merge_path(buf)
-    spill = jnp.any(jnp.asarray(counts, jnp.int32) > slot)
-    return jax.lax.cond(
-        spill,
-        lambda b: bops.local_sort(b, interpret=interpret),
-        merge_path, buf)
+    return merge_ragged_runs_batched(
+        buf[None], jnp.asarray(starts)[None], jnp.asarray(counts)[None],
+        slot=slot, vmem_block=vmem_block, interpret=interpret)[0]
 
 
-def _cap_rows_to(merged, cap: int):
-    """Per-row `cap_to`: slice/pad the trailing axis to a static capacity."""
-    b, n = merged.shape
+def cap_to(merged, cap: int):
+    """Slice/pad the trailing axis of sorted runs to a static capacity
+    (sentinel-filled tail)."""
+    n = merged.shape[-1]
     if n >= cap:
-        return merged[:, :cap]
-    return jnp.concatenate(
-        [merged, jnp.full((b, cap - n), hi_sentinel(merged.dtype),
-                          merged.dtype)], axis=1)
+        return merged[..., :cap]
+    pad = jnp.full(merged.shape[:-1] + (cap - n,), hi_sentinel(merged.dtype),
+                   merged.dtype)
+    return jnp.concatenate([merged, pad], axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("slot", "vmem_block", "interpret"))
@@ -225,7 +190,7 @@ def merge_ragged_runs_batched(buf, starts, counts, slot: int | None = None,
             bufs, starts, counts, slot)
         merged = merge_sorted_runs_batched(runs, vmem_block=vmem_block,
                                            interpret=interpret)
-        return _cap_rows_to(merged, cap)
+        return cap_to(merged, cap)
 
     if slot >= cap:
         return merge_path(buf)

@@ -11,6 +11,7 @@ import jax
 
 from repro.models.config import ArchConfig
 from repro.parallel.ctx import ParallelCtx
+from repro.parallel.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False, devices=None):
@@ -21,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False, devices=None):
         devices = jax.devices()[:n]
     import numpy as np
     dev = np.asarray(devices).reshape(shape)
-    return jax.make_mesh(shape, axes, devices=dev.reshape(-1))
+    return make_mesh(shape, axes, devices=dev.reshape(-1))
 
 
 def make_ctx(cfg: ArchConfig, mesh, *, multi_pod: bool = False) -> ParallelCtx:

@@ -27,12 +27,13 @@ from repro.optim import make_optimizer
 from repro.optim.schedule import cosine_schedule
 from repro.parallel.ctx import ParallelCtx
 from repro.runtime.ft import TrainSupervisor
+from repro.parallel.compat import make_mesh
 
 
 def host_mesh_ctx(cfg):
     """Mesh over whatever devices exist (tests/CPU): (data, model=1)."""
     n = len(jax.devices())
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh((n, 1), ("data", "model"))
     return ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                        shard_heads=cfg.heads_shardable(1))
 

@@ -1,43 +1,28 @@
-"""jax API compatibility shims shared by every shard_map call site.
+"""The two jax constructors every sharded call site shares.
 
-`jax.shard_map` graduated out of `jax.experimental.shard_map` only in newer
-jax releases (and renamed `check_rep` to `check_vma` on the way). The repo
-supports both: every call site routes through `shard_map` below instead of
-touching `jax.shard_map` directly, so the same code runs on the pinned CI
-jax and on current TPU toolchains.
+`make_mesh` builds meshes with Auto axes. `jax.make_mesh` defaults to
+Explicit axes, under which the sort's shard bodies (scatters and reshapes
+of sharded operands), `with_sharding_constraint` and shard_map closures
+over sharded inputs are rejected; every mesh in the repo is built here.
 
-The `jax.tree` aliases grew over several releases too: 0.4.37 has
-`jax.tree.flatten`/`map` but not `flatten_with_path`/`map_with_path`, which
-only exist under `jax.tree_util` there. The checkpoint code
-(repro.ckpt.checkpoint) routes its path-aware traversals through the
-`tree_*` shims below so one code path serves both toolchains.
+`shard_map` is `jax.shard_map` with the replication check off: shard
+bodies return per-shard results that the out_specs declare replicated
+(splitters, stats) after a collective made them so.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def tree_flatten_with_path(tree):
-    """`jax.tree.flatten_with_path` where available, tree_util elsewhere."""
-    if hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree)
-    from jax.tree_util import tree_flatten_with_path as _fwp
-    return _fwp(tree)
-
-
-def tree_map_with_path(f, tree, *rest):
-    """`jax.tree.map_with_path` where available, tree_util elsewhere."""
-    if hasattr(jax.tree, "map_with_path"):
-        return jax.tree.map_with_path(f, tree, *rest)
-    from jax.tree_util import tree_map_with_path as _mwp
-    return _mwp(f, tree, *rest)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """`jax.make_mesh` with every axis Auto."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map(..., check_vma=False)` on new jax, experimental on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """`jax.shard_map(..., check_vma=False)`."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -20,6 +20,8 @@ from typing import Any
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro.parallel.compat import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class ParallelCtx:
@@ -71,6 +73,5 @@ class ParallelCtx:
 def local_ctx() -> ParallelCtx:
     """1-device ctx for unit tests: named axes exist but have size 1, so every
     collective and constraint is a well-formed no-op."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     return ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")

@@ -229,9 +229,15 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     import jax
-    if jax.default_backend() == "cpu" and jax.device_count() == 1:
-        # the p == 1 driver short-circuit serves correct results but
-        # bypasses the executable cache — batching buys nothing there
+
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
+    dev = jax.devices()[0]
+    print(f"devices: {len(jax.devices())} x {dev.platform} "
+          f"({dev.device_kind})")
+    if dev.platform == "cpu" and jax.device_count() == 1:
+        # one chip is a deployment (every request sorts on it, p == 1);
+        # one CPU device is a rehearsal that lost its simulated devices
         print("warning: single CPU device (jax read XLA_FLAGS before it "
               "was set?) — run `python -m repro.serve.http`, or export "
               "XLA_FLAGS=--xla_force_host_platform_device_count=8")

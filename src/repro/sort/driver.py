@@ -41,7 +41,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.common import hi_sentinel, lo_sentinel
-from repro.parallel.compat import shard_map
+from repro.parallel.compat import make_mesh, shard_map
 
 
 class MeshPlan(NamedTuple):
@@ -171,7 +171,7 @@ def resolve_mesh(mesh, axis_names, sizes=None) -> MeshPlan:
         sizes = (p,)
     if int(np.prod(sizes)) != p:
         raise ValueError(f"mesh sizes {sizes} != {p} devices")
-    mesh = jax.make_mesh(tuple(sizes), axis_names, devices=devices)
+    mesh = make_mesh(tuple(sizes), axis_names, devices=devices)
     return MeshPlan(mesh, axis_names, tuple(sizes), p)
 
 

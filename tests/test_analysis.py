@@ -16,7 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.analysis import comms, contracts, jaxpr_walk, purity, vmem
 from repro.analysis.contracts import CommsContract
-from repro.parallel.compat import shard_map
+from repro.parallel.compat import make_mesh, shard_map
 from repro.sort import SortSpec, sort
 from repro.sort.semisort import semisort
 
@@ -27,7 +27,7 @@ AXIS, P_SHARDS, N_LOCAL = "sort", 8, 128
 
 def _trace(body, *, batch=None):
     """Trace a toy per-shard body under shard_map, driver-style."""
-    mesh = jax.make_mesh((P_SHARDS,), (AXIS,))
+    mesh = make_mesh((P_SHARDS,), (AXIS,))
     f = shard_map(body, mesh=mesh, in_specs=(P(AXIS),), out_specs=P(AXIS))
     shape = ((P_SHARDS, N_LOCAL) if batch is None
              else (batch, P_SHARDS, N_LOCAL))
@@ -174,7 +174,7 @@ def test_batch_invariance_fires_on_b_dependent_psum():
             for i in range(b):                       # one psum per request
                 out = out.at[i].add(jax.lax.psum(xs[i], AXIS))
             return out
-        mesh = jax.make_mesh((P_SHARDS,), (AXIS,))
+        mesh = make_mesh((P_SHARDS,), (AXIS,))
         f = shard_map(body, mesh=mesh, in_specs=(P(None, AXIS),),
                       out_specs=P(None, AXIS))
         return f, (jax.ShapeDtypeStruct((b, P_SHARDS, N_LOCAL), jnp.int32),)
@@ -237,7 +237,7 @@ def test_vmem_budget_fires_on_oversized_block():
     with pytest.raises(vmem.VmemBudgetError) as e:
         vmem.block_sort_footprint(1 << 22, itemsize=4).check("tpu")
     # the failure message shows the arithmetic and the budget
-    assert "2*4194304*4" in str(e.value)
+    assert "10*4194304*4 + 2*4194304*4" in str(e.value)
     assert str(vmem.vmem_budget_bytes("tpu")) in str(e.value)
 
 

@@ -8,6 +8,7 @@ import pytest
 from repro.models.layers import (attention_chunked, attention_full,
                                  attention_seqpar)
 from repro.parallel.ctx import ParallelCtx
+from repro.parallel.compat import make_mesh
 
 B, S, HQ, HKV, D = 2, 64, 6, 2, 16
 
@@ -56,7 +57,7 @@ def test_flash_sliding_window(rng):
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_seqpar_matches_full(rng, causal):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                       shard_heads=False)
     q, k, v = _qkv(rng)
@@ -67,7 +68,7 @@ def test_seqpar_matches_full(rng, causal):
 
 
 def test_seqpar_grads_match_full(rng):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                       shard_heads=False)
     q, k, v = _qkv(rng)

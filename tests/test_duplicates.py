@@ -69,6 +69,7 @@ def test_float_corner_encodings_totally_ordered():
 
 from repro.sort import grouping
 from repro.sort.grouping import counting_dispatch, grouping_permutation
+from repro.parallel.compat import make_mesh
 
 
 def _dispatch_np(ids, n_groups, capacity, method):
@@ -128,7 +129,7 @@ def _moe_smoke(rng, capacity_factor):
     cfg = dc.replace(smoke_config("phi3.5-moe-42b-a6.6b"),
                      n_experts=8, d_model=64, d_ff_expert=96,
                      moe_capacity_factor=capacity_factor)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")
     d, E, f = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
     params = {

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import (ExchangeConfig, HSSConfig, gather_sorted, hss_sort)
+from repro.parallel.compat import make_mesh
 
 
 def check_sorted(x, res, eps, exact=True):
@@ -97,14 +98,14 @@ def test_hss_warm_start_reduces_rounds(rng):
 def test_hss_two_devices(rng):
     n = 2 * 512
     x = rng.permutation(n).astype(np.int32)
-    mesh = jax.make_mesh((2,), ("sort",), devices=jax.devices()[:2])
+    mesh = make_mesh((2,), ("sort",), devices=jax.devices()[:2])
     res = hss_sort(jnp.asarray(x), mesh=mesh)
     check_sorted(x, res, 0.05)
 
 
 def test_hss_single_device(rng):
     x = rng.permutation(256).astype(np.int32)
-    mesh = jax.make_mesh((1,), ("sort",), devices=jax.devices()[:1])
+    mesh = make_mesh((1,), ("sort",), devices=jax.devices()[:1])
     res = hss_sort(jnp.asarray(x), mesh=mesh)
     np.testing.assert_array_equal(np.asarray(res.shards[0]), np.sort(x))
 
@@ -115,3 +116,33 @@ def test_overflow_reported_when_capacity_too_small(rng):
     res = hss_sort(jnp.asarray(x), hss_cfg=HSSConfig(eps=0.05),
                    ex_cfg=ExchangeConfig(pair_factor=1.0))
     assert int(res.overflow) > 0  # detected, not silent
+
+
+@pytest.mark.parametrize("n_valid", [None, 700])
+def test_ragged_row_aligned_chunks(rng, n_valid):
+    """The ragged exchange's staging: each destination slice starts on a
+    row of RAGGED_ROW keys and nothing else but sentinels is sent."""
+    from repro.core.exchange import (RAGGED_ROW, destination_slices,
+                                     row_aligned_chunks)
+    n, p = 1000, 4
+    local = np.sort(rng.integers(0, 10**6, n).astype(np.int32))
+    splitters = np.sort(rng.choice(local[:600], p - 1, replace=False))
+    starts, counts = destination_slices(jnp.asarray(local),
+                                        jnp.asarray(splitters), n_valid)
+    rows = -(-n // RAGGED_ROW) + p
+    staged, row_starts, row_counts = row_aligned_chunks(
+        jnp.asarray(local), starts, counts, rows)
+    assert staged.shape == (rows, RAGGED_ROW)
+    flat = np.asarray(staged).reshape(-1)
+    starts, counts, row_starts, row_counts = map(
+        np.asarray, (starts, counts, row_starts, row_counts))
+    np.testing.assert_array_equal(row_counts, -(-counts // RAGGED_ROW))
+    sent = np.zeros(flat.shape, bool)
+    for d in range(p):
+        lo = row_starts[d] * RAGGED_ROW
+        assert row_starts[d] + row_counts[d] <= rows
+        np.testing.assert_array_equal(
+            flat[lo:lo + counts[d]], local[starts[d]:starts[d] + counts[d]])
+        sent[lo:lo + counts[d]] = True
+    assert np.all(flat[~sent] == np.iinfo(np.int32).max)
+    assert sent.sum() == (n if n_valid is None else n_valid)

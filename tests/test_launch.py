@@ -56,8 +56,9 @@ def test_input_specs_shardable():
     """batch_specs/decode_specs stay consistent with a small mesh."""
     from repro.configs import SHAPES, smoke_config
     from repro.launch.specs import batch_specs, decode_specs
+    from repro.parallel.compat import make_mesh
     from repro.parallel.ctx import ParallelCtx
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = smoke_config("granite-34b")
     ctx = ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")
     bs, bsh = batch_specs(cfg, SHAPES["train_4k"], ctx)

@@ -84,8 +84,9 @@ def test_merge_pass_hbm_matches_vmem_network(rng, vmem_block, cols):
     run = 512
     x = np.sort(_keys(rng, 8 * run, np.float32).reshape(-1, run),
                 axis=1).reshape(-1)
-    got = mk.merge_pass_hbm(jnp.asarray(x), run, vmem_block=vmem_block,
-                            cols=cols, interpret=True)
+    pairs = bops._flip_odd_runs(jnp.asarray(x), run)   # bitonic pairs
+    got = mk.merge_level(pairs, run, vmem_block=vmem_block, row_len=2 * run,
+                         cols=cols, interpret=True)
     want = np.sort(x.reshape(-1, 2 * run), axis=1).reshape(-1)
     np.testing.assert_array_equal(np.asarray(got), want)
 

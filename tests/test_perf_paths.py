@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from repro.configs import smoke_config
 from repro.models.moe import moe_ffn
 from repro.parallel.ctx import ParallelCtx
+from repro.parallel.compat import make_mesh
 
 
 def _moe_setup(rng, cfg):
@@ -23,7 +24,7 @@ def _moe_setup(rng, cfg):
 
 
 def _ctx(p=8):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     return ParallelCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")
 
 

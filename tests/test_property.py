@@ -18,6 +18,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import ExchangeConfig, HSSConfig, gather_sorted, hss_sort
 from repro.core.common import interval_union_size
+from repro.parallel.compat import make_mesh
 
 
 @st.composite
@@ -49,7 +50,7 @@ def key_arrays(draw):
 def test_sort_invariants(arr, eps):
     import jax
     p, n_local, x = arr
-    mesh = jax.make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+    mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
     res = hss_sort(jnp.asarray(x), mesh=mesh, hss_cfg=HSSConfig(eps=eps),
                    ex_cfg=ExchangeConfig(strategy="allgather"))
     g = gather_sorted(res)

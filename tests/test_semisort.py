@@ -14,7 +14,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental import enable_x64
 
 from repro.sort import (GROUPBY_OPS, SortSpec, bucket_key, groupby_aggregate,
                         semisort, semisort_batched, top_k, top_k_batched)
@@ -76,7 +75,9 @@ def make_keys(dist, dtype, rng, n=N):
 def _x64_if(dist):
     """DTYPE_EXTREME keys collide with the hi sentinel -> tagged fallback,
     whose 32-bit key spaces + tag bits need x64 packing."""
-    return enable_x64() if dist == "DTYPE_EXTREME" else contextlib.nullcontext()
+    if dist == "DTYPE_EXTREME":
+        return jax.enable_x64(True)
+    return contextlib.nullcontext()
 
 
 def assert_grouped(g, x):
@@ -186,7 +187,7 @@ def test_groupby_dtype_max_keys_route_through_tagging(rng):
     hi = np.iinfo(np.int32).max
     x = np.where(rng.random(N) < 0.3, hi, rng.integers(0, 50, size=N))
     x = x.astype(np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         keys, counts = groupby_aggregate(jnp.asarray(x), op="count",
                                          spec=_spec("hss"))
         ok, oc = np.unique(x, return_counts=True)

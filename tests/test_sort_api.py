@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.sort import SortSpec, argsort, available_algorithms, sort, sort_kv
+from repro.parallel.compat import make_mesh
 
 # per-algorithm spec tweaks that make every baseline exact on 8 host shards
 ALGO_SPECS = {
@@ -43,8 +44,7 @@ def test_float32_bijection_roundtrip(rng):
 
 
 def test_float64_bijection_roundtrip(rng):
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 8 * 512
         x = rng.standard_normal(n) * 1e6   # float64
         out = sort(jnp.asarray(x), SortSpec(exchange="allgather"))
@@ -110,8 +110,7 @@ def test_dtype_max_key_never_silently_dropped(rng):
     x[0] = np.iinfo(np.int32).max
     with pytest.raises(ValueError, match="x64|sentinel"):
         sort(jnp.asarray(x), SortSpec(exchange="allgather"))
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         out = sort(jnp.asarray(x), SortSpec(exchange="allgather"))
         np.testing.assert_array_equal(out.gather(), np.sort(x))
 
@@ -157,7 +156,7 @@ def test_spec_kwargs_shorthand(rng):
 
 def test_multistage_honors_explicit_mesh(rng):
     # (4, 2) differs from the auto factoring of 8 = (2, 4)
-    mesh = jax.make_mesh((4, 2), ("outer", "inner"))
+    mesh = make_mesh((4, 2), ("outer", "inner"))
     x = rng.permutation(8 * 512).astype(np.int32)
     out = sort(jnp.asarray(x), SortSpec(algorithm="multistage", mesh=mesh,
                                         exchange="allgather"))
@@ -231,11 +230,10 @@ def test_int_extremes_audited_end_to_end(algo):
     # INT32 min/max clusters: max collides with the untagged sentinel, so
     # the adapter forces tagging (int64 packing under x64) — and the full
     # audit must still pass on every partitioner
-    from jax.experimental import enable_x64
     from repro.data.distributions import make_adversarial
     n = 8 * 256
     x = make_adversarial("DTYPE_EXTREME", n, seed=2, dtype=np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = sort(jnp.asarray(x),
                    SortSpec(algorithm=algo, exchange="allgather",
                             verify="full", **ALGO_SPECS[algo]))
@@ -267,12 +265,11 @@ def test_nan_payload_sort_kv_audited(rng, algo):
     # NaN keys ride sort_kv with their payloads intact: the bijection
     # orders them after +inf (numpy's NaN-last), tagging keeps the
     # permutation stable, and the kv audit fingerprints key AND value
-    from jax.experimental import enable_x64
     n = 8 * 256
     keys = rng.standard_normal(n).astype(np.float32)
     keys[rng.permutation(n)[:48]] = np.float32(np.nan)
     values = np.arange(n, dtype=np.float32)
-    with enable_x64():   # negative floats span the int32 packing budget
+    with jax.enable_x64(True):   # negative floats span the int32 packing budget
         k, v = sort_kv(jnp.asarray(keys), values,
                        SortSpec(algorithm=algo, exchange="allgather",
                                 verify="full", **ALGO_SPECS[algo]))

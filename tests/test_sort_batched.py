@@ -16,7 +16,7 @@ import jax.random as jr
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
+from repro.parallel.compat import make_mesh, shard_map
 from repro.sort import (
     ShardCtx, SortSpec, exec_cache, get_partitioner, sort, sort_batched)
 
@@ -145,7 +145,7 @@ def _collective_counts(batch, *, p=8, n_local=128):
     repro.analysis.jaxpr_walk (shared with the contracts lint)."""
     from repro.analysis.jaxpr_walk import find_round_scan, primitive_counts
 
-    mesh = jax.make_mesh((p,), ("sort",))
+    mesh = make_mesh((p,), ("sort",))
     part = get_partitioner("hss")
     ctx = ShardCtx(spec=SortSpec(), axis_names=("sort",), sizes=(p,),
                    rng=None)

@@ -8,6 +8,7 @@ import pytest
 from repro.optim import (clip_by_global_norm, error_feedback_int8, global_norm,
                          init_compressor, make_optimizer)
 from repro.optim.schedule import cosine_schedule
+from repro.parallel.compat import make_mesh
 
 
 def _tiny_params(rng):
@@ -106,7 +107,7 @@ def test_checkpoint_reshard_restore(tmp_path, rng):
     from repro.ckpt import restore, save
     tree = {"w": jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)}
     save(str(tmp_path), 1, tree)
-    mesh = jax.make_mesh((4,), ("d",), devices=jax.devices()[:4])
+    mesh = make_mesh((4,), ("d",), devices=jax.devices()[:4])
     sh = {"w": NamedSharding(mesh, P("d", None))}
     got, _ = restore(str(tmp_path), 1, tree, shardings=sh)
     assert got["w"].sharding == sh["w"]
