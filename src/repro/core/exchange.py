@@ -32,6 +32,10 @@ dense hands the merge p
 runs of pair_cap, ragged hands it runs at the received offsets, allgather
 hands it the kept window of each source shard. `ExchangeConfig.kernel_policy`
 selects the merge backend (Pallas kernels vs the XLA oracle).
+
+Each strategy traces its send side and collectives under the `exchange`
+scope and the merge of what it received under `merge`
+(repro.runtime.spans), so a device trace tells the two apart.
 """
 from __future__ import annotations
 
@@ -41,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.common import hi_sentinel, round_up
+from repro.runtime import spans
 
 #: Collectives each exchange strategy issues per (single-request) call —
 #: the static wire contract the analysis lint proves against the traced
@@ -135,22 +140,28 @@ def exchange_dense(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
     out_cap = cfg.out_cap(n, p, eps)
     sent_hi = hi_sentinel(local_sorted.dtype)
 
-    starts, counts = destination_slices(local_sorted, splitter_keys, n_valid)
-    sent_counts = jnp.minimum(counts, cap)
-    overflow = jax.lax.psum(jnp.sum(counts - sent_counts), axis_name)
+    with spans.scope(spans.EXCHANGE):
+        starts, counts = destination_slices(local_sorted, splitter_keys,
+                                            n_valid)
+        sent_counts = jnp.minimum(counts, cap)
+        overflow = jax.lax.psum(jnp.sum(counts - sent_counts), axis_name)
 
-    idx = starts[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < sent_counts[:, None]
-    buf = jnp.where(valid, local_sorted[jnp.clip(idx, 0, n - 1)], sent_hi)
+        idx = starts[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
+        valid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
+                 < sent_counts[:, None])
+        buf = jnp.where(valid, local_sorted[jnp.clip(idx, 0, n - 1)],
+                        sent_hi)
 
-    recv = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0,
-                              tiled=False)
-    recv_counts = jax.lax.all_to_all(
-        sent_counts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
+        recv = jax.lax.all_to_all(buf, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=False)
+        recv_counts = jax.lax.all_to_all(
+            sent_counts.reshape(p, 1), axis_name, 0, 0,
+            tiled=False).reshape(p)
     # p sorted sentinel-tailed runs of cap keys -> one k-way merge.
     dispatch, _ = _kernels()
-    merged = dispatch.merge_runs(recv, policy=cfg.kernel_policy)
-    out = _cap_to(merged, out_cap)
+    with spans.scope(spans.MERGE):
+        merged = dispatch.merge_runs(recv, policy=cfg.kernel_policy)
+        out = _cap_to(merged, out_cap)
     n_recv = jnp.sum(recv_counts)
     # Receive-side truncation (only possible when the splitting violated its
     # eps guarantee, e.g. an undersized sample-sort sample) is overflow too.
@@ -190,53 +201,62 @@ def exchange_dense_spill(local_sorted, splitter_keys, *, axis_name, p, cfg,
     me = jax.lax.axis_index(axis_name)
     nv = jnp.asarray(n if n_valid is None else n_valid, jnp.int32)
 
-    starts, counts = destination_slices(local_sorted, splitter_keys, n_valid)
-    sent_counts = jnp.minimum(counts, cap)
-
-    # -- dense channel (identical to exchange_dense)
-    idx = starts[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < sent_counts[:, None]
-    buf = jnp.where(valid, local_sorted[jnp.clip(idx, 0, n - 1)], sent_hi)
-    recv = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0,
-                              tiled=False)
-    recv_counts = jax.lax.all_to_all(
-        sent_counts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
-
-    # -- spill channel: position i spills iff its offset within its
-    # destination slice is past that pair's capacity
     dispatch, gather_runs = _kernels()
-    pos = jnp.arange(n, dtype=jnp.int32)
-    dest = jnp.searchsorted(starts[1:], pos, side="right").astype(jnp.int32)
-    offset = pos - starts[dest]
-    spilled = (offset >= sent_counts[dest]) & (pos < nv)
-    n_spill = jnp.sum(spilled.astype(jnp.int32))
-    spill = dispatch.local_sort(   # compact: spilled keys stay sorted
-        jnp.where(spilled, local_sorted, sent_hi), policy=cfg.kernel_policy)
-    every = jax.lax.all_gather(spill, axis_name, tiled=True)     # (p*n,)
-    nv_sp = jax.lax.all_gather(n_spill[None], axis_name, tiled=True)  # (p,)
-    rows = every.reshape(p, n)
-    lo = splitter_keys[jnp.maximum(me - 1, 0)]
-    hi = splitter_keys[jnp.minimum(me, p - 2)]
-    a = jax.vmap(lambda r: jnp.searchsorted(r, lo, side="left"))(rows)
-    b = jax.vmap(lambda r: jnp.searchsorted(r, hi, side="left"))(rows)
-    a = jnp.where(me > 0, a.astype(jnp.int32), 0)
-    b = jnp.where(me < p - 1, b.astype(jnp.int32), n)
-    s_ends = jnp.minimum(b, nv_sp)
-    s_starts = jnp.minimum(a, s_ends)
-    s_counts = s_ends - s_starts
-    flat_starts = jnp.arange(p, dtype=jnp.int32) * n + s_starts
-    spill_runs = gather_runs(every, flat_starts, s_counts, n)    # (p, n)
+    with spans.scope(spans.EXCHANGE):
+        starts, counts = destination_slices(local_sorted, splitter_keys,
+                                            n_valid)
+        sent_counts = jnp.minimum(counts, cap)
+
+        # -- dense channel (identical to exchange_dense)
+        idx = starts[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
+        valid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
+                 < sent_counts[:, None])
+        buf = jnp.where(valid, local_sorted[jnp.clip(idx, 0, n - 1)],
+                        sent_hi)
+        recv = jax.lax.all_to_all(buf, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=False)
+        recv_counts = jax.lax.all_to_all(
+            sent_counts.reshape(p, 1), axis_name, 0, 0,
+            tiled=False).reshape(p)
+
+        # -- spill channel: position i spills iff its offset within its
+        # destination slice is past that pair's capacity
+        pos = jnp.arange(n, dtype=jnp.int32)
+        dest = jnp.searchsorted(starts[1:], pos,
+                                side="right").astype(jnp.int32)
+        offset = pos - starts[dest]
+        spilled = (offset >= sent_counts[dest]) & (pos < nv)
+        n_spill = jnp.sum(spilled.astype(jnp.int32))
+        spill = dispatch.local_sort(   # compact: spilled keys stay sorted
+            jnp.where(spilled, local_sorted, sent_hi),
+            policy=cfg.kernel_policy)
+        every = jax.lax.all_gather(spill, axis_name, tiled=True)   # (p*n,)
+        nv_sp = jax.lax.all_gather(n_spill[None], axis_name,
+                                   tiled=True)                    # (p,)
+        rows = every.reshape(p, n)
+        lo = splitter_keys[jnp.maximum(me - 1, 0)]
+        hi = splitter_keys[jnp.minimum(me, p - 2)]
+        a = jax.vmap(lambda r: jnp.searchsorted(r, lo, side="left"))(rows)
+        b = jax.vmap(lambda r: jnp.searchsorted(r, hi, side="left"))(rows)
+        a = jnp.where(me > 0, a.astype(jnp.int32), 0)
+        b = jnp.where(me < p - 1, b.astype(jnp.int32), n)
+        s_ends = jnp.minimum(b, nv_sp)
+        s_starts = jnp.minimum(a, s_ends)
+        s_counts = s_ends - s_starts
+        flat_starts = jnp.arange(p, dtype=jnp.int32) * n + s_starts
+        spill_runs = gather_runs(every, flat_starts, s_counts, n)  # (p, n)
 
     # -- merge both channels: p dense runs + p spill-window runs
-    if cap < n:
-        dense_rows = jnp.concatenate(
-            [recv, jnp.full((p, n - cap), sent_hi, recv.dtype)], axis=1)
-    else:
-        dense_rows = recv
-    merged = dispatch.merge_runs(
-        jnp.concatenate([dense_rows, spill_runs], axis=0),
-        policy=cfg.kernel_policy)
-    out = _cap_to(merged, out_cap)
+    with spans.scope(spans.MERGE):
+        if cap < n:
+            dense_rows = jnp.concatenate(
+                [recv, jnp.full((p, n - cap), sent_hi, recv.dtype)], axis=1)
+        else:
+            dense_rows = recv
+        merged = dispatch.merge_runs(
+            jnp.concatenate([dense_rows, spill_runs], axis=0),
+            policy=cfg.kernel_policy)
+        out = _cap_to(merged, out_cap)
     n_recv = jnp.sum(recv_counts) + jnp.sum(s_counts)
     trunc = jnp.maximum(n_recv - out_cap, 0)
     return out, n_recv - trunc, jax.lax.psum(trunc, axis_name)
@@ -248,30 +268,33 @@ def exchange_allgather(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
     out_cap = cfg.out_cap(n, p, eps)
     me = jax.lax.axis_index(axis_name)
 
-    everything = jax.lax.all_gather(local_sorted, axis_name, tiled=True)
-    nv_local = jnp.asarray(n if n_valid is None else n_valid, jnp.int32)
-    nv = jax.lax.all_gather(nv_local[None], axis_name, tiled=True)   # (p,)
-    rows = everything.reshape(p, n)
-    # My key range [lo, hi) is a contiguous window of each (sorted) source
-    # run: two vmapped binary searches per run, not an O(p*n) mask.
-    lo = splitter_keys[jnp.maximum(me - 1, 0)]
-    hi = splitter_keys[jnp.minimum(me, p - 2)]
-    a = jax.vmap(lambda r: jnp.searchsorted(r, lo, side="left"))(rows)
-    b = jax.vmap(lambda r: jnp.searchsorted(r, hi, side="left"))(rows)
-    a = jnp.where(me > 0, a.astype(jnp.int32), 0)
-    b = jnp.where(me < p - 1, b.astype(jnp.int32), n)
-    ends = jnp.minimum(b, nv)
-    starts = jnp.minimum(a, ends)
-    counts = ends - starts
-    n_out = jnp.sum(counts)
-
     dispatch, gather_runs = _kernels()
-    flat_starts = jnp.arange(p, dtype=jnp.int32) * n + starts
-    # slot = n bounds every window exactly (a source can contribute at most
-    # its whole run); merge_runs pads the row length internally as needed.
-    runs = gather_runs(everything, flat_starts, counts, n)
-    merged = dispatch.merge_runs(runs, policy=cfg.kernel_policy)
-    vals = _cap_to(merged, out_cap)
+    with spans.scope(spans.EXCHANGE):
+        everything = jax.lax.all_gather(local_sorted, axis_name, tiled=True)
+        nv_local = jnp.asarray(n if n_valid is None else n_valid, jnp.int32)
+        nv = jax.lax.all_gather(nv_local[None], axis_name, tiled=True)  # (p,)
+        rows = everything.reshape(p, n)
+        # My key range [lo, hi) is a contiguous window of each (sorted)
+        # source run: two vmapped binary searches per run, not an O(p*n)
+        # mask.
+        lo = splitter_keys[jnp.maximum(me - 1, 0)]
+        hi = splitter_keys[jnp.minimum(me, p - 2)]
+        a = jax.vmap(lambda r: jnp.searchsorted(r, lo, side="left"))(rows)
+        b = jax.vmap(lambda r: jnp.searchsorted(r, hi, side="left"))(rows)
+        a = jnp.where(me > 0, a.astype(jnp.int32), 0)
+        b = jnp.where(me < p - 1, b.astype(jnp.int32), n)
+        ends = jnp.minimum(b, nv)
+        starts = jnp.minimum(a, ends)
+        counts = ends - starts
+        n_out = jnp.sum(counts)
+        flat_starts = jnp.arange(p, dtype=jnp.int32) * n + starts
+        # slot = n bounds every window exactly (a source can contribute at
+        # most its whole run); merge_runs pads the row length internally.
+        runs = gather_runs(everything, flat_starts, counts, n)
+
+    with spans.scope(spans.MERGE):
+        merged = dispatch.merge_runs(runs, policy=cfg.kernel_policy)
+        vals = _cap_to(merged, out_cap)
     trunc = jnp.maximum(n_out - out_cap, 0)
     return vals, n_out - trunc, jax.lax.psum(trunc, axis_name)
 
@@ -314,30 +337,35 @@ def exchange_ragged(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
     out_cap = cfg.out_cap(n, p, eps)
     sent_hi = hi_sentinel(local_sorted.dtype)
 
-    starts, counts = destination_slices(local_sorted, splitter_keys, n_valid)
-    staged, send_starts, send_rows = row_aligned_chunks(
-        local_sorted, starts, counts, -(-n // RAGGED_ROW) + p)
-    # recv_counts[s] = how many keys I receive from source s.
-    recv_counts = jax.lax.all_to_all(
-        counts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
-    recv_rows = -(-recv_counts // RAGGED_ROW)
-    recv_starts = _exclusive_cumsum(recv_rows)
-    # send_offsets[d] = row within destination d's buffer of my chunk.
-    send_offsets = jax.lax.all_to_all(
-        recv_starts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
-    out = jnp.full((-(-out_cap // RAGGED_ROW) + p, RAGGED_ROW), sent_hi,
-                   local_sorted.dtype)
-    out = jax.lax.ragged_all_to_all(
-        staged, out, send_starts, send_rows, send_offsets, recv_rows,
-        axis_name=axis_name)
+    with spans.scope(spans.EXCHANGE):
+        starts, counts = destination_slices(local_sorted, splitter_keys,
+                                            n_valid)
+        staged, send_starts, send_rows = row_aligned_chunks(
+            local_sorted, starts, counts, -(-n // RAGGED_ROW) + p)
+        # recv_counts[s] = how many keys I receive from source s.
+        recv_counts = jax.lax.all_to_all(
+            counts.reshape(p, 1), axis_name, 0, 0, tiled=False).reshape(p)
+        recv_rows = -(-recv_counts // RAGGED_ROW)
+        recv_starts = _exclusive_cumsum(recv_rows)
+        # send_offsets[d] = row within destination d's buffer of my chunk.
+        send_offsets = jax.lax.all_to_all(
+            recv_starts.reshape(p, 1), axis_name, 0, 0,
+            tiled=False).reshape(p)
+        out = jnp.full((-(-out_cap // RAGGED_ROW) + p, RAGGED_ROW), sent_hi,
+                       local_sorted.dtype)
+        out = jax.lax.ragged_all_to_all(
+            staged, out, send_starts, send_rows, send_offsets, recv_rows,
+            axis_name=axis_name)
     n_valid = jnp.sum(recv_counts)
     # p sorted runs at known (traced) offsets: k-way merge, with the
     # in-kernel full-sort fallback if a run overflows the static slot.
     dispatch, _ = _kernels()
-    out = dispatch.merge_ragged(
-        out.reshape(-1), recv_starts * RAGGED_ROW, recv_counts,
-        policy=cfg.kernel_policy, slot=cfg.ragged_slot(n, p, eps))
-    return out[:out_cap], n_valid, jnp.zeros((), jnp.int32)
+    with spans.scope(spans.MERGE):
+        out = dispatch.merge_ragged(
+            out.reshape(-1), recv_starts * RAGGED_ROW, recv_counts,
+            policy=cfg.kernel_policy, slot=cfg.ragged_slot(n, p, eps))
+        out = out[:out_cap]
+    return out, n_valid, jnp.zeros((), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -376,29 +404,32 @@ def exchange_dense_batched(local_sorted, splitter_keys, *, axis_name, p, cfg,
     out_cap = cfg.out_cap(n, p, eps)
     sent_hi = hi_sentinel(local_sorted.dtype)
 
-    starts, counts = jax.vmap(destination_slices)(
-        local_sorted, splitter_keys, _rows_valid(n_valid, b, n))  # (B, p)
-    sent_counts = jnp.minimum(counts, cap)
-    overflow = jax.lax.psum(
-        jnp.sum(counts - sent_counts, axis=1), axis_name)  # (B,)
+    with spans.scope(spans.EXCHANGE):
+        starts, counts = jax.vmap(destination_slices)(
+            local_sorted, splitter_keys, _rows_valid(n_valid, b, n))  # (B, p)
+        sent_counts = jnp.minimum(counts, cap)
+        overflow = jax.lax.psum(
+            jnp.sum(counts - sent_counts, axis=1), axis_name)  # (B,)
 
-    idx = starts[:, :, None] + jnp.arange(cap, dtype=jnp.int32)[None, None, :]
-    valid = (jnp.arange(cap, dtype=jnp.int32)[None, None, :]
-             < sent_counts[:, :, None])
-    rows = jnp.take_along_axis(local_sorted, jnp.clip(idx, 0, n - 1)
-                               .reshape(b, -1), axis=1).reshape(b, p, cap)
-    buf = jnp.where(valid, rows, sent_hi)                # (B, p, cap)
+        idx = (starts[:, :, None]
+               + jnp.arange(cap, dtype=jnp.int32)[None, None, :])
+        valid = (jnp.arange(cap, dtype=jnp.int32)[None, None, :]
+                 < sent_counts[:, :, None])
+        rows = jnp.take_along_axis(local_sorted, jnp.clip(idx, 0, n - 1)
+                                   .reshape(b, -1), axis=1).reshape(b, p, cap)
+        buf = jnp.where(valid, rows, sent_hi)                # (B, p, cap)
 
-    # ONE all_to_all for the whole batch: shard axis leading.
-    recv = jax.lax.all_to_all(jnp.swapaxes(buf, 0, 1), axis_name, 0, 0,
-                              tiled=False)               # (p, B, cap)
-    recv = jnp.swapaxes(recv, 0, 1)                      # (B, p, cap)
-    recv_counts = jax.lax.all_to_all(
-        sent_counts.T[..., None], axis_name, 0, 0, tiled=False)[..., 0].T
+        # ONE all_to_all for the whole batch: shard axis leading.
+        recv = jax.lax.all_to_all(jnp.swapaxes(buf, 0, 1), axis_name, 0, 0,
+                                  tiled=False)               # (p, B, cap)
+        recv = jnp.swapaxes(recv, 0, 1)                      # (B, p, cap)
+        recv_counts = jax.lax.all_to_all(
+            sent_counts.T[..., None], axis_name, 0, 0, tiled=False)[..., 0].T
 
     dispatch, _ = _kernels()
-    merged = dispatch.merge_runs_batched(recv, policy=cfg.kernel_policy)
-    out = _cap_to(merged, out_cap)
+    with spans.scope(spans.MERGE):
+        merged = dispatch.merge_runs_batched(recv, policy=cfg.kernel_policy)
+        out = _cap_to(merged, out_cap)
     n_recv = jnp.sum(recv_counts, axis=1)                # (B,)
     trunc = jnp.maximum(n_recv - out_cap, 0)
     overflow = overflow + jax.lax.psum(trunc, axis_name)
@@ -411,30 +442,34 @@ def exchange_allgather_batched(local_sorted, splitter_keys, *, axis_name, p,
     out_cap = cfg.out_cap(n, p, eps)
     me = jax.lax.axis_index(axis_name)
 
-    # ONE all_gather of the whole (B, n_local) shard.
-    everything = jax.lax.all_gather(local_sorted, axis_name)   # (p, B, n)
-    nv = jax.lax.all_gather(_rows_valid(n_valid, b, n), axis_name)  # (p, B)
-    lo = splitter_keys[:, jnp.maximum(me - 1, 0)]              # (B,)
-    hi = splitter_keys[:, jnp.minimum(me, p - 2)]              # (B,)
-    search = jax.vmap(jax.vmap(
-        lambda r, q: jnp.searchsorted(r, q, side="left"),
-        in_axes=(0, 0)), in_axes=(0, None))
-    a = search(everything, lo)                                 # (p, B)
-    bq = search(everything, hi)
-    a = jnp.where(me > 0, a.astype(jnp.int32), 0)
-    bq = jnp.where(me < p - 1, bq.astype(jnp.int32), n)
-    ends = jnp.minimum(bq, nv)
-    starts = jnp.minimum(a, ends)
-    counts = ends - starts                                     # (p, B)
-    n_out = jnp.sum(counts, axis=0)                            # (B,)
-
     dispatch, gather_runs = _kernels()
-    flat = jnp.swapaxes(everything, 0, 1).reshape(b, p * n)    # (B, p*n)
-    flat_starts = (jnp.arange(p, dtype=jnp.int32)[:, None] * n + starts).T
-    runs = jax.vmap(gather_runs, in_axes=(0, 0, 0, None))(
-        flat, flat_starts, counts.T, n)                        # (B, p, n)
-    merged = dispatch.merge_runs_batched(runs, policy=cfg.kernel_policy)
-    vals = _cap_to(merged, out_cap)
+    with spans.scope(spans.EXCHANGE):
+        # ONE all_gather of the whole (B, n_local) shard.
+        everything = jax.lax.all_gather(local_sorted, axis_name)  # (p, B, n)
+        nv = jax.lax.all_gather(_rows_valid(n_valid, b, n),
+                                axis_name)                        # (p, B)
+        lo = splitter_keys[:, jnp.maximum(me - 1, 0)]             # (B,)
+        hi = splitter_keys[:, jnp.minimum(me, p - 2)]             # (B,)
+        search = jax.vmap(jax.vmap(
+            lambda r, q: jnp.searchsorted(r, q, side="left"),
+            in_axes=(0, 0)), in_axes=(0, None))
+        a = search(everything, lo)                                # (p, B)
+        bq = search(everything, hi)
+        a = jnp.where(me > 0, a.astype(jnp.int32), 0)
+        bq = jnp.where(me < p - 1, bq.astype(jnp.int32), n)
+        ends = jnp.minimum(bq, nv)
+        starts = jnp.minimum(a, ends)
+        counts = ends - starts                                    # (p, B)
+        n_out = jnp.sum(counts, axis=0)                           # (B,)
+        flat = jnp.swapaxes(everything, 0, 1).reshape(b, p * n)   # (B, p*n)
+        flat_starts = (jnp.arange(p, dtype=jnp.int32)[:, None] * n
+                       + starts).T
+        runs = jax.vmap(gather_runs, in_axes=(0, 0, 0, None))(
+            flat, flat_starts, counts.T, n)                       # (B, p, n)
+
+    with spans.scope(spans.MERGE):
+        merged = dispatch.merge_runs_batched(runs, policy=cfg.kernel_policy)
+        vals = _cap_to(merged, out_cap)
     trunc = jnp.maximum(n_out - out_cap, 0)
     return vals, n_out - trunc, jax.lax.psum(trunc, axis_name)
 

@@ -20,6 +20,7 @@ import jax.random as jr
 from repro.core.common import HSSConfig, hi_sentinel
 from repro.kernels import dispatch
 from repro.core.exchange import ExchangeConfig, exchange
+from repro.runtime import spans
 from repro.core.splitters import (
     SplitterState, choose_splitters, refine, active_union_size, _sample_round,
 )
@@ -74,7 +75,9 @@ def hss_splitters_general(
         return (state, key), (gamma, jax.lax.psum(n_samp, axis_names),
                               jax.lax.psum(ovf, axis_names))
 
-    (state, _), stats = jax.lax.scan(round_body, (state0, rng), None, length=k)
+    with spans.scope(spans.SPLITTER_ROUND):   # the rounds and their loop
+        (state, _), stats = jax.lax.scan(round_body, (state0, rng), None,
+                                         length=k)
     keys, ranks = choose_splitters(state, targets)
     return keys, ranks, stats
 
@@ -88,13 +91,15 @@ def two_stage_sort_sharded(
     """shard_map-resident two-stage HSS sort over a (r1, r2) mesh."""
     hss_cfg = hss_cfg or HSSConfig()
     ex_cfg = ex_cfg or ExchangeConfig(kernel_policy=hss_cfg.kernel_policy)
-    local_sorted = dispatch.local_sort(local, policy=hss_cfg.kernel_policy)
+    with spans.scope(spans.LOCAL_SORT):
+        local_sorted = dispatch.local_sort(local, policy=hss_cfg.kernel_policy)
     rng1, rng2 = jr.split(rng)
 
     # ---- stage 1: split into r1 groups, exchange along the outer axis only.
-    g_keys, _, _ = hss_splitters_general(
-        local_sorted, axis_names=(outer_axis, inner_axis),
-        num_shards=r1 * r2, num_parts=r1, cfg=hss_cfg, rng=rng1)
+    with spans.scope(spans.SPLITTERS):
+        g_keys, _, _ = hss_splitters_general(
+            local_sorted, axis_names=(outer_axis, inner_axis),
+            num_shards=r1 * r2, num_parts=r1, cfg=hss_cfg, rng=rng1)
     ex1 = dataclasses.replace(ex_cfg, out_slack=stage1_out_slack)
     mid, mid_valid, ovf1 = exchange(
         local_sorted, g_keys, axis_name=outer_axis, p=r1, cfg=ex1,
@@ -103,9 +108,10 @@ def two_stage_sort_sharded(
     # ---- stage 2: full HSS sort within the group along the inner axis.
     # mid is sentinel-padded; group-wide valid count:
     group_n = jax.lax.psum(mid_valid, inner_axis)
-    s_keys, _, _ = hss_splitters_general(
-        mid, axis_names=inner_axis, num_shards=r2, num_parts=r2,
-        cfg=hss_cfg, rng=rng2, n_valid=group_n)
+    with spans.scope(spans.SPLITTERS):
+        s_keys, _, _ = hss_splitters_general(
+            mid, axis_names=inner_axis, num_shards=r2, num_parts=r2,
+            cfg=hss_cfg, rng=rng2, n_valid=group_n)
     out, n_valid, ovf2 = exchange(
         mid, s_keys, axis_name=inner_axis, p=r2, cfg=ex_cfg, eps=hss_cfg.eps,
         n_valid=mid_valid)

@@ -39,6 +39,7 @@ from repro.core.common import (
     sampling_ratios,
 )
 from repro.kernels import dispatch
+from repro.runtime import spans
 
 
 #: Collectives one non-converged HSS round issues — ONE all_gather of the
@@ -260,8 +261,9 @@ def hss_splitters(
         )
         return (state, key), stats
 
-    (state, _), (gam, cnt, ovf, nsat) = jax.lax.scan(
-        round_body, (state0, rng), jnp.arange(k))
+    with spans.scope(spans.SPLITTER_ROUND):   # the rounds and their loop
+        (state, _), (gam, cnt, ovf, nsat) = jax.lax.scan(
+            round_body, (state0, rng), jnp.arange(k))
     keys, ranks = choose_splitters(state, targets)
     all_sat = nsat >= (p - 1)
     rounds_used = jnp.where(
@@ -375,8 +377,9 @@ def hss_splitters_batched(
                  jnp.sum(state.satisfied.astype(jnp.int32), axis=1))
         return (state, key), stats
 
-    (state, _), (gam, cnt, ovf, nsat) = jax.lax.scan(
-        round_body, (state0, rng), jnp.arange(k))
+    with spans.scope(spans.SPLITTER_ROUND):   # the rounds and their loop
+        (state, _), (gam, cnt, ovf, nsat) = jax.lax.scan(
+            round_body, (state0, rng), jnp.arange(k))
     keys, ranks = jax.vmap(choose_splitters, in_axes=(0, None))(state, targets)
     all_sat = nsat >= (p - 1)                                   # (k, B)
     rounds_used = jnp.where(jnp.any(all_sat, axis=0),

@@ -26,6 +26,10 @@ Rows. Several independent rows of `row_len` keys (a power of two) may
 share one flat array — a request batch. Directions are computed from the
 index within the row, so the last stage orders each row ascending and no
 comparator crosses a row boundary as long as blocks divide rows.
+
+Name. The launch is named `<family>_network` (repro.runtime.spans): the
+caller's family, `local_sort` or `merge_sorted_runs`, so a trace tells the
+local sort's passes from the merge's.
 """
 from __future__ import annotations
 
@@ -35,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import spans
 
 LANES = 128
 
@@ -82,7 +88,8 @@ def _network_kernel(x_ref, o_ref, *, steps, row_len):
 
 
 def network_pass(x: jax.Array, *, block: int, steps: tuple, row_len: int,
-                 interpret: bool) -> jax.Array:
+                 interpret: bool,
+                 family: str = spans.SORT_FAMILY) -> jax.Array:
     """Run `steps` on every aligned `block` of the flat array x, whose
     rows hold `row_len` keys. Every step's distance must be < block."""
     n = x.shape[0]
@@ -97,5 +104,6 @@ def network_pass(x: jax.Array, *, block: int, steps: tuple, row_len: int,
         out_specs=pl.BlockSpec(tile, lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n // lanes, lanes), x.dtype),
         interpret=interpret,
+        name=f"{family}_{spans.NETWORK}",
     )(x.reshape(n // lanes, lanes))
     return out.reshape(n)

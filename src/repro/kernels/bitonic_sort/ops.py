@@ -17,6 +17,7 @@ from repro.core.common import hi_sentinel, pow2_ceil
 from repro.kernels import from_kernel_keys, to_kernel_keys
 from repro.kernels import interpret_default as _interpret
 from repro.kernels.bitonic_sort import kernel as K
+from repro.runtime import spans
 
 # VMEM budget: a merge block of MAX_RUN keys (plus double buffering and the
 # step temporaries) must fit VMEM; 64K keys = 256 KiB at 4 bytes. Beyond
@@ -56,7 +57,7 @@ def _sort_rows(x, block: int, interpret: bool):
     flat = K.network_pass(xp.reshape(-1), block=blk, steps=K.stages(2, blk),
                           row_len=row, interpret=interpret)
     flat = merge_cascade(flat, blk, vmem_block=MAX_RUN, row_len=row,
-                         interpret=interpret)
+                         interpret=interpret, family=spans.SORT_FAMILY)
     return from_kernel_keys(flat.reshape(b, row)[:, :n], dtype)
 
 

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import spans
+
 LANES = 128
 SUBLANES = 8
 
@@ -70,5 +72,6 @@ def probe_ranks_pallas(keys: jax.Array, probes: jax.Array, *, tile: int,
         out_specs=pl.BlockSpec((1, m, LANES), lambda r, i: (r, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, LANES), jnp.int32),
         interpret=interpret,
+        name=spans.HISTOGRAM_KERNEL,
     )(keys3, probes3)
     return jnp.sum(counts, axis=2, dtype=jnp.int32)

@@ -19,6 +19,9 @@ Correctness is a property of the comparator network, not of the chunking:
 these shapes execute exactly the comparators of the standard bitonic merge,
 in network order, so the result is bit-identical to the VMEM kernel and to
 the `jnp.sort` oracle for any block sizes.
+
+The strided launch is named `<family>_strided` (repro.runtime.spans), the
+caller's family as for `network_pass`.
 """
 from __future__ import annotations
 
@@ -29,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.bitonic_sort.kernel import LANES, network_pass
+from repro.runtime import spans
 
 # Keys per half-tile of a strided HBM pass: a (2, 8, 128) pair of tiles
 # (8 KiB at 4 bytes) per grid step — deliberately tiny so the pass
@@ -54,7 +58,8 @@ def _strided_ce_kernel(x_ref, o_ref, *, d: int, k: int, row_len: int):
 
 
 def strided_compare_exchange(x: jax.Array, d: int, k: int, *, cols: int,
-                             row_len: int, interpret: bool) -> jax.Array:
+                             row_len: int, interpret: bool,
+                             family: str = spans.MERGE_FAMILY) -> jax.Array:
     """Step (k, d) of the network over the flat array x (d % cols == 0)."""
     n = x.shape[0]
     assert n % (2 * d) == 0 and d % cols == 0, (n, d, cols)
@@ -68,23 +73,26 @@ def strided_compare_exchange(x: jax.Array, d: int, k: int, *, cols: int,
         out_specs=pl.BlockSpec(tile, lambda i, j: (i, 0, j, 0)),
         out_shape=jax.ShapeDtypeStruct(x4.shape, x.dtype),
         interpret=interpret,
+        name=f"{family}_{spans.STRIDED}",
     )(x4)
     return out.reshape(n)
 
 
 def merge_level(x: jax.Array, run: int, *, vmem_block: int, row_len: int,
-                cols: int = DEFAULT_COLS, interpret: bool) -> jax.Array:
+                cols: int = DEFAULT_COLS, interpret: bool,
+                family: str = spans.MERGE_FAMILY) -> jax.Array:
     """Merge stage k = 2*run: runs of length `run` in alternating directions
     -> runs of 2*run, holding at most `vmem_block` keys in VMEM."""
     k = 2 * run
     d = run
     while d >= vmem_block:
         x = strided_compare_exchange(x, d, k, cols=min(d, cols),
-                                     row_len=row_len, interpret=interpret)
+                                     row_len=row_len, interpret=interpret,
+                                     family=family)
         d //= 2
     steps = []
     while d >= 1:
         steps.append((k, d))
         d //= 2
     return network_pass(x, block=2 * steps[0][1], steps=tuple(steps),
-                        row_len=row_len, interpret=interpret)
+                        row_len=row_len, interpret=interpret, family=family)

@@ -36,10 +36,11 @@ from repro.kernels import interpret_default as _interpret
 from repro.kernels.bitonic_sort import kernel as BK
 from repro.kernels.bitonic_sort import ops as bops
 from repro.kernels.merge import kernel as MK
+from repro.runtime import spans
 
 
 def merge_cascade(x, run: int, *, vmem_block: int, row_len: int,
-                  interpret: bool):
+                  interpret: bool, family: str):
     """Pairwise-merge tree over the flat array x, whose rows hold `row_len`
     keys (a power of two): each row a sequence of sorted runs of length
     `run` in alternating directions (run j ascending iff j is even) -> each
@@ -48,15 +49,17 @@ def merge_cascade(x, run: int, *, vmem_block: int, row_len: int,
 
     All stages that fit one `vmem_block` run in a single VMEM pass; each
     larger stage is one `merge_level` (strided HBM steps + a VMEM tail).
+    `family` names the kernel launches (repro.runtime.spans).
     """
     blk = min(vmem_block, row_len)
     if run < blk:
         x = BK.network_pass(x, block=blk, steps=BK.stages(2 * run, blk),
-                            row_len=row_len, interpret=interpret)
+                            row_len=row_len, interpret=interpret,
+                            family=family)
         run = blk
     while run < row_len:
         x = MK.merge_level(x, run, vmem_block=blk, row_len=row_len,
-                           interpret=interpret)
+                           interpret=interpret, family=family)
         run *= 2
     return x
 
@@ -83,7 +86,7 @@ def _merge_rows(runs, vmem_block, interpret):
     # about two seconds.
     flat = jax.lax.optimization_barrier(flat)
     out = merge_cascade(flat, r2, vmem_block=vmem_block, row_len=k2 * r2,
-                        interpret=interpret)
+                        interpret=interpret, family=spans.MERGE_FAMILY)
     return from_kernel_keys(out.reshape(b, k2 * r2)[:, :k * r], dtype)
 
 

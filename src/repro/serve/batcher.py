@@ -32,6 +32,11 @@ class Request:
     deadline is absolute `loop.time()` (None = no deadline); expired
     requests are dropped from the batch at dispatch, resolved with
     DeadlineExceeded, and never poison the surviving requests.
+
+    The life cycle is stamped on the same clock as `t_submit`
+    (`loop.time()` is `time.monotonic()`): `t_flush` when its bucket
+    flushes, `t_start`/`t_end` when the dispatch thread first starts and
+    last ends a run that holds it (retries and bisection included).
     """
     kind: str                  # "sort" | "argsort" | "sort_kv" |
     #                            "semisort" | "top_k"
@@ -43,6 +48,9 @@ class Request:
     t_submit: float            # loop.time() at admission
     deadline: float | None = None
     param: Any = None          # kind-specific scalar (top_k: the k)
+    t_flush: float | None = None
+    t_start: float | None = None
+    t_end: float | None = None
 
 
 class DynamicBatcher:
@@ -84,6 +92,9 @@ class DynamicBatcher:
             timer.cancel()
         reqs = self._pending.pop(key, None)
         if reqs:
+            now = asyncio.get_running_loop().time()
+            for r in reqs:
+                r.t_flush = now
             self.flush_cb(key, reqs, reason)
 
     def flush_all(self, reason: str = "drain") -> None:

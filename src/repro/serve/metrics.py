@@ -7,6 +7,9 @@ operator of a sort-as-a-service deployment watches:
     reasons (size/deadline/drain), queue-wait, executable-cache hit/miss
     deltas attributed to the bucket, and a bounded latency reservoir from
     which p50/p99 are computed at snapshot time;
+  * per-bucket sums of each served request's life cycle (`PHASES`):
+    submit to flush, flush to the dispatch thread, the dispatch thread's
+    run, and its end to resolution, which add up to the latency;
   * global counters — admissions, typed rejections, expired/cancelled
     requests, served results;
   * a batch-time EWMA reusing `repro.runtime.ft.StepTimer` (seeded from
@@ -31,6 +34,10 @@ from collections import deque
 
 from repro.runtime.ft import StepTimer
 
+#: A request's life cycle, in order; the four add up to its latency
+#: (repro.serve.service stamps them).
+PHASES = ("batcher_wait", "dispatch_wait", "batch_host", "resolve_wait")
+
 
 def percentile(samples, q: float) -> float:
     """Nearest-rank percentile of an unsorted sample list (q in [0, 1])."""
@@ -51,6 +58,8 @@ class _BucketMetrics:
         self.flush_reasons: dict = {}
         self.queue_wait_s_sum = 0.0
         self.queue_wait_s_max = 0.0
+        self.phase_s_sum = dict.fromkeys(PHASES, 0.0)
+        self.phased = 0      # requests whose life cycle was recorded
         self.cache_hits = 0
         self.cache_misses = 0
         self.expired = 0
@@ -75,6 +84,11 @@ class _BucketMetrics:
             "queue_wait_ms": {
                 "mean": 1e3 * self.queue_wait_s_sum / max(self.requests, 1),
                 "max": 1e3 * self.queue_wait_s_max,
+            },
+            "lifecycle_ms": {
+                "requests": self.phased,
+                **{ph: {"sum": 1e3 * s, "mean": 1e3 * s / max(self.phased, 1)}
+                   for ph, s in self.phase_s_sum.items()},
             },
             "cache": {
                 "hits": self.cache_hits,
@@ -237,10 +251,17 @@ class MetricsRegistry:
         with self._lock:
             self.verify_failed_requests += rows
 
-    def observe_result(self, key, latency_s: float, *, ok: bool = True) -> None:
+    def observe_result(self, key, latency_s: float, *, ok: bool = True,
+                       phases_s: dict | None = None) -> None:
+        """One resolved request: its latency and, where the service
+        stamped it, its life cycle (seconds per `PHASES` entry)."""
         with self._lock:
             b = self._bucket(key)
             b.latency_s.append(latency_s)
+            if phases_s is not None:
+                b.phased += 1
+                for ph in PHASES:
+                    b.phase_s_sum[ph] += phases_s[ph]
             if ok:
                 self.served += 1
             else:

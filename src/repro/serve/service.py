@@ -52,14 +52,16 @@ a blocking `submit`.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import dataclasses
+import itertools
 import threading
 import time
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime import chaos
+from repro.runtime import chaos, spans
 from repro.runtime.ft import SupervisedExecutor
 from repro.serve.batcher import DynamicBatcher, Request
 from repro.serve.breaker import BreakerBoard
@@ -139,6 +141,38 @@ def _pad_pow2(b: int, cap: int) -> int:
     return min(p, cap)
 
 
+@contextlib.contextmanager
+def _stamp_run(reqs):
+    """Stamp each request's first executor start and last executor end
+    (`time.monotonic()`, the clock of `loop.time()`)."""
+    t = time.monotonic()
+    for r in reqs:
+        if r.t_start is None:
+            r.t_start = t
+    try:
+        yield
+    finally:
+        t = time.monotonic()
+        for r in reqs:
+            r.t_end = t
+
+
+def _phases(req: Request, done: float) -> dict:
+    """A resolved request's life cycle in seconds: submit to flush
+    (`batcher_wait`), flush to the dispatch thread's first start
+    (`dispatch_wait`: the loop's task, the in-flight semaphore, the
+    executor's queue), that start to its last end (`batch_host`), and that
+    end to resolution (`resolve_wait`); they sum to its latency. A request
+    no run reached counts its whole wait after the flush as dispatch."""
+    flush = req.t_flush if req.t_flush is not None else req.t_submit
+    start = req.t_start if req.t_start is not None else done
+    end = req.t_end if req.t_end is not None else start
+    return {"batcher_wait": flush - req.t_submit,
+            "dispatch_wait": start - flush,
+            "batch_host": end - start,
+            "resolve_wait": done - end}
+
+
 class SortService:
     """Asyncio sort-as-a-service over the batched single-launch engine."""
 
@@ -173,6 +207,7 @@ class SortService:
         self._in_flight = 0     # batches past the semaphore
         self._idle: asyncio.Event | None = None
         self._closed = False
+        self._batch_seq = itertools.count()   # `serve.batch` span numbers
 
     # -- submission --------------------------------------------------------
 
@@ -301,7 +336,8 @@ class SortService:
             for r, res in zip(live, results):
                 self.metrics.observe_result(
                     r.key, done - r.t_submit,
-                    ok=not isinstance(res, BaseException))
+                    ok=not isinstance(res, BaseException),
+                    phases_s=_phases(r, done))
                 self._resolve(r, res)
 
     async def _execute(self, key, reqs, *, top: bool = True):
@@ -406,6 +442,11 @@ class SortService:
         """Executor thread: one request through the unbatched front door
         (the degraded path). Bit-identical to the batched result by the
         engine's batching and kernel-policy parity contracts."""
+        with _stamp_run([req]), spans.span(
+                spans.BATCH, batch=next(self._batch_seq), requests=1):
+            return self._serve_one(req)
+
+    def _serve_one(self, req: Request):
         chaos.on_dispatch(req.x)
         spec = req.spec
         fkp = self.config.fallback_kernel_policy
@@ -424,7 +465,15 @@ class SortService:
         return sort_single(x, spec).gather(), req.values[order]
 
     def _run_batch(self, reqs):
-        """Executor thread: one `sort_batched` launch for the batch.
+        """Executor thread: one `sort_batched` launch for the batch, as
+        the `serve.batch` span; see `_launch_batch`."""
+        with _stamp_run(reqs), spans.span(
+                spans.BATCH, batch=next(self._batch_seq),
+                requests=len(reqs)):
+            return self._launch_batch(reqs)
+
+    def _launch_batch(self, reqs):
+        """One `sort_batched` launch for the batch.
 
         All requests share a bucket key, hence an (n,), dtype, kind, and
         spec — stacking is safe. Returns per-request results in input
@@ -437,23 +486,27 @@ class SortService:
         typed VerificationError carrying its own row verdict."""
         spec, kind = reqs[0].spec, reqs[0].kind
         b_real = len(reqs)
-        xs = np.stack([r.x for r in reqs])
-        chaos.on_dispatch(xs)   # fault-injection hook (no-op in prod)
-        if self.config.pad_batches:
-            b_pad = _pad_pow2(b_real, self.config.max_batch)
-            if b_pad > b_real:   # repeat the last row; rows are independent
-                xs = np.concatenate(
-                    [xs, np.broadcast_to(xs[-1], (b_pad - b_real,) + xs[-1].shape)])
+        with spans.span(spans.STACK):
+            xs = np.stack([r.x for r in reqs])
+            chaos.on_dispatch(xs)   # fault-injection hook (no-op in prod)
+            if self.config.pad_batches:
+                b_pad = _pad_pow2(b_real, self.config.max_batch)
+                if b_pad > b_real:   # repeat the last row; rows independent
+                    xs = np.concatenate(
+                        [xs, np.broadcast_to(
+                            xs[-1], (b_pad - b_real,) + xs[-1].shape)])
         stats0 = sort_driver.exec_cache.stats()
         verify_err = None
         row_ok = None
+        with spans.span(spans.H2D):
+            dev = jnp.asarray(xs)
         try:
             if kind == "top_k":
-                out = top_k_batched(jnp.asarray(xs), reqs[0].param, spec=spec)
+                out = top_k_batched(dev, reqs[0].param, spec=spec)
             elif kind == "semisort":
-                out = semisort_batched(jnp.asarray(xs), spec=spec)
+                out = semisort_batched(dev, spec=spec)
             else:
-                out = sort_batched(jnp.asarray(xs), spec)
+                out = sort_batched(dev, spec)
         except BatchVerificationError as e:
             # sort kinds only: semisort/top_k don't wrap the device audit
             # (DESIGN.md Section 10), so they can't raise this here — a
@@ -464,9 +517,24 @@ class SortService:
             row_ok = e.row_ok
         self.metrics.observe_recovery(
             reqs[0].key, getattr(out, "recovery", None))
+        with spans.span(spans.RESULTS):
+            results, verify_bad = self._results(reqs, out, row_ok,
+                                                verify_err)
+        if verify_bad:
+            self.metrics.observe_verify_failure(reqs[0].key, verify_bad)
+        stats1 = sort_driver.exec_cache.stats()
+        delta = {k: stats1[k] - stats0[k]
+                 for k in ("hits", "misses", "evictions")}
+        return results, delta, verify_bad
+
+    @staticmethod
+    def _results(reqs, out, row_ok, verify_err):
+        """Each request's answer from the batch's output, in order, and
+        the count of rows whose audit terminally failed."""
+        kind = reqs[0].kind
         results = []
         verify_bad = 0
-        for b in range(b_real):
+        for b in range(len(reqs)):
             if row_ok is not None and not row_ok[b]:
                 verify_bad += 1
                 results.append(VerificationError(
@@ -494,12 +562,7 @@ class SortService:
                 results.append(order)
             else:   # sort_kv
                 results.append((r.gather(), reqs[b].values[order]))
-        if verify_bad:
-            self.metrics.observe_verify_failure(reqs[0].key, verify_bad)
-        stats1 = sort_driver.exec_cache.stats()
-        delta = {k: stats1[k] - stats0[k]
-                 for k in ("hits", "misses", "evictions")}
-        return results, delta, verify_bad
+        return results, verify_bad
 
     # -- health ------------------------------------------------------------
 

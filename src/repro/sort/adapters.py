@@ -295,8 +295,15 @@ def _needs_tags(x: jax.Array, spec: SortSpec, want_indices: bool):
     # are known-distinct and the check matters. On a (B, n) batch, rows
     # sort independently — duplicates only matter within a request, but
     # any duplicated row tags the whole batch (one shared plan).
+    return bool(detect_duplicates(x)), False
+
+
+@jax.jit
+def detect_duplicates(x: jax.Array) -> jax.Array:
+    """Whether some row of x (the last axis) holds a key twice: its sort,
+    compare and any as one program, `jit_detect_duplicates` in a trace."""
     s = jnp.sort(x, axis=-1)
-    return bool(jnp.any(s[..., 1:] == s[..., :-1])), False
+    return jnp.any(s[..., 1:] == s[..., :-1])
 
 
 def make_plan(x: jax.Array, spec: SortSpec, p: int,

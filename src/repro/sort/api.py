@@ -25,7 +25,7 @@ import numpy as np
 from repro.core.ams import ams_sample_size
 from repro.core.sample_sort import default_regular_s, default_total_sample
 from repro.kernels import dispatch
-from repro.runtime import chaos
+from repro.runtime import chaos, spans
 from repro.sort import driver, verify
 from repro.sort.adapters import BatchedSortOutput, SortOutput, make_plan
 from repro.sort.partitioners import ShardCtx, get_partitioner
@@ -166,7 +166,8 @@ def _sort_impl(x, spec: SortSpec, want_indices: bool) -> SortOutput:
         raise ValueError(f"sort expects a 1-D key array, got shape {x.shape}")
     p, names, sizes = _mesh_axes(spec, part)
 
-    plan = make_plan(x, spec, p, want_indices=want_indices)
+    with spans.span(spans.PLAN):
+        plan = make_plan(x, spec, p, want_indices=want_indices)
     enc = plan.encode(x)
     probes = (plan.encode_probes(spec.initial_probes)
               if spec.initial_probes is not None else None)
@@ -191,7 +192,8 @@ def _sort_impl(x, spec: SortSpec, want_indices: bool) -> SortOutput:
         raw, audit_vec = verify.split_raw(raw)
     elif spec.verify != "off":   # p == 1 short-circuit bypasses sort_fn
         audit_vec = verify.audit_p1(enc, raw[0], raw[1], spec.verify)
-    out = plan.decode(raw)
+    with spans.span(spans.DECODE):
+        out = plan.decode(raw)
     out._audit_vec = audit_vec
     out._audit_expected = plan.n + plan.n_pad
     return out
@@ -205,7 +207,8 @@ def _sort_batched_impl(xs, spec: SortSpec,
             f"sort_batched expects a (B, n) key array, got shape {xs.shape}")
     p, names, sizes = _mesh_axes(spec, part)
 
-    plan = make_plan(xs, spec, p, want_indices=want_indices)
+    with spans.span(spans.PLAN):
+        plan = make_plan(xs, spec, p, want_indices=want_indices)
     enc = plan.encode(xs)
     probes = (plan.encode_probes(spec.initial_probes)
               if spec.initial_probes is not None else None)
@@ -231,7 +234,8 @@ def _sort_batched_impl(xs, spec: SortSpec,
         raw, audit_vec = verify.split_raw(raw)
     elif spec.verify != "off":   # p == 1 short-circuit bypasses sort_fn
         audit_vec = verify.audit_p1(enc, raw[0], raw[1], spec.verify)
-    out = plan.decode_batched(raw)
+    with spans.span(spans.DECODE):
+        out = plan.decode_batched(raw)
     out._audit_vec = audit_vec
     out._audit_expected = plan.n + plan.n_pad
     return out
@@ -262,7 +266,8 @@ def _host_overflow(out) -> int:
     """Materialize the overflow counter — the retry policy's one
     deliberate host sync per launch (max over the batch on the batched
     path, where `overflow` is (B,))."""
-    return int(np.max(np.asarray(out.overflow)))
+    with spans.span(spans.OVERFLOW_SYNC):
+        return int(np.max(np.asarray(out.overflow)))
 
 
 def _warm_started(spec: SortSpec, out) -> SortSpec:
@@ -494,8 +499,9 @@ def sort(x, spec: SortSpec | None = None, **overrides) -> SortOutput:
     spec = _as_spec(spec, overrides)
     if spec.batch:
         return sort_batched(x, spec)
-    return _with_policies(
-        lambda s: _sort_impl(x, s, want_indices=False), spec)
+    with spans.span(spans.FRONT_DOOR):
+        return _with_policies(
+            lambda s: _sort_impl(x, s, want_indices=False), spec)
 
 
 def sort_batched(xs, spec: SortSpec | None = None, **overrides):
@@ -515,11 +521,13 @@ def sort_batched(xs, spec: SortSpec | None = None, **overrides):
     for every shape bucket already seen (DESIGN.md Section 6).
     """
     spec = _as_spec(spec, overrides)
-    if isinstance(xs, (list, tuple)):
-        return _sort_batched_buckets(xs, spec)
-    return _with_policies(
-        lambda s: _sort_batched_impl(jnp.asarray(xs), s, want_indices=False),
-        spec, batched=True)
+    with spans.span(spans.FRONT_DOOR):
+        if isinstance(xs, (list, tuple)):
+            return _sort_batched_buckets(xs, spec)
+        return _with_policies(
+            lambda s: _sort_batched_impl(jnp.asarray(xs), s,
+                                         want_indices=False),
+            spec, batched=True)
 
 
 def gather_perm_checked(out: "SortOutput", what: str) -> np.ndarray:
@@ -546,8 +554,9 @@ def argsort(x, spec: SortSpec | None = None, **overrides) -> np.ndarray:
     Raises if the exchange dropped keys (the result must be exact);
     `on_overflow="retry"`/"spill" recover instead of raising."""
     spec = dataclasses.replace(_as_spec(spec, overrides), stable=True)
-    out = _with_policies(
-        lambda s: _sort_impl(x, s, want_indices=True), spec)
+    with spans.span(spans.FRONT_DOOR):
+        out = _with_policies(
+            lambda s: _sort_impl(x, s, want_indices=True), spec)
     return gather_perm_checked(out, "argsort")
 
 
@@ -561,8 +570,9 @@ def sort_kv(keys, values, spec: SortSpec | None = None, **overrides):
         raise ValueError(f"values leading dim {values.shape[:1]} != "
                          f"keys shape {keys.shape}")
     spec = dataclasses.replace(_as_spec(spec, overrides), stable=True)
-    out = _with_policies(
-        lambda s: _sort_impl(keys, s, want_indices=True), spec)
+    with spans.span(spans.FRONT_DOOR):
+        out = _with_policies(
+            lambda s: _sort_impl(keys, s, want_indices=True), spec)
     order = gather_perm_checked(out, "sort_kv")
     return out.gather(), values[order]
 

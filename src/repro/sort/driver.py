@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.common import hi_sentinel, lo_sentinel
 from repro.parallel.compat import make_mesh, shard_map
+from repro.runtime import compile_cache, spans
 
 
 class MeshPlan(NamedTuple):
@@ -119,13 +120,18 @@ class ExecutableCache:
 
     def stats(self) -> dict:
         """Counter snapshot for metrics consumers (plain dict, safe to
-        diff: the serving layer attributes per-batch deltas to buckets)."""
+        diff: the serving layer attributes per-batch deltas to buckets).
+        `compiles` is the process's backend compiles so far
+        (`repro.runtime.compile_cache.compile_count`), eager ops' too: it
+        moves on a warm path where `misses` cannot, and `clear()` leaves
+        it alone."""
         with self._lock:
             total = self.hits + self.misses
             return {"size": len(self._fns), "max_entries": self.max_entries,
                     "hits": self.hits, "misses": self.misses,
                     "evictions": self.evictions, "traces": self.traces,
-                    "hit_rate": self.hits / total if total else 0.0}
+                    "hit_rate": self.hits / total if total else 0.0,
+                    "compiles": compile_cache.compile_count()}
 
     def clear(self):
         with self._lock:
@@ -270,7 +276,8 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
     p = plan.p
     n_real = x.shape[0] if n_real is None else n_real
     if p == 1:
-        out = (local_sort_fn or jnp.sort)(x)
+        with spans.span(spans.LAUNCH):
+            out = (local_sort_fn or jnp.sort)(x)
         return (out[None], jnp.full((1,), n_real, jnp.int32),
                 jnp.zeros((0,), x.dtype), jnp.zeros((0,), jnp.int32),
                 jnp.zeros((), jnp.int32), None)
@@ -302,8 +309,9 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
             in_specs=(sharded, P()),
             out_specs=(sharded, sharded, P(), P(), P(), P())))
 
-    fn = exec_cache.get_or_build(cache_key, build)
-    out, counts, keys, ranks, ovf, stats = fn(xs, jr.key(seed))
+    with spans.span(spans.LAUNCH):
+        fn = exec_cache.get_or_build(cache_key, build)
+        out, counts, keys, ranks, ovf, stats = fn(xs, jr.key(seed))
     out = out.reshape((p,) + out.shape[naxes:])
     counts = counts.reshape(p)
     if n_pad:   # our sentinel pads may have been counted as keys
@@ -334,7 +342,8 @@ def run_batched(sort_fn, xs, *, mesh=None, axis_names=("sort",), sizes=None,
     batch, n = xs.shape
     n_real = n if n_real is None else n_real
     if p == 1:
-        out = (local_sort_fn or (lambda v: jnp.sort(v, axis=-1)))(xs)
+        with spans.span(spans.LAUNCH):
+            out = (local_sort_fn or (lambda v: jnp.sort(v, axis=-1)))(xs)
         return (out[:, None, :], jnp.full((batch, 1), n_real, jnp.int32),
                 jnp.zeros((batch, 0), xs.dtype),
                 jnp.zeros((batch, 0), jnp.int32),
@@ -371,8 +380,9 @@ def run_batched(sort_fn, xs, *, mesh=None, axis_names=("sort",), sizes=None,
             in_specs=(sharded, P()),
             out_specs=(sharded, sharded, P(), P(), P(), P())))
 
-    fn = exec_cache.get_or_build(cache_key, build)
-    out, counts, keys, ranks, ovf, stats = fn(xsr, jr.key(seed))
+    with spans.span(spans.LAUNCH):
+        fn = exec_cache.get_or_build(cache_key, build)
+        out, counts, keys, ranks, ovf, stats = fn(xsr, jr.key(seed))
     out = out.reshape((batch, p) + out.shape[1 + naxes:])
     counts = counts.reshape(batch, p)
     if n_pad:   # our sentinel pads may have been counted as keys
@@ -390,6 +400,11 @@ def masked_concat(shards, counts, total=None) -> np.ndarray:
     dropped via out-of-range indices), replacing the old host Python loop.
     Returns NumPy, like the old `gather_sorted`.
     """
+    with spans.span(spans.GATHER):
+        return _masked_concat(shards, counts, total)
+
+
+def _masked_concat(shards, counts, total):
     shards = jnp.asarray(shards)
     counts_np = np.asarray(counts).astype(np.int64)
     total = int(counts_np.sum()) if total is None else total

@@ -39,6 +39,7 @@ from repro.core.sample_sort import (
 from repro.core.splitters import (
     ROUND_COLLECTIVES, SplitterStats, hss_splitters, hss_splitters_batched)
 from repro.kernels import dispatch
+from repro.runtime import spans
 from repro.sort.driver import factor_stages
 from repro.sort.spec import SortSpec
 
@@ -123,12 +124,15 @@ class Partitioner:
         raise NotImplementedError
 
     def sharded(self, local, rng, ctx: ShardCtx):
-        """Full shard-level sort: local sort -> splitters -> exchange."""
+        """Full shard-level sort: local sort -> splitters -> exchange, each
+        phase under its scope (`repro.runtime.spans`)."""
         sort_local = (ctx.spec.local_sort_fn
                       or dispatch.local_sort_fn(ctx.spec.kernel_policy))
-        local_sorted = sort_local(local)
-        keys, ranks, s_ovf, stats = self.splitters(
-            local_sorted, dataclasses.replace(ctx, rng=rng))
+        with spans.scope(spans.LOCAL_SORT):
+            local_sorted = sort_local(local)
+        with spans.scope(spans.SPLITTERS):
+            keys, ranks, s_ovf, stats = self.splitters(
+                local_sorted, dataclasses.replace(ctx, rng=rng))
         out, n_valid, e_ovf = exchange(
             local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
             cfg=ctx.ex_cfg, eps=ctx.spec.eps)
@@ -147,9 +151,11 @@ class Partitioner:
         sort_local = (dispatch.local_sort_batched_fn(ctx.spec.kernel_policy)
                       if ctx.spec.local_sort_fn is None
                       else jax.vmap(ctx.spec.local_sort_fn))
-        local_sorted = sort_local(local)
-        keys, ranks, s_ovf, stats = self.splitters_batched(
-            local_sorted, dataclasses.replace(ctx, rng=rng))
+        with spans.scope(spans.LOCAL_SORT):
+            local_sorted = sort_local(local)
+        with spans.scope(spans.SPLITTERS):
+            keys, ranks, s_ovf, stats = self.splitters_batched(
+                local_sorted, dataclasses.replace(ctx, rng=rng))
         out, n_valid, e_ovf = exchange_batched(
             local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
             cfg=ctx.ex_cfg, eps=ctx.spec.eps)
@@ -165,8 +171,9 @@ class Partitioner:
         splitter rounds see the sentinel tail as genuine max keys, which
         only biases the top splitters upward — grouping (not total order)
         is the contract here, so that is harmless."""
-        keys, ranks, s_ovf, stats = self.splitters(
-            local_sorted, dataclasses.replace(ctx, rng=rng))
+        with spans.scope(spans.SPLITTERS):
+            keys, ranks, s_ovf, stats = self.splitters(
+                local_sorted, dataclasses.replace(ctx, rng=rng))
         out, n_out, e_ovf = exchange(
             local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
             cfg=ex_cfg if ex_cfg is not None else ctx.ex_cfg,
@@ -177,8 +184,9 @@ class Partitioner:
                                  n_valid=None, ex_cfg=None):
         """Batched `partition_sorted`: (B, n_local) sorted rows, n_valid
         None | scalar | (B,)."""
-        keys, ranks, s_ovf, stats = self.splitters_batched(
-            local_sorted, dataclasses.replace(ctx, rng=rng))
+        with spans.scope(spans.SPLITTERS):
+            keys, ranks, s_ovf, stats = self.splitters_batched(
+                local_sorted, dataclasses.replace(ctx, rng=rng))
         out, n_out, e_ovf = exchange_batched(
             local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
             cfg=ex_cfg if ex_cfg is not None else ctx.ex_cfg,

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.common import hi_sentinel, lo_sentinel
+from repro.runtime import spans
 
 TIERS = ("off", "cheap", "full")
 _LANES = {"cheap": 2, "full": 4}
@@ -226,6 +227,12 @@ def audited(sort_fn, *, tier: str, axis_names, sizes, batched: bool,
 
     def wrapped(local, rng):
         out, n_valid, keys, ranks, ovf, stats = sort_fn(local, rng)
+        with spans.scope(spans.AUDIT):
+            o, vec = audit(local, out, n_valid, keys)
+        out = o if batched else o[0]
+        return out, n_valid, keys, ranks, ovf, (stats, vec)
+
+    def audit(local, out, n_valid, keys):
         if batched:
             o, loc = out, local
             nv = jnp.asarray(n_valid, jnp.int32)
@@ -257,9 +264,7 @@ def audited(sort_fn, *, tier: str, axis_names, sizes, batched: bool,
         # vec to 64-bit — but the lane algebra NEEDS the psum to wrap mod
         # 2^32 (per-shard lane sums already wrapped; a 64-bit reduction
         # makes identical multisets disagree by multiples of 2^32)
-        vec = jax.lax.psum(vec.astype(jnp.uint32), axis_names)
-        out = o if batched else o[0]
-        return out, n_valid, keys, ranks, ovf, (stats, vec)
+        return o, jax.lax.psum(vec.astype(jnp.uint32), axis_names)
 
     return wrapped
 

@@ -8,6 +8,8 @@ the chip. Nothing runs; results are checked by the parity tests and by
 `chip_smoke.py` on the chip. The ragged exchange, which only XLA:TPU
 compiles, is compiled here too, over the four chips of the described host.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -100,3 +102,34 @@ def test_ragged_exchange_compiles_for_v5e_in_linear_memory(topo):
     assert "ragged-all-to-all" in compiled.as_text()
     # one key per 128-lane row would need 512 bytes of each buffer per key
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 4 * n
+
+
+def _kernel_op_names(hlo: str) -> set:
+    """Names of the compiled program's Pallas launches, numbers dropped."""
+    return {re.sub(r"\.\d+$", "", m) for m in re.findall(
+        r"%(\S+) = \S+ custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"",
+        hlo)}
+
+
+@pytest.mark.parametrize("entry, shape, want", [
+    # past one VMEM block (2^16 keys) each takes strided HBM passes too
+    ("merge", (2, 1 << 16), {"merge_sorted_runs_network",
+                             "merge_sorted_runs_strided"}),
+    ("sort", (1 << 17,), {"local_sort_network", "local_sort_strided"}),
+    ("ranks", (1 << 16,), {"probe_ranks_histogram"}),
+])
+def test_kernels_carry_their_names_on_v5e(one_chip, entry, shape, want):
+    """Each Pallas launch is named by `repro.runtime.spans`: the merge's
+    launches all start with `merge_sorted_runs`, which the benchmark's
+    merge reader matches, and the local sort's never do."""
+    x = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    if entry == "merge":
+        hlo = _compile_text(
+            lambda r: mops.merge_sorted_runs(r, interpret=False), x)
+    elif entry == "sort":
+        hlo = _compile_text(lambda v: bops.local_sort(v, interpret=False), x)
+    else:
+        probes = jax.ShapeDtypeStruct((255,), jnp.int32, sharding=one_chip)
+        hlo = _compile_text(
+            lambda k, q: hops.probe_ranks(k, q, interpret=False), x, probes)
+    assert _kernel_op_names(hlo) == want
