@@ -138,19 +138,14 @@ def exchange_dense(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
     n = local_sorted.shape[0]
     cap = cfg.pair_cap(n, p)
     out_cap = cfg.out_cap(n, p, eps)
-    sent_hi = hi_sentinel(local_sorted.dtype)
 
+    dispatch, gather_runs = _kernels()
     with spans.scope(spans.EXCHANGE):
         starts, counts = destination_slices(local_sorted, splitter_keys,
                                             n_valid)
         sent_counts = jnp.minimum(counts, cap)
         overflow = jax.lax.psum(jnp.sum(counts - sent_counts), axis_name)
-
-        idx = starts[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
-        valid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
-                 < sent_counts[:, None])
-        buf = jnp.where(valid, local_sorted[jnp.clip(idx, 0, n - 1)],
-                        sent_hi)
+        buf = gather_runs(local_sorted, starts, sent_counts, cap)
 
         recv = jax.lax.all_to_all(buf, axis_name, split_axis=0,
                                   concat_axis=0, tiled=False)
@@ -158,7 +153,6 @@ def exchange_dense(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
             sent_counts.reshape(p, 1), axis_name, 0, 0,
             tiled=False).reshape(p)
     # p sorted sentinel-tailed runs of cap keys -> one k-way merge.
-    dispatch, _ = _kernels()
     with spans.scope(spans.MERGE):
         merged = dispatch.merge_runs(recv, policy=cfg.kernel_policy)
         out = _cap_to(merged, out_cap)
@@ -208,11 +202,7 @@ def exchange_dense_spill(local_sorted, splitter_keys, *, axis_name, p, cfg,
         sent_counts = jnp.minimum(counts, cap)
 
         # -- dense channel (identical to exchange_dense)
-        idx = starts[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
-        valid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
-                 < sent_counts[:, None])
-        buf = jnp.where(valid, local_sorted[jnp.clip(idx, 0, n - 1)],
-                        sent_hi)
+        buf = gather_runs(local_sorted, starts, sent_counts, cap)
         recv = jax.lax.all_to_all(buf, axis_name, split_axis=0,
                                   concat_axis=0, tiled=False)
         recv_counts = jax.lax.all_to_all(
@@ -402,22 +392,16 @@ def exchange_dense_batched(local_sorted, splitter_keys, *, axis_name, p, cfg,
     b, n = local_sorted.shape
     cap = cfg.pair_cap(n, p)
     out_cap = cfg.out_cap(n, p, eps)
-    sent_hi = hi_sentinel(local_sorted.dtype)
 
+    dispatch, gather_runs = _kernels()
     with spans.scope(spans.EXCHANGE):
         starts, counts = jax.vmap(destination_slices)(
             local_sorted, splitter_keys, _rows_valid(n_valid, b, n))  # (B, p)
         sent_counts = jnp.minimum(counts, cap)
         overflow = jax.lax.psum(
             jnp.sum(counts - sent_counts, axis=1), axis_name)  # (B,)
-
-        idx = (starts[:, :, None]
-               + jnp.arange(cap, dtype=jnp.int32)[None, None, :])
-        valid = (jnp.arange(cap, dtype=jnp.int32)[None, None, :]
-                 < sent_counts[:, :, None])
-        rows = jnp.take_along_axis(local_sorted, jnp.clip(idx, 0, n - 1)
-                                   .reshape(b, -1), axis=1).reshape(b, p, cap)
-        buf = jnp.where(valid, rows, sent_hi)                # (B, p, cap)
+        buf = jax.vmap(gather_runs, in_axes=(0, 0, 0, None))(
+            local_sorted, starts, sent_counts, cap)          # (B, p, cap)
 
         # ONE all_to_all for the whole batch: shard axis leading.
         recv = jax.lax.all_to_all(jnp.swapaxes(buf, 0, 1), axis_name, 0, 0,
@@ -426,7 +410,6 @@ def exchange_dense_batched(local_sorted, splitter_keys, *, axis_name, p, cfg,
         recv_counts = jax.lax.all_to_all(
             sent_counts.T[..., None], axis_name, 0, 0, tiled=False)[..., 0].T
 
-    dispatch, _ = _kernels()
     with spans.scope(spans.MERGE):
         merged = dispatch.merge_runs_batched(recv, policy=cfg.kernel_policy)
         out = _cap_to(merged, out_cap)

@@ -133,13 +133,21 @@ def merge_flat_runs(x, run: int, vmem_block: int | None = None,
 def gather_runs(buf, starts, counts, slot: int):
     """Extract k runs at traced offsets into a sentinel-padded (k, slot)
     buffer. Slots past counts[i] hold the sentinel; entries of a run beyond
-    `slot` are NOT represented (callers detect via counts > slot)."""
-    cap = buf.shape[0]
+    `slot` are NOT represented (callers detect via counts > slot).
+
+    Each run is one contiguous window, so it is copied as one: a
+    `dynamic_slice` of `slot` keys out of `buf` padded by `slot`
+    sentinels, which no start in [0, len(buf)] can push past the end. An
+    element-indexed gather of the same windows costs XLA:TPU a tile per
+    key. Requires 0 <= starts[i] and starts[i] + counts[i] <= len(buf)."""
+    sent = hi_sentinel(buf.dtype)
+    padded = jnp.concatenate([buf, jnp.full((slot,), sent, buf.dtype)])
+    starts = jnp.clip(jnp.asarray(starts, jnp.int32), 0, buf.shape[0])
+    vals = jnp.stack([jax.lax.dynamic_slice(padded, (starts[i],), (slot,))
+                      for i in range(starts.shape[0])])
     pos = jnp.arange(slot, dtype=jnp.int32)[None, :]
-    idx = jnp.asarray(starts, jnp.int32)[:, None] + pos
     valid = pos < jnp.asarray(counts, jnp.int32)[:, None]
-    vals = buf[jnp.clip(idx, 0, cap - 1)]
-    return jnp.where(valid, vals, hi_sentinel(buf.dtype))
+    return jnp.where(valid, vals, sent)
 
 
 @functools.partial(jax.jit, static_argnames=("slot", "vmem_block", "interpret"))

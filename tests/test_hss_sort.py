@@ -146,3 +146,89 @@ def test_ragged_row_aligned_chunks(rng, n_valid):
         sent[lo:lo + counts[d]] = True
     assert np.all(flat[~sent] == np.iinfo(np.int32).max)
     assert sent.sum() == (n if n_valid is None else n_valid)
+
+
+def _exchange_model(local, splitters, n_valid, cap, out_cap):
+    """NumPy model of a capacity-padded exchange over p sorted shards: each
+    source sends destination d the first min(count, cap) keys of its slice
+    [searchsorted(splitters), n_valid); each destination keeps the first
+    out_cap of what it received, sorted. Returns every destination's
+    sentinel-padded output, its kept count, and the overflow all share."""
+    p, n = local.shape
+    recv = [[] for _ in range(p)]
+    overflow = 0
+    for s in range(p):
+        b = np.minimum(np.searchsorted(local[s], splitters, side="left"),
+                       n_valid)
+        starts, ends = np.r_[0, b], np.r_[b, n_valid]
+        for d in range(p):
+            kept = min(ends[d] - starts[d], cap)
+            overflow += ends[d] - starts[d] - kept
+            recv[d].append(local[s, starts[d]:starts[d] + kept])
+    out = np.full((p, out_cap), np.iinfo(local.dtype).max, local.dtype)
+    counts = np.zeros(p, np.int32)
+    for d in range(p):
+        keys = np.sort(np.concatenate(recv[d]))[:out_cap]
+        overflow += sum(map(len, recv[d])) - len(keys)
+        out[d, :len(keys)] = keys
+        counts[d] = len(keys)
+    return out, counts, overflow
+
+
+@pytest.mark.parametrize("strategy", ["dense", "dense_spill", "dense_batched"])
+@pytest.mark.parametrize("split", ["balanced", "skewed", "n_valid"])
+def test_dense_exchange_matches_send_side_model(rng, strategy, split):
+    """The dense exchanges' send buffers hold each destination's slice,
+    capped at pair_cap: what every destination receives, keeps and counts
+    as overflow matches the NumPy model bit for bit. The skewed split puts
+    one destination's count past pair_cap (dense drops and counts the
+    excess, dense_spill carries it on its spill channel)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.exchange import (exchange_dense, exchange_dense_batched,
+                                     exchange_dense_spill)
+    from repro.parallel.compat import shard_map
+
+    p, n, rows = 4, 256, 2 if strategy == "dense_batched" else 1
+    n_valid = 200 if split == "n_valid" else n
+    sent = np.iinfo(np.int32).max
+    local = np.sort(rng.integers(0, 1000, (p, rows, n)), axis=-1)
+    local = local.astype(np.int32)
+    local[..., n_valid:] = sent
+    splitters = np.array([900, 950, 975] if split == "skewed"
+                         else [250, 500, 750], np.int32)
+    splitters = np.stack([splitters + r for r in range(rows)])
+    cfg = ExchangeConfig(strategy=strategy.replace("_batched", ""))
+    cap = n if strategy == "dense_spill" else cfg.pair_cap(n, p)
+    out_cap = cfg.out_cap(n, p, 0.05)
+    fn = {"dense": exchange_dense, "dense_spill": exchange_dense_spill,
+          "dense_batched": exchange_dense_batched}[strategy]
+
+    def body(shard, spl):
+        if strategy == "dense_batched":
+            return tuple(v[None] for v in fn(
+                shard[0], spl, axis_name="sort", p=p, cfg=cfg, eps=0.05,
+                n_valid=n_valid))
+        return tuple(v[None] for v in fn(
+            shard[0, 0], spl[0], axis_name="sort", p=p, cfg=cfg, eps=0.05,
+            n_valid=n_valid))
+
+    mesh = make_mesh((p,), ("sort",), devices=jax.devices()[:p])
+    out, counts, overflow = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(P("sort"), P()),
+        out_specs=(P("sort"),) * 3))(jnp.asarray(local),
+                                     jnp.asarray(splitters))
+    out, counts, overflow = (np.asarray(out), np.asarray(counts),
+                             np.asarray(overflow))
+    if strategy != "dense_batched":
+        out, counts, overflow = out[:, None], counts[:, None], overflow[:, None]
+    for r in range(rows):
+        want_out, want_counts, want_ovf = _exchange_model(
+            local[:, r], splitters[r], n_valid, cap, out_cap)
+        np.testing.assert_array_equal(out[:, r], want_out)
+        np.testing.assert_array_equal(counts[:, r], want_counts)
+        assert np.all(overflow[:, r] == want_ovf)
+        if split == "skewed":   # destination 0's slice is past pair_cap
+            first = [np.searchsorted(row, splitters[r, 0])
+                     for row in local[:, r]]
+            assert min(first) > cfg.pair_cap(n, p)

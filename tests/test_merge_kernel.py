@@ -110,6 +110,49 @@ def _ragged_buf(rng, cap, counts, dtype):
     return buf, jnp.asarray(starts), jnp.asarray(np.asarray(counts, np.int32))
 
 
+def _gather_runs_ref(buf, starts, counts, slot):
+    """The element-gather formula gather_runs replaced."""
+    pos = np.arange(slot)[None, :]
+    idx = np.clip(np.asarray(starts)[:, None] + pos, 0, buf.shape[0] - 1)
+    return np.where(pos < np.asarray(counts)[:, None], buf[idx],
+                    np.asarray(hi_sentinel(jnp.dtype(buf.dtype))))
+
+
+@pytest.mark.parametrize("dtype,n,starts,counts,slot,batched", [
+    (np.int32, 100, [0, 30, 55, 90], [30, 25, 35, 10], 40, False),
+    (np.uint32, 100, [0, 30, 55, 90], [30, 25, 35, 10], 40, False),
+    (np.float32, 100, [0, 30, 55, 90], [30, 25, 35, 10], 40, False),
+    (np.int32, 64, [0, 10, 10, 40], [10, 0, 30, 24], 32, False),   # empty
+    (np.int32, 64, [0, 40, 64], [40, 24, 0], 32, False),   # start at the end
+    (np.int32, 64, [0, 32], [32, 32], 32, False),   # window ends at the end
+    (np.float32, 24, [0, 5, 20], [5, 15, 4], 40, False),   # slot > len(buf)
+    (np.int32, 96, [[0, 12, 50, 96], [0, 40, 41, 96], [0, 0, 0, 48]],
+     [[12, 38, 46, 0], [40, 1, 48, 0], [0, 0, 48, 48]], 48, True),   # vmap
+], ids=["int32", "uint32", "float32", "count0", "start_at_len",
+        "ends_at_len", "slot_past_len", "vmap"])
+def test_gather_runs_matches_element_gather(rng, dtype, n, starts, counts,
+                                            slot, batched):
+    """Contiguous window copies return what the element gather returned,
+    wherever 0 <= start and start + count <= len(buf)."""
+    starts = np.asarray(starts, np.int32)
+    counts = np.asarray(counts, np.int32)
+    if not batched:
+        buf = np.sort(_keys(rng, n, dtype))
+        got = mops.gather_runs(jnp.asarray(buf), jnp.asarray(starts),
+                               jnp.asarray(counts), slot)
+        assert got.shape == (len(starts), slot) and got.dtype == buf.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got), _gather_runs_ref(buf, starts, counts, slot))
+        return
+    bufs = np.sort(_keys(rng, len(starts) * n, dtype).reshape(-1, n), axis=1)
+    got = jax.vmap(mops.gather_runs, in_axes=(0, 0, 0, None))(
+        jnp.asarray(bufs), jnp.asarray(starts), jnp.asarray(counts), slot)
+    for b in range(len(bufs)):
+        np.testing.assert_array_equal(
+            np.asarray(got[b]),
+            _gather_runs_ref(bufs[b], starts[b], counts[b], slot))
+
+
 @pytest.mark.parametrize("dtype", [np.int32, np.uint32, np.float32])
 def test_merge_ragged_runs_matches_oracle(rng, dtype):
     counts = [37, 0, 1, 80, 0, 23]    # empty and single-key runs included
