@@ -17,6 +17,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
+#: Sort Benchmark records (repro.sort.sort_records): 100 bytes as 25 uint32
+#: words, big-endian, so unsigned word order is byte (memcmp) order; the
+#: 10-byte key fills words 0, 1 and the top half of word 2. A sort compares
+#: the first 3 words whole, so records with equal keys come out ordered by
+#: bytes 10 and 11, which any order of equal keys allows.
+RECORD_BYTES = 100
+RECORD_WORDS = 25
+RECORD_KEY_WORDS = 3
+
+
 def hi_sentinel(dtype) -> Any:
     dtype = jnp.dtype(dtype)
     if jnp.issubdtype(dtype, jnp.floating):

@@ -530,3 +530,49 @@ def exchange_batched(local_sorted, splitter_keys, *, axis_name, p,
         raise ValueError(f"unknown exchange strategy {cfg.strategy!r}") from None
     return fn(local_sorted, splitter_keys, axis_name=axis_name, p=p,
               cfg=cfg, eps=eps, n_valid=n_valid)
+
+
+def exchange_records(records, key_planes, splitter_keys, *, axis_name, p,
+                     cfg: ExchangeConfig, eps: float):
+    """The dense exchange for records held as word planes.
+
+    records: the shard's records, (words, n_local) uint32, sorted;
+    key_planes: the word tuples they are sorted by, (n_local,) planes, the
+    last each record's global index; splitter_keys: (p-1, words) tuples
+    from `hss_splitters_lex`. Each destination's records are one
+    contiguous window of every plane, cut with `gather_runs` (pair_cap
+    records, as `exchange_dense` cuts keys), so one (words, p, pair_cap)
+    all-to-all moves whole records; the received runs are merged with
+    their payload (`dispatch.merge_records`). Returns (out (words,
+    out_cap), n_valid, overflow, sent (p, 2)): per chip, the record slots
+    it put on the wire to other chips and how many of them held records.
+    """
+    from repro.core.splitters import lex_searchsorted
+    n = records.shape[1]
+    cap = cfg.pair_cap(n, p)
+    out_cap = cfg.out_cap(n, p, eps)
+
+    dispatch, gather_runs = _kernels()
+    with spans.scope(spans.EXCHANGE):
+        b = jnp.minimum(lex_searchsorted(key_planes, splitter_keys), n)
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), b])
+        counts = jnp.concatenate([b, jnp.full((1,), n, jnp.int32)]) - starts
+        sent_counts = jnp.minimum(counts, cap)
+        overflow = jax.lax.psum(jnp.sum(counts - sent_counts), axis_name)
+        buf = gather_runs(records, starts, sent_counts, cap)
+        recv = jax.lax.all_to_all(buf, axis_name, split_axis=1,
+                                  concat_axis=1, tiled=False)
+        recv_counts = jax.lax.all_to_all(
+            sent_counts.reshape(p, 1), axis_name, 0, 0,
+            tiled=False).reshape(p)
+        me = jax.lax.axis_index(axis_name)
+        sent = jax.lax.all_gather(
+            jnp.stack([jnp.int32((p - 1) * cap),
+                       jnp.sum(sent_counts) - sent_counts[me]]), axis_name)
+    with spans.scope(spans.MERGE):
+        out = dispatch.merge_records(recv, recv_counts, out_cap,
+                                     len(key_planes) - 1)
+    n_recv = jnp.sum(recv_counts)
+    trunc = jnp.maximum(n_recv - out_cap, 0)
+    overflow = overflow + jax.lax.psum(trunc, axis_name)
+    return out, n_recv - trunc, overflow, sent

@@ -25,7 +25,8 @@ logical-p simulator in repro.core.simulator.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,14 @@ def init_state(p: int, n: int, dtype) -> SplitterState:
     )
 
 
+def _pick(mask, a, b):
+    """jnp.where over keys that may be tuples: a (m,) mask picks whole
+    (m, words) rows of lexicographic keys (repro.core.splitters, records)."""
+    if a.ndim > mask.ndim:
+        mask = mask[:, None]
+    return jnp.where(mask, a, b)
+
+
 def refine(state: SplitterState, probes: jax.Array, probe_ranks: jax.Array,
            targets: jax.Array, tol) -> SplitterState:
     """Tighten every splitter interval with freshly ranked probes.
@@ -107,14 +116,14 @@ def refine(state: SplitterState, probes: jax.Array, probe_ranks: jax.Array,
     jm = jnp.maximum(j - 1, 0)
     has_lo = j > 0
     cand_lo_rank = jnp.where(has_lo, probe_ranks[jm], 0)
-    cand_lo_key = jnp.where(has_lo, probes[jm], state.lo_key)
+    cand_lo_key = _pick(has_lo, probes[jm], state.lo_key)
 
     take_lo = cand_lo_rank > state.lo_rank
     take_hi = cand_hi_rank < state.hi_rank
     lo_rank = jnp.where(take_lo, cand_lo_rank, state.lo_rank)
-    lo_key = jnp.where(take_lo, cand_lo_key, state.lo_key)
+    lo_key = _pick(take_lo, cand_lo_key, state.lo_key)
     hi_rank = jnp.where(take_hi, cand_hi_rank, state.hi_rank)
-    hi_key = jnp.where(take_hi, cand_hi_key, state.hi_key)
+    hi_key = _pick(take_hi, cand_hi_key, state.hi_key)
     satisfied = ((targets - lo_rank) <= tol) | ((hi_rank - targets) <= tol)
     return SplitterState(lo_rank, hi_rank, lo_key, hi_key, satisfied)
 
@@ -153,7 +162,7 @@ def choose_splitters(state: SplitterState, targets: jax.Array):
     d_lo = targets - state.lo_rank
     d_hi = state.hi_rank - targets
     pick_lo = d_lo <= d_hi
-    keys = jnp.where(pick_lo, state.lo_key, state.hi_key)
+    keys = _pick(pick_lo, state.lo_key, state.hi_key)
     ranks = jnp.where(pick_lo, state.lo_rank, state.hi_rank)
     return keys, ranks
 
@@ -171,6 +180,25 @@ def _sample_round(local_sorted, state, prob, cap, rng, kernel_policy="auto"):
     return vals, n_hit - overflow, overflow
 
 
+class ShardKeys(NamedTuple):
+    """How the splitter rounds see one shard's sorted keys: scalar keys
+    (`hss_splitters`) or lexicographic tuples of words (`hss_splitters_lex`).
+
+    init(n)            the initial SplitterState for N = n keys
+    sample(state, prob, cap, rng) -> (vals, n_sampled, overflow): a
+                       sentinel-padded buffer of cap sampled active keys
+    gather(vals)       every shard's sample, end to end
+    sort(probes)       the gathered sample, sorted
+    ranks(probes)      this shard's count of keys below each probe
+    """
+
+    init: Callable
+    sample: Callable
+    gather: Callable
+    sort: Callable
+    ranks: Callable
+
+
 def hss_splitters(
     local_sorted: jax.Array,
     *,
@@ -179,6 +207,7 @@ def hss_splitters(
     cfg: HSSConfig,
     rng: jax.Array,
     initial_probes: jax.Array | None = None,
+    shard_keys: ShardKeys | None = None,
 ):
     """Determine the p-1 splitters of a distributed sort. shard_map-resident.
 
@@ -191,13 +220,26 @@ def hss_splitters(
       initial_probes: optional sorted probe keys to warm-start round 1 with
         (e.g. the previous iteration's splitters — the ChaNGa trick, paper
         Section 7.3). Sentinel-padded, any static length.
+      shard_keys: how the rounds see the shard's keys; None for the scalar
+        keys of `local_sorted` (`hss_splitters_lex` passes word tuples,
+        and `local_sorted` then only gives the shard's length).
 
     Returns:
       (splitter_keys (p-1,), splitter_ranks (p-1,), SplitterStats) — replicated.
     """
     n_local = local_sorted.shape[0]
+    if shard_keys is None:
+        policy = cfg.kernel_policy
+        shard_keys = ShardKeys(
+            init=functools.partial(init_state, p, dtype=local_sorted.dtype),
+            sample=functools.partial(_sample_round, local_sorted,
+                                     kernel_policy=policy),
+            gather=functools.partial(jax.lax.all_gather,
+                                     axis_name=axis_name, tiled=True),
+            sort=functools.partial(dispatch.local_sort, policy=policy),
+            ranks=functools.partial(dispatch.probe_ranks, local_sorted,
+                                    policy=policy, assume_sorted=True))
     n = n_local * p
-    dtype = local_sorted.dtype
     k = cfg.resolved_rounds(p)
     cap = cfg.resolved_sample_cap(p)
     tol = jnp.int32(max(1, int(n * cfg.eps / (2 * p))))
@@ -205,12 +247,10 @@ def hss_splitters(
     f_total = float(cap * p) / 2.0  # target expected overall sample per round
     ratios = jnp.asarray(sampling_ratios(p, cfg.eps, k), jnp.float32)
 
-    state0 = init_state(p, n, dtype)
+    state0 = shard_keys.init(n)
     if initial_probes is not None:
         # Free warm-start: rank the provided probes once and refine.
-        lr = dispatch.probe_ranks(local_sorted, initial_probes,
-                                  policy=cfg.kernel_policy, assume_sorted=True)
-        pr = jax.lax.psum(lr, axis_name)
+        pr = jax.lax.psum(shard_keys.ranks(initial_probes), axis_name)
         state0 = refine(state0, initial_probes, pr, targets, tol)
 
     def round_body(carry, j):
@@ -223,15 +263,9 @@ def hss_splitters(
             prob = jnp.minimum(1.0, ratios[j] / float(n_local))
 
         def do_round(state):
-            vals, n_samp, ovf = _sample_round(
-                local_sorted, state, prob, cap, sub,
-                kernel_policy=cfg.kernel_policy)
-            probes = dispatch.local_sort(
-                jax.lax.all_gather(vals, axis_name, tiled=True),
-                policy=cfg.kernel_policy)
-            local_ranks = dispatch.probe_ranks(local_sorted, probes,
-                                               policy=cfg.kernel_policy,
-                                               assume_sorted=True)
+            vals, n_samp, ovf = shard_keys.sample(state, prob, cap, sub)
+            probes = shard_keys.sort(shard_keys.gather(vals))
+            local_ranks = shard_keys.ranks(probes)
             # one fused reduction per round: ranks + sample count + overflow
             # (explicit int32: under x64 jnp.sum promotes counts to int64,
             # which would leak into the scan carry through refine)
@@ -270,6 +304,120 @@ def hss_splitters(
         jnp.any(all_sat), 1 + jnp.argmax(all_sat), jnp.int32(k))
     stats = SplitterStats(gam, cnt, ovf, nsat, rounds_used)
     return keys, ranks, stats
+
+
+# -- lexicographic keys: tuples of unsigned words (records) -----------------
+#
+# A key is a tuple of uint32 words compared lexicographically; a shard
+# holds it as `words` planes of shape (n_local,), sorted. The last word is
+# the record's global index, so tuples are distinct even where the
+# records' keys are equal, and HSS's eps balance holds however many
+# records share a key. Splitter state keeps (p-1, words) key rows; the
+# all-ones tuple is the hi sentinel, above every real tuple because no
+# global index reaches 2^32 - 1.
+
+def lex_less(a, b, or_equal: bool = False):
+    """Whether word tuple a sorts before b (or equals it, with or_equal):
+    a, b are sequences of broadcastable uint32 arrays, most significant
+    word first."""
+    res = jnp.asarray(or_equal)
+    for x, y in reversed(list(zip(a, b))):
+        res = (x < y) | ((x == y) & res)
+    return res
+
+
+def lex_searchsorted(planes, queries):
+    """For each query row of (m, words), how many tuples of the sorted
+    planes lie below it (searchsorted side="left"): a binary search of
+    bit_length(n) steps, unrolled (no loop op in the program), each
+    reading m tuples."""
+    n = planes[0].shape[0]
+    m = queries.shape[0]
+    q = [queries[:, w] for w in range(queries.shape[1])]
+    lo = jnp.zeros((m,), jnp.int32)
+    hi = jnp.full((m,), n, jnp.int32)
+    for _ in range(max(1, n.bit_length())):
+        mid = (lo + hi) // 2
+        at = jnp.minimum(mid, n - 1)
+        below = lex_less([pl[at] for pl in planes], q)
+        open_ = lo < hi
+        lo, hi = (jnp.where(open_ & below, mid + 1, lo),
+                  jnp.where(open_ & ~below, mid, hi))
+    return lo
+
+
+def lex_sort_rows(rows):
+    """Sort (m, words) key rows lexicographically."""
+    cols = jax.lax.sort(tuple(rows[:, w] for w in range(rows.shape[1])),
+                        num_keys=rows.shape[1])
+    return jnp.stack(cols, axis=1)
+
+
+def init_state_lex(p: int, n: int, words: int) -> SplitterState:
+    m = p - 1
+    return SplitterState(
+        lo_rank=jnp.zeros((m,), jnp.int32),
+        hi_rank=jnp.full((m,), n, jnp.int32),
+        lo_key=jnp.zeros((m, words), jnp.uint32),
+        hi_key=jnp.full((m, words), hi_sentinel(jnp.uint32), jnp.uint32),
+        satisfied=jnp.zeros((m,), bool),
+    )
+
+
+def gamma_membership_lex(planes, state: SplitterState) -> jax.Array:
+    """`gamma_membership` for word tuples: whether each key lies strictly
+    inside the interval of some unsatisfied splitter, one elementwise pass
+    over the planes per splitter."""
+    inside = jnp.zeros(planes[0].shape, bool)
+    for i in range(state.lo_key.shape[0]):
+        lo = [state.lo_key[i, w] for w in range(len(planes))]
+        hi = [state.hi_key[i, w] for w in range(len(planes))]
+        inside = inside | (~state.satisfied[i] & lex_less(lo, planes)
+                           & lex_less(planes, hi))
+    return inside
+
+
+def _sample_round_lex(planes, state, prob, cap, rng):
+    """Bernoulli-sample active-interval tuples into a (cap, words) buffer.
+    The shard is sorted, so the first cap sampled positions, in order,
+    are the sample: one sort of n_local positions finds them, and cap
+    rows are read."""
+    n_local = planes[0].shape[0]
+    mask = gamma_membership_lex(planes, state) & (
+        jr.uniform(rng, (n_local,)) < prob)
+    n_hit = jnp.sum(mask.astype(jnp.int32))
+    pos = jnp.sort(jnp.where(mask, jnp.arange(n_local, dtype=jnp.int32),
+                             n_local))[:cap]
+    at = jnp.minimum(pos, n_local - 1)
+    vals = jnp.stack([jnp.where(pos < n_local, pl[at], hi_sentinel(pl.dtype))
+                      for pl in planes], axis=1)
+    overflow = jnp.maximum(n_hit - cap, 0)
+    return vals, n_hit - overflow, overflow
+
+
+def hss_splitters_lex(planes, *, axis_name: str, p: int, cfg: HSSConfig,
+                      rng: jax.Array):
+    """`hss_splitters` over lexicographic keys: `planes` is a tuple of
+    (n_local,) uint32 words, sorted as tuples, the last of them each key's
+    global index. The same rounds, sample sizes and eps; each round ranks
+    its probes with a binary search per probe (scope `probe_search`) where
+    scalar keys use the histogram kernel. Returns (splitter_keys
+    (p-1, words), splitter_ranks (p-1,), SplitterStats)."""
+    planes = tuple(planes)
+
+    def ranks(probes):
+        with spans.scope(spans.PROBE_SEARCH):
+            return lex_searchsorted(planes, probes)
+
+    keys = ShardKeys(
+        init=functools.partial(init_state_lex, p, words=len(planes)),
+        sample=functools.partial(_sample_round_lex, planes),
+        gather=functools.partial(jax.lax.all_gather, axis_name=axis_name,
+                                 tiled=True),
+        sort=lex_sort_rows,
+        ranks=ranks)
+    return hss_splitters(planes[0], axis_name=axis_name, p=p, cfg=cfg,
+                         rng=rng, shard_keys=keys)
 
 
 def hss_splitters_batched(

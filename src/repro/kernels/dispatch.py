@@ -177,3 +177,47 @@ def merge_ragged_batched(buf, starts, counts, *, policy: str = "auto",
         return jnp.sort(buf, axis=-1)
     return mops.merge_ragged_runs_batched(buf, starts, counts, slot=slot,
                                           vmem_block=vmem_block)
+
+
+def sort_planes(planes, num_keys: int):
+    """Stable sort of records held as planes: planes (words, n) -> (the
+    planes sorted by their first `num_keys` words compared as unsigned
+    words lexicographically, ties in input order; order (n,) int32, the
+    input position of each output record).
+
+    Every sort here has two operands and one key, which XLA:TPU compiles
+    in seconds where a sort of many operands or keys takes minutes: the
+    key words are sorted least significant first, each pass one stable
+    sort of the key words and the positions by one word, all rows at once
+    (rows of a 2-D sort are independent); then the payload rows are
+    sorted by each record's output position. No permutation is applied
+    as a gather. A multi-word Pallas sort is not written yet, so every
+    policy takes this path."""
+    n = planes.shape[1]
+    pos = jnp.arange(n, dtype=jnp.uint32)
+    rows = jnp.concatenate([planes[:num_keys], pos[None]])
+    for w in reversed(range(num_keys)):
+        key = jnp.broadcast_to(rows[w], rows.shape)
+        rows = jax.lax.sort((key, rows), dimension=1, num_keys=1,
+                            is_stable=True)[1]
+    order = rows[num_keys]
+    if planes.shape[0] == num_keys:
+        return rows[:num_keys], order.astype(jnp.int32)
+    dest = jax.lax.sort((order, pos), num_keys=1)[1]
+    payload = planes[num_keys:]
+    payload = jax.lax.sort(
+        (jnp.broadcast_to(dest, payload.shape), payload), dimension=1,
+        num_keys=1)[1]
+    return (jnp.concatenate([rows[:num_keys], payload]),
+            order.astype(jnp.int32))
+
+
+def merge_records(runs, counts, size: int, num_keys: int):
+    """Merge received record runs: runs (words, k, slot) whose first
+    counts[i] records are valid -> (words, size) sorted by the first
+    `num_keys` words. The runs are laid end to end (`concat_runs`, one
+    contiguous copy each) and sorted with their payload (`sort_planes`);
+    the slots past the records hold all-ones words, and the sort is
+    stable, so they stay behind a record whose key words are all ones."""
+    buf = mops.concat_runs(runs, counts, size)
+    return sort_planes(buf, num_keys)[0]

@@ -139,12 +139,20 @@ def gather_runs(buf, starts, counts, slot: int):
     `dynamic_slice` of `slot` keys out of `buf` padded by `slot`
     sentinels, which no start in [0, len(buf)] can push past the end. An
     element-indexed gather of the same windows costs XLA:TPU a tile per
-    key. Requires 0 <= starts[i] and starts[i] + counts[i] <= len(buf)."""
+    key. Requires 0 <= starts[i] and starts[i] + counts[i] <= len(buf).
+
+    A buf of (..., n) — records as word planes — cuts the same windows
+    of every row at once: (..., k, slot). Not under `jax.vmap`, whose
+    `dynamic_slice` becomes a gather."""
     sent = hi_sentinel(buf.dtype)
-    padded = jnp.concatenate([buf, jnp.full((slot,), sent, buf.dtype)])
-    starts = jnp.clip(jnp.asarray(starts, jnp.int32), 0, buf.shape[0])
-    vals = jnp.stack([jax.lax.dynamic_slice(padded, (starts[i],), (slot,))
-                      for i in range(starts.shape[0])])
+    n, lead = buf.shape[-1], buf.shape[:-1]
+    padded = jnp.concatenate(
+        [buf, jnp.full(lead + (slot,), sent, buf.dtype)], axis=-1)
+    starts = jnp.clip(jnp.asarray(starts, jnp.int32), 0, n)
+    vals = jnp.stack(
+        [jax.lax.dynamic_slice(padded, (0,) * len(lead) + (starts[i],),
+                               lead + (slot,))
+         for i in range(starts.shape[0])], axis=-2)
     pos = jnp.arange(slot, dtype=jnp.int32)[None, :]
     valid = pos < jnp.asarray(counts, jnp.int32)[:, None]
     return jnp.where(valid, vals, sent)
@@ -210,3 +218,23 @@ def merge_ragged_runs_batched(buf, starts, counts, slot: int | None = None,
         spill,
         lambda bu: bops.local_sort_batched(bu, interpret=interpret),
         merge_path, buf)
+
+
+def concat_runs(runs, counts, size: int):
+    """Lay k runs end to end: runs (..., k, slot) whose first counts[i]
+    entries along the last axis are valid -> (..., size), run 0's entries
+    first, then run 1's, ... Each run is one `dynamic_update_slice` of its
+    whole slot, in order, so the next run overwrites a run's tail; past
+    the last run's entries the buffer holds its tail, then the hi
+    sentinel. Entries past `size` are cut off (callers count them as
+    truncated)."""
+    k, slot = runs.shape[-2], runs.shape[-1]
+    counts = jnp.asarray(counts, jnp.int32)
+    offsets = jnp.minimum(jnp.cumsum(counts) - counts, size)
+    buf = jnp.full(runs.shape[:-2] + (size + slot,), hi_sentinel(runs.dtype),
+                   runs.dtype)
+    lead = (0,) * (runs.ndim - 2)
+    for i in range(k):
+        buf = jax.lax.dynamic_update_slice(buf, runs[..., i, :],
+                                           lead + (offsets[i],))
+    return buf[..., :size]

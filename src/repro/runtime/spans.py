@@ -1,6 +1,6 @@
 """Every name the program records in a profiler trace, in one table.
 
-Three kinds of name, all read by `perfbench/` and listed in PERF.md:
+Four kinds of name, all read by `perfbench/` and listed in PERF.md:
 
   host spans   `jax.profiler.TraceAnnotation`s on the host thread that
                does the work; they land in the profiler's trace on the
@@ -12,13 +12,15 @@ Three kinds of name, all read by `perfbench/` and listed in PERF.md:
                `tf_op` stat of a TPU trace event) and change nothing that
                runs. Scopes never nest in one another, except
                `SPLITTER_ROUND` (the round scan: its loop and body) inside
-               `SPLITTERS`.
+               `SPLITTERS`, and `PROBE_SEARCH` inside `SPLITTER_ROUND`.
   kernels      the `name=` of each `pallas_call`: on a TPU the kernel's op
                takes this name in a trace. The bitonic network and the
                strided compare-exchange run both in the local sort and in
                the merge, so a kernel's name is its caller's family plus
                the kernel: `merge_sorted_runs_network`,
                `local_sort_strided`, ...
+  counters     what `repro.sort.sort_records` returns beside the records
+               (`RecordSortOutput.counters()` keys them by these names).
 
 Nothing here switches anything on: there is no exporter and no knob.
 """
@@ -47,6 +49,7 @@ COMPILE = "runtime.compile"       # marks the end of each backend compile
 LOCAL_SORT = "local_sort"
 SPLITTERS = "splitters"
 SPLITTER_ROUND = "splitter_round"   # the round scan, in SPLITTERS
+PROBE_SEARCH = "probe_search"       # records: lexicographic probe ranks
 EXCHANGE = "exchange"               # send-buffer build and all-to-alls
 MERGE = "merge"                     # merge of the received runs, cap_to
 AUDIT = "audit"                     # sort/verify.py's fused audit
@@ -62,6 +65,12 @@ MERGE_FAMILY = "merge_sorted_runs"  # merge.ops entry points
 NETWORK = "network"                 # bitonic_sort.kernel.network_pass
 STRIDED = "strided"                 # merge.kernel.strided_compare_exchange
 HISTOGRAM_KERNEL = "probe_ranks_histogram"   # histogram.kernel
+
+# -- counters a record sort returns (repro.sort.RecordSortOutput) ----------
+ROUNDS_USED = "rounds_used"       # splitter rounds run: SplitterStats
+WIRE_BYTES = "wire_bytes"         # per chip: the padded all-to-all buffer
+SENT_BYTES = "sent_bytes"         # per chip: bytes of real records in it
+COUNTERS = (ROUNDS_USED, WIRE_BYTES, SENT_BYTES)
 
 HOST_SPANS = (FRONT_DOOR, PLAN, LAUNCH, DECODE, OVERFLOW_SYNC, GATHER,
               BATCH, STACK, H2D, RESULTS, COMPILE)

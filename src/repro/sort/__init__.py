@@ -27,6 +27,10 @@ cache (`exec_cache`) keyed by shape bucket — see DESIGN.md Section 6:
     outs = sort_batched(xs_2d)       # (B, n) -> BatchedSortOutput
     outs = sort_batched([a, b, c])   # length-bucketed list -> per-request
 
+Records (the Sort Benchmark's 100-byte format): `sort_records(words)`
+sorts (25, n) uint32 word planes (`pack_records` makes them from (n, 100)
+uint8) by their 10-byte keys, the payload moved on the device.
+
 Verified mode (DESIGN.md Section 9): `SortSpec(verify="cheap")` fuses a
 device-side postcondition audit (multiset fingerprint + sortedness +
 boundary/range + count conservation) into the launch; failures surface as
@@ -37,10 +41,11 @@ quality at runtime.
 The legacy per-algorithm entry points (`repro.core.hss_sort` et al.) remain
 as thin shims over the same driver.
 """
-from repro.sort.adapters import BatchedSortOutput, SortOutput
+from repro.sort.adapters import (BatchedSortOutput, RecordSortOutput,
+                                 SortOutput, pack_records, unpack_records)
 from repro.sort.api import (
     RecoveryStats, argsort, bucket_key, gather, gather_perm_checked, sort,
-    sort_batched, sort_kv, spec_fingerprint)
+    sort_batched, sort_kv, sort_records, spec_fingerprint)
 from repro.sort.driver import exec_cache
 from repro.sort.semisort import (
     GROUPBY_OPS, BatchedSemisortOutput, SemisortOutput, groupby_aggregate,
@@ -57,10 +62,11 @@ __all__ = [
     "ALGORITHMS", "AuditReport", "BatchVerificationError",
     "BatchedSemisortOutput", "BatchedSortOutput", "GROUPBY_OPS",
     "ImbalanceError", "ON_OVERFLOW", "ON_VERIFY_FAILURE", "Partitioner",
-    "RecoveryStats", "SemisortOutput", "ShardCtx", "SortOutput", "SortSpec",
+    "RecordSortOutput", "RecoveryStats", "SemisortOutput", "ShardCtx", "SortOutput", "SortSpec",
     "VERIFY", "VerificationError", "argsort", "available_algorithms",
     "bucket_key", "exec_cache", "gather", "gather_perm_checked",
-    "get_partitioner", "groupby_aggregate", "register_partitioner",
-    "semisort", "semisort_batched", "sort", "sort_batched", "sort_kv",
-    "spec_fingerprint", "top_k", "top_k_batched",
+    "get_partitioner", "groupby_aggregate", "pack_records",
+    "register_partitioner", "semisort", "semisort_batched", "sort",
+    "sort_batched", "sort_kv", "sort_records", "spec_fingerprint", "top_k",
+    "top_k_batched", "unpack_records",
 ]

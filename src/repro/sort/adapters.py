@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.common import hi_sentinel
+from repro.core.common import (RECORD_BYTES, RECORD_KEY_WORDS, RECORD_WORDS,
+                               hi_sentinel)
 from repro.core.tagging import (
     float32_to_sortable_int32, float64_to_sortable_int64,
     sortable_int32_to_float32, sortable_int64_to_float64, tag_bits)
@@ -307,14 +308,18 @@ def detect_duplicates(x: jax.Array) -> jax.Array:
 
 
 def make_plan(x: jax.Array, spec: SortSpec, p: int,
-              want_indices: bool = False) -> AdapterPlan:
+              want_indices: bool = False, records: bool = False):
     """Inspect the input and decide bijection/tagging/padding. Host-side.
+    With `records`, x is record word planes and the plan a `RecordPlan`:
+    no detection, no key range, no host sync.
 
     x may be (n,) or, for the batched engine, (B, n): one plan serves the
     whole batch — the key range (and so the rebase offset and packing
     budget) is taken over all B requests jointly, while tag indices stay
     per-request (`encode` broadcasts one arange over rows).
     """
+    if records:
+        return RecordPlan.of(x, spec, p)
     n = x.shape[-1]
     if n == 0 or x.size == 0:
         raise ValueError("cannot sort an empty array")
@@ -393,3 +398,113 @@ def make_plan(x: jax.Array, spec: SortSpec, p: int,
     plan.pack_dtype = pack_dtype
     plan._enc = enc        # reuse the bijection result in encode()
     return plan
+
+
+# -- Sort Benchmark records (repro.sort.sort_records) ----------------------
+
+@jax.jit
+def pack_records(records) -> jax.Array:
+    """(n, 100) uint8 records (NumPy or on the device) -> their (25, n)
+    uint32 word planes on the device, each word four bytes big-endian, so
+    unsigned word order is memcmp order: one program, `jit_pack_records`
+    in a trace."""
+    b = jnp.asarray(records, jnp.uint8).reshape(
+        -1, RECORD_WORDS, 4).astype(jnp.uint32)
+    w = (b[..., 0] << 24) | (b[..., 1] << 16) | (b[..., 2] << 8) | b[..., 3]
+    return w.T
+
+
+def unpack_records(words) -> np.ndarray:
+    """(25, n) uint32 word planes -> (n, 100) uint8 records (host)."""
+    w = np.asarray(words, np.uint32)
+    return (np.ascontiguousarray(w.T).astype(">u4").view(np.uint8)
+            .reshape(-1, RECORD_BYTES))
+
+
+class RecordSortOutput:
+    """Result of `repro.sort.sort_records`, all of it on the device until a
+    caller gathers.
+
+    shards   (p, 25, out_cap) uint32: each chip's records as word planes,
+             sorted by key; slots past counts[i] hold no record.
+    counts   (p,) records per chip (they sum to n when overflow == 0).
+    overflow records dropped (0 => every record is in the output).
+    splitter_keys / splitter_ranks  the p-1 splitters: (p-1, 4) word
+             tuples (3 key words, then the global index that breaks ties)
+             and their global ranks.
+    stats    the splitters' SplitterStats (None on one chip).
+    sent     (p, 2) int32 per chip: record slots it put on the wire in the
+             all-to-all (the padded buffer, to the other chips) and how
+             many of them held records.
+    n        number of input records.
+    """
+
+    def __init__(self, shards, counts, overflow, splitter_keys,
+                 splitter_ranks, stats, sent, n):
+        self.shards = shards
+        self.counts = counts
+        self.overflow = overflow
+        self.splitter_keys = splitter_keys
+        self.splitter_ranks = splitter_ranks
+        self.stats = stats
+        self.sent = sent
+        self.n = n
+
+    def gather(self) -> np.ndarray:
+        """All records in order as one (n, 100) uint8 array (host)."""
+        from repro.runtime import spans
+        with spans.span(spans.GATHER):
+            shards = np.asarray(self.shards)
+            counts = np.asarray(self.counts)
+            return unpack_records(np.concatenate(
+                [shards[i, :, :counts[i]] for i in range(shards.shape[0])],
+                axis=1))
+
+    def counters(self) -> dict:
+        """The program's counters, read to the host: splitter rounds run,
+        and per chip the bytes it put on the wire in the all-to-all and
+        the bytes of real records among them (`repro.runtime.spans`
+        names the keys)."""
+        from repro.runtime import spans
+        sent = np.asarray(self.sent).astype(np.int64) * RECORD_BYTES
+        rounds = (0 if self.stats is None
+                  else int(np.asarray(self.stats.rounds_used)))
+        return {spans.ROUNDS_USED: rounds,
+                spans.WIRE_BYTES: sent[:, 0].tolist(),
+                spans.SENT_BYTES: sent[:, 1].tolist()}
+
+
+@dataclasses.dataclass
+class RecordPlan:
+    """What `make_plan` decides for records: nothing to encode (the word
+    planes are the sort's domain already), so it checks them and decodes
+    the launch's result."""
+    spec: SortSpec
+    p: int
+    n: int
+
+    @classmethod
+    def of(cls, x, spec: SortSpec, p: int) -> "RecordPlan":
+        if x.ndim != 2 or x.shape[0] != RECORD_WORDS \
+                or x.dtype != jnp.uint32:
+            raise ValueError(
+                f"records are ({RECORD_WORDS}, n) uint32 word planes, got "
+                f"{x.shape} {x.dtype}")
+        if x.shape[1] == 0:
+            raise ValueError("cannot sort no records")
+        return cls(spec=spec, p=p, n=int(x.shape[1]))
+
+    def decode(self, raw) -> RecordSortOutput:
+        shards, counts, skeys, sranks, overflow, stats = raw
+        if stats is None:   # one chip: a local sort, nothing sent
+            splitter_stats, sent = None, jnp.zeros((1, 2), jnp.int32)
+        else:
+            splitter_stats, sent = stats
+        return RecordSortOutput(shards, counts, overflow, skeys, sranks,
+                                splitter_stats, sent, self.n)
+
+
+def local_sort_records(x):
+    """One chip's record sort: (25, n) planes by their key words."""
+    from repro.kernels import dispatch
+    return dispatch.sort_planes(x, RECORD_KEY_WORDS)[0]

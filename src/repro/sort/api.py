@@ -23,11 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ams import ams_sample_size
+from repro.core.common import RECORD_BYTES
 from repro.core.sample_sort import default_regular_s, default_total_sample
 from repro.kernels import dispatch
 from repro.runtime import chaos, spans
 from repro.sort import driver, verify
-from repro.sort.adapters import BatchedSortOutput, SortOutput, make_plan
+from repro.sort.adapters import (BatchedSortOutput, RecordSortOutput,
+                                 SortOutput, local_sort_records, make_plan,
+                                 pack_records)
 from repro.sort.partitioners import ShardCtx, get_partitioner
 from repro.sort.spec import SortSpec
 from repro.sort.verify import (BatchVerificationError, ImbalanceError,
@@ -147,14 +150,15 @@ def bucket_key(n, dtype, spec: SortSpec, *, kind: str = "sort", param=None):
     return key if param is None else key + (param,)
 
 
-def _cache_key(spec: SortSpec, names, sizes, enc, *, batched: bool):
-    """Compiled-executable cache key: (shape bucket, dtype, SortSpec
-    fingerprint, mesh fingerprint). None (uncached) when the spec carries
-    state the key cannot capture — a caller-supplied local_sort_fn or
-    warm-start probes would be baked into a reused trace."""
+def _cache_key(spec: SortSpec, names, sizes, enc, *, kind: str):
+    """Compiled-executable cache key: (kind — single, batched or records —,
+    shape bucket, dtype, SortSpec fingerprint, mesh fingerprint). None
+    (uncached) when the spec carries state the key cannot capture — a
+    caller-supplied local_sort_fn or warm-start probes would be baked into
+    a reused trace."""
     if spec.local_sort_fn is not None or spec.initial_probes is not None:
         return None
-    return (("batched" if batched else "single",) + _spec_trace_fields(spec)
+    return ((kind,) + _spec_trace_fields(spec)
             + (names, sizes, _mesh_fingerprint(spec),
                tuple(enc.shape), str(enc.dtype)))
 
@@ -175,7 +179,7 @@ def _sort_impl(x, spec: SortSpec, want_indices: bool) -> SortOutput:
                    initial_probes=probes)
     p1_sort = spec.local_sort_fn or dispatch.local_sort_fn(spec.kernel_policy)
     sort_fn = lambda local, rng: part.sharded(local, rng, ctx)
-    cache_key = _cache_key(spec, names, sizes, enc, batched=False)
+    cache_key = _cache_key(spec, names, sizes, enc, kind="single")
     audit = spec.verify != "off" and p > 1
     if audit:
         corrupt = chaos.corrupt_now()
@@ -217,7 +221,7 @@ def _sort_batched_impl(xs, spec: SortSpec,
     p1_sort = (jax.vmap(spec.local_sort_fn) if spec.local_sort_fn is not None
                else dispatch.local_sort_batched_fn(spec.kernel_policy))
     sort_fn = lambda local, rng: part.sharded_batched(local, rng, ctx)
-    cache_key = _cache_key(spec, names, sizes, enc, batched=True)
+    cache_key = _cache_key(spec, names, sizes, enc, kind="batched")
     audit = spec.verify != "off" and p > 1
     if audit:
         corrupt = chaos.corrupt_now()
@@ -575,6 +579,68 @@ def sort_kv(keys, values, spec: SortSpec | None = None, **overrides):
             lambda s: _sort_impl(keys, s, want_indices=True), spec)
     order = gather_perm_checked(out, "sort_kv")
     return out.gather(), values[order]
+
+
+def _record_spec_supported(spec: SortSpec) -> None:
+    """What a record sort runs today: HSS, the dense exchange, overflow
+    counted and not recovered, no audit."""
+    if spec.verify != "off":
+        raise NotImplementedError(
+            f"sort_records: verify={spec.verify!r} — no audit checks "
+            "records yet; use verify='off'")
+    unsupported = {
+        "algorithm": spec.algorithm != "hss",
+        "exchange": spec.resolved_exchange() != "dense",
+        "on_overflow": spec.on_overflow != "raise",
+        "imbalance_slo": spec.imbalance_slo is not None,
+        "batch": spec.batch,
+        "local_sort_fn": spec.local_sort_fn is not None,
+        "initial_probes": spec.initial_probes is not None,
+    }
+    bad = [k for k, v in unsupported.items() if v]
+    if bad:
+        raise NotImplementedError(
+            f"sort_records supports algorithm='hss', exchange='dense', "
+            f"on_overflow='raise' and nothing set of {bad}")
+
+
+def _sort_records_impl(x, spec: SortSpec) -> RecordSortOutput:
+    part = get_partitioner(spec.algorithm)
+    if x.dtype == np.uint8 and x.ndim == 2 and x.shape[1] == RECORD_BYTES:
+        x = pack_records(x)
+    p, names, sizes = _mesh_axes(spec, part)
+    with spans.span(spans.PLAN):
+        plan = make_plan(x, spec, p, records=True)
+    ctx = ShardCtx(spec=spec, axis_names=names, sizes=sizes, rng=None)
+    raw = driver.run(
+        lambda local, rng: part.sharded(local, rng, ctx),
+        x, mesh=spec.mesh, axis_names=names, sizes=sizes, seed=spec.seed,
+        n_real=plan.n, local_sort_fn=local_sort_records,
+        cache_key=_cache_key(spec, names, sizes, x, kind="records"))
+    with spans.span(spans.DECODE):
+        return plan.decode(raw)
+
+
+def sort_records(records, spec: SortSpec | None = None,
+                 **overrides) -> RecordSortOutput:
+    """Sort Sort Benchmark records (100 bytes: a 10-byte key, a 90-byte
+    payload) across the mesh by memcmp order of their keys; records with
+    equal keys come out in any order.
+
+    `records` is their device form: (25, n) uint32 word planes, each word
+    four bytes big-endian (`repro.sort.pack_records` makes them on the
+    device from (n, 100) uint8), sharded over the mesh on the last axis;
+    (n, 100) uint8 records are packed first. The payload moves with its
+    key on the device; the result (`RecordSortOutput`) stays there until
+    `gather()`. The call runs the chain `sort()` runs — plan,
+    one cached `shard_map` launch of `HSSPartitioner.sharded`, decode —
+    with no duplicate detection: HSS breaks ties between equal keys by
+    each record's global index. Only HSS with the dense exchange, and no
+    audit (`verify` other than "off" raises), for now."""
+    spec = _as_spec(spec, overrides)
+    _record_spec_supported(spec)
+    with spans.span(spans.FRONT_DOOR):
+        return _sort_records_impl(records, spec)
 
 
 def gather(out: SortOutput) -> np.ndarray:

@@ -262,7 +262,10 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
         n_real=None, local_sort_fn=None, cache_key=None):
     """Run a shard-level sort over a mesh; returns the raw 6-tuple with
     leading (p, ...) shard dims: (shards, counts, keys, ranks, overflow,
-    stats). Inputs the driver itself had to sentinel-pad get their counts
+    stats). `x` is a 1-D key array, or records as (words, n) planes
+    sharded over the mesh on their last axis: each shard gets its
+    (words, n_local) block as placed, unpadded, and returns (words,
+    out_cap). Inputs the driver itself had to sentinel-pad get their counts
     corrected via `strip_sentinel_counts`; callers that pre-padded with
     non-sentinel values (the tagged adapter path) correct counts on decode.
     `n_real` (default: len(x)) is the non-pad key count for the p==1 path,
@@ -274,7 +277,7 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
     """
     plan = resolve_mesh(mesh, axis_names, sizes)
     p = plan.p
-    n_real = x.shape[0] if n_real is None else n_real
+    n_real = x.shape[-1] if n_real is None else n_real
     if p == 1:
         with spans.span(spans.LAUNCH):
             out = (local_sort_fn or jnp.sort)(x)
@@ -282,17 +285,24 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
                 jnp.zeros((0,), x.dtype), jnp.zeros((0,), jnp.int32),
                 jnp.zeros((), jnp.int32), None)
     n_sent_real = None
-    if (-x.shape[0]) % p:   # count sentinel-valued data keys before padding
-        n_sent_real = jnp.sum((x == hi_sentinel(x.dtype)).astype(jnp.int32))
-    x, n_pad = pad_to_shards(x, p)
-    n_local = x.shape[0] // p
-    xs = x.reshape(plan.sizes + (n_local,))
+    if x.ndim == 2:   # record planes (words, n), sharded on their last axis
+        if x.shape[1] % p:
+            raise ValueError(f"{x.shape[1]} records do not split into {p} "
+                             "equal shards")
+        xs, n_pad, in_spec = x, 0, P(None, plan.axis_names)
+    else:
+        if (-x.shape[0]) % p:   # count sentinel-valued data keys pre-pad
+            n_sent_real = jnp.sum(
+                (x == hi_sentinel(x.dtype)).astype(jnp.int32))
+        x, n_pad = pad_to_shards(x, p)
+        xs = x.reshape(plan.sizes + (x.shape[0] // p,))
+        in_spec = P(*plan.axis_names)
     naxes = len(plan.axis_names)
 
     def build():
         def per_shard(block, key):
             exec_cache.traces += 1
-            local = block.reshape(-1)
+            local = block if x.ndim == 2 else block.reshape(-1)
             me = jnp.int32(0)
             for name, size in zip(plan.axis_names, plan.sizes):
                 me = me * size + jax.lax.axis_index(name)
@@ -304,9 +314,11 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
                     keys, ranks, ovf, stats)
 
         sharded = P(*plan.axis_names)
-        return _jit_donated(shard_map(
+        # record planes are the caller's own array, not a copy: kept
+        jit = _jit_donated if x.ndim == 1 else jax.jit
+        return jit(shard_map(
             per_shard, mesh=plan.mesh,
-            in_specs=(sharded, P()),
+            in_specs=(in_spec, P()),
             out_specs=(sharded, sharded, P(), P(), P(), P())))
 
     with spans.span(spans.LAUNCH):

@@ -30,14 +30,15 @@ import jax.random as jr
 
 from repro.analysis.contracts import CommsContract, register_contract
 from repro.core.ams import ams_sample_size, ams_splitters, scanning_splitters
-from repro.core.common import hi_sentinel, round_up
-from repro.core.exchange import exchange, exchange_batched
+from repro.core.common import RECORD_KEY_WORDS, hi_sentinel, round_up
+from repro.core.exchange import exchange, exchange_batched, exchange_records
 from repro.core.multistage import two_stage_sort_sharded
 from repro.core.sample_sort import (
     default_regular_s, default_total_sample, random_sample_splitters,
     regular_sample_splitters)
 from repro.core.splitters import (
-    ROUND_COLLECTIVES, SplitterStats, hss_splitters, hss_splitters_batched)
+    ROUND_COLLECTIVES, SplitterStats, hss_splitters, hss_splitters_batched,
+    hss_splitters_lex)
 from repro.kernels import dispatch
 from repro.runtime import spans
 from repro.sort.driver import factor_stages
@@ -125,7 +126,10 @@ class Partitioner:
 
     def sharded(self, local, rng, ctx: ShardCtx):
         """Full shard-level sort: local sort -> splitters -> exchange, each
-        phase under its scope (`repro.runtime.spans`)."""
+        phase under its scope (`repro.runtime.spans`). A (words, n_local)
+        block is records (`sharded_records`)."""
+        if local.ndim == 2:
+            return self.sharded_records(local, rng, ctx)
         sort_local = (ctx.spec.local_sort_fn
                       or dispatch.local_sort_fn(ctx.spec.kernel_policy))
         with spans.scope(spans.LOCAL_SORT):
@@ -137,6 +141,35 @@ class Partitioner:
             local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
             cfg=ctx.ex_cfg, eps=ctx.spec.eps)
         return out, n_valid, keys, ranks, s_ovf + e_ovf, stats
+
+    def splitters_records(self, key_planes, ctx: ShardCtx):
+        """`splitters` over word tuples: key_planes, the shard's sorted
+        (n_local,) key words with the global index last -> ((p-1, words)
+        keys, (p-1,) ranks, overflow, stats)."""
+        raise NotImplementedError(
+            f"partitioner {self.name!r} does not sort records")
+
+    def sharded_records(self, local, rng, ctx: ShardCtx):
+        """The shard-level sort of records: `local` is (RECORD_WORDS,
+        n_local) uint32 word planes, big-endian, the key in the first
+        RECORD_KEY_WORDS. The local sort orders them by those words, ties
+        by position, so by each record's global index too; the splitters
+        cut that order, the global index breaking ties; the dense exchange
+        moves whole records and merges them. Stats are the splitters' and
+        the exchange's (p, 2) slot counts."""
+        kw, n_local = RECORD_KEY_WORDS, local.shape[1]
+        with spans.scope(spans.LOCAL_SORT):
+            records, order = dispatch.sort_planes(local, kw)
+        me = jax.lax.axis_index(ctx.axis_name)
+        gidx = (me * n_local + order).astype(jnp.uint32)
+        key_planes = tuple(records[w] for w in range(kw)) + (gidx,)
+        with spans.scope(spans.SPLITTERS):
+            keys, ranks, s_ovf, stats = self.splitters_records(
+                key_planes, dataclasses.replace(ctx, rng=rng))
+        out, n_valid, e_ovf, sent = exchange_records(
+            records, key_planes, keys, axis_name=ctx.axis_name, p=ctx.p,
+            cfg=ctx.ex_cfg, eps=ctx.spec.eps)
+        return out, n_valid, keys, ranks, s_ovf + e_ovf, (stats, sent)
 
     def splitters_batched(self, local_sorted, ctx: ShardCtx):
         """Batched counterpart of `splitters`: (B, n_local) sorted rows ->
@@ -263,6 +296,12 @@ class HSSPartitioner(Partitioner):
         keys, ranks, stats = hss_splitters(
             local_sorted, axis_name=ctx.axis_name, p=ctx.p, cfg=ctx.hss_cfg,
             rng=ctx.rng, initial_probes=ctx.initial_probes)
+        return keys, ranks, jnp.zeros((), jnp.int32), stats
+
+    def splitters_records(self, key_planes, ctx):
+        keys, ranks, stats = hss_splitters_lex(
+            key_planes, axis_name=ctx.axis_name, p=ctx.p, cfg=ctx.hss_cfg,
+            rng=ctx.rng)
         return keys, ranks, jnp.zeros((), jnp.int32), stats
 
     def splitters_batched(self, local_sorted, ctx):

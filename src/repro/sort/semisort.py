@@ -324,7 +324,8 @@ def _semisort_fast(x, spec: SortSpec):
     fallback = type(part).sharded is not Partitioner.sharded
     shard_fn = _semisort_shard_fn(part, ctx, n_local, s_loc, min_count,
                                   max_heavy, ex_cfg, fallback, batch=batch)
-    base = _cache_key(spec, names, sizes, enc, batched=batched)
+    base = _cache_key(spec, names, sizes, enc,
+                      kind="batched" if batched else "single")
     cache_key = (None if base is None
                  else ("semisort", s_loc, min_count, max_heavy,
                        out_extra) + base)

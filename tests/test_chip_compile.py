@@ -174,3 +174,41 @@ def test_dense_exchange_sends_without_an_element_gather_on_v5e(topo):
     for shape, path in in_exchange:
         assert "searchsorted" in path and shape.startswith(f"s32[{p - 1}]"), \
             (shape, path)
+
+
+def test_record_launch_compiles_for_v5e(topo):
+    """The record sort's launch (local sort, splitter rounds over key
+    tuples, the all-to-all of whole records, the merge) compiles for the
+    four chips of a described v5e host: every sort of a shard's records
+    has two operands and one key (XLA adds a third, the positions), and
+    no gather reads more than a sample's rows."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.parallel.compat import make_mesh, shard_map
+    from repro.sort import SortSpec
+    from repro.sort.partitioners import ShardCtx, get_partitioner
+
+    p, n = 4, 1 << 16
+    mesh = make_mesh((p,), ("sort",), devices=topo.devices[:p])
+    ctx = ShardCtx(spec=SortSpec(mesh=mesh), axis_names=("sort",),
+                   sizes=(p,), rng=None)
+    part = get_partitioner("hss")
+
+    def body(local, key):
+        out, n_valid, *_ = part.sharded_records(local, key[0], ctx)
+        return out[None], n_valid[None]
+
+    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(None, "sort"), P()),
+                          out_specs=(P("sort"), P("sort"))))
+    records = jax.ShapeDtypeStruct((25, p * n), jnp.uint32,
+                                   sharding=NamedSharding(mesh,
+                                                          P(None, "sort")))
+    rng = jax.ShapeDtypeStruct((1, 2), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    hlo = f.lower(records, rng).compile().as_text()
+    assert "all-to-all" in hlo
+    sorts = re.findall(r"= \((.*?)\) sort\(", hlo)
+    big = [s for s in sorts if f"{n}]" in s or f",{n}]" in s]
+    assert big and all(s.count("[") <= 3 for s in big), big
+    for shape in re.findall(r"= (\S+) gather\(", hlo):
+        assert f"{n}" not in shape, shape
