@@ -30,7 +30,8 @@ import jax
 
 # -- host spans: the sort front door (repro.sort.api, repro.sort.driver) ---
 FRONT_DOOR = "sort.front_door"    # a whole sort/sort_batched/argsort call
-PLAN = "sort.plan"                # make_plan, with detection's host sync
+PLAN = "sort.plan"                # make_plan, with its key-range read
+DETECT = "sort.detect"            # in PLAN: detection and its host sync
 LAUNCH = "sort.launch"            # cache lookup + dispatch of the program
 DECODE = "sort.decode"            # AdapterPlan.decode / decode_batched
 OVERFLOW_SYNC = "sort.overflow_sync"   # the retry policy's counter read
@@ -58,6 +59,8 @@ SCOPES = (LOCAL_SORT, SPLITTERS, SPLITTER_ROUND, EXCHANGE, MERGE, AUDIT)
 #: The front door's duplicate detection is its own jitted program; its
 #: module in a trace is `jit_detect_duplicates`.
 DETECT_MODULE = "jit_detect_duplicates"
+#: The front door's key range (min and max in one read) is another.
+RANGE_MODULE = "jit_key_range"
 
 # -- Pallas kernel names: <family>_<kernel> ---------------------------------
 SORT_FAMILY = "local_sort"          # bitonic_sort.ops entry points
@@ -72,7 +75,7 @@ WIRE_BYTES = "wire_bytes"         # per chip: the padded all-to-all buffer
 SENT_BYTES = "sent_bytes"         # per chip: bytes of real records in it
 COUNTERS = (ROUNDS_USED, WIRE_BYTES, SENT_BYTES)
 
-HOST_SPANS = (FRONT_DOOR, PLAN, LAUNCH, DECODE, OVERFLOW_SYNC, GATHER,
+HOST_SPANS = (FRONT_DOOR, PLAN, DETECT, LAUNCH, DECODE, OVERFLOW_SYNC, GATHER,
               BATCH, STACK, H2D, RESULTS, COMPILE)
 
 

@@ -8,15 +8,16 @@ that contract and back:
   * float keys are routed through the order-preserving IEEE-754 bijection
     (repro.core.tagging): float32 <-> int32, float64 <-> int64 (jax x64);
   * duplicate keys — always present for `stable=True`, `argsort`,
-    `sort_kv`, and auto-detected otherwise — are made distinct by implicit
-    tagging (paper Section 6.3): keys are rebased to their observed range
-    and packed as (key << b) | global_index, so the tag doubles as the
-    argsort permutation on the way out;
+    `sort_kv`, and auto-detected otherwise where a tag fits — are made
+    distinct by implicit tagging (paper Section 6.3): keys are rebased to
+    their observed range and packed as (key << b) | global_index, so the
+    tag doubles as the argsort permutation on the way out;
   * non-divisible inputs are padded *before* packing with the maximum real
     key, so pads sort to the global tail and the driver trims them.
 
-An `AdapterPlan` is built per call (it inspects the key range — a few O(n)
-device reductions whose scalar results sync to host) and exposes
+An `AdapterPlan` is built per call (it reads the key range — one O(n)
+device reduction whose two scalars sync to host — and, only where a tag
+would fit, a duplicate-detection sort whose flag syncs too) and exposes
 `encode(x)` / `decode(raw)`, both device-side. The raw core path
 (`repro.core.hss_sort` et al.) remains available for callers that cannot
 afford even the scalar syncs.
@@ -35,6 +36,7 @@ from repro.core.common import (RECORD_BYTES, RECORD_KEY_WORDS, RECORD_WORDS,
 from repro.core.tagging import (
     float32_to_sortable_int32, float64_to_sortable_int64,
     sortable_int32_to_float32, sortable_int64_to_float64, tag_bits)
+from repro.runtime import spans
 from repro.sort.spec import SortSpec
 
 
@@ -280,23 +282,19 @@ class AdapterPlan:
         return enc.astype(self.out_dtype)
 
 
-def _needs_tags(x: jax.Array, spec: SortSpec, want_indices: bool):
-    """-> (wanted, required). Required tagging errors out when the packing
-    budget does not fit; merely wanted tagging (auto duplicate detection)
-    falls back to untagged, which still sorts correctly — duplicates only
-    cost load balance, and that surfaces through the overflow counter."""
+def _needs_tags(spec: SortSpec, want_indices: bool):
+    """-> (wanted, required), wanted None where only duplicate detection
+    can say. Required tagging errors out when the packing budget does not
+    fit; merely wanted tagging (auto duplicate detection) falls back to
+    untagged, which still sorts correctly — duplicates only cost load
+    balance, and that surfaces through the overflow counter."""
     if spec.tag is not None:
         if not spec.tag and want_indices:
             raise ValueError("argsort/sort_kv require tagging (tag=False set)")
         return spec.tag, spec.tag
     if spec.stable or want_indices:
         return True, True
-    # auto duplicate detection: a device-side sort + adjacent-equal check
-    # (only a scalar crosses to host); override with tag=False when keys
-    # are known-distinct and the check matters. On a (B, n) batch, rows
-    # sort independently — duplicates only matter within a request, but
-    # any duplicated row tags the whole batch (one shared plan).
-    return bool(detect_duplicates(x)), False
+    return None, False
 
 
 @jax.jit
@@ -307,11 +305,20 @@ def detect_duplicates(x: jax.Array) -> jax.Array:
     return jnp.any(s[..., 1:] == s[..., :-1])
 
 
+@jax.jit
+def key_range(enc: jax.Array) -> jax.Array:
+    """[min, max] of every key in enc, so one read brings both to the
+    host: one program, `jit_key_range` in a trace."""
+    return jnp.stack([jnp.min(enc), jnp.max(enc)])
+
+
 def make_plan(x: jax.Array, spec: SortSpec, p: int,
               want_indices: bool = False, records: bool = False):
     """Inspect the input and decide bijection/tagging/padding. Host-side.
-    With `records`, x is record word planes and the plan a `RecordPlan`:
-    no detection, no key range, no host sync.
+    The key range is read first; auto duplicate detection (`tag=None`)
+    runs only where the range and p leave room for a tag, since only
+    there can it change the plan. With `records`, x is record word planes
+    and the plan a `RecordPlan`: no detection, no key range, no host sync.
 
     x may be (n,) or, for the batched engine, (B, n): one plan serves the
     whole batch — the key range (and so the rebase offset and packing
@@ -355,8 +362,8 @@ def make_plan(x: jax.Array, spec: SortSpec, p: int,
         enc_sentinel = int(jnp.iinfo(dtype).max)
     plan._enc = enc if float_bits else None   # reuse bijection in encode()
 
-    wanted, required = _needs_tags(x, spec, want_indices)
-    key_max = int(jnp.max(enc))
+    wanted, required = _needs_tags(spec, want_indices)
+    key_min, key_max = map(int, np.asarray(key_range(enc)))
     if key_max == enc_sentinel:
         # keys whose (encoded) value equals the hi sentinel the untagged
         # pipeline uses for padding/buffers would be silently dropped —
@@ -368,11 +375,10 @@ def make_plan(x: jax.Array, spec: SortSpec, p: int,
                 "NaN payload mapping onto it) reserved by the untagged path "
                 "(tag=False): remove those keys or drop tag=False")
         wanted = required = True
-    if not wanted:
+    if wanted is False:
         return plan
 
     # tagging: compute the packing budget from the observed key range
-    key_min = int(jnp.min(enc))
     key_bits = max(1, int(key_max - key_min).bit_length())
     n_local = (n + n_pad) // p
     b = tag_bits(p, n_local)
@@ -391,6 +397,15 @@ def make_plan(x: jax.Array, spec: SortSpec, p: int,
     else:
         raise ValueError(f"key_bits={key_bits} + tag_bits={b} > 62: "
                          "compress the key range before sorting")
+    if wanted is None:
+        # auto duplicate detection, only now that a tag fits: a device-side
+        # sort + adjacent-equal check (only a scalar crosses to host);
+        # override with tag=False when keys are known-distinct. On a (B, n)
+        # batch, rows sort independently — duplicates only matter within a
+        # request, but any duplicated row tags the whole batch (one plan).
+        with spans.span(spans.DETECT):
+            if not bool(detect_duplicates(x)):
+                return plan
     plan.tagged = True
     plan.tag_b = b
     plan.key_min = key_min
@@ -452,7 +467,6 @@ class RecordSortOutput:
 
     def gather(self) -> np.ndarray:
         """All records in order as one (n, 100) uint8 array (host)."""
-        from repro.runtime import spans
         with spans.span(spans.GATHER):
             shards = np.asarray(self.shards)
             counts = np.asarray(self.counts)
@@ -465,7 +479,6 @@ class RecordSortOutput:
         and per chip the bytes it put on the wire in the all-to-all and
         the bytes of real records among them (`repro.runtime.spans`
         names the keys)."""
-        from repro.runtime import spans
         sent = np.asarray(self.sent).astype(np.int64) * RECORD_BYTES
         rounds = (0 if self.stats is None
                   else int(np.asarray(self.stats.rounds_used)))

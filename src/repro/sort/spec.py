@@ -129,13 +129,15 @@ class SortSpec:
                      applied so equal keys keep input order and original
                      indices travel with the keys. `argsort`/`sort_kv` force
                      this on. False + tag=None still auto-tags when the input
-                     is detected to contain duplicates.
+                     is detected to contain duplicates and a tag fits.
       tag            tri-state tagging override: None = auto (tag when stable,
                      when indices are required, or when duplicates are
-                     detected), True = always, False = never (caller asserts
-                     distinct keys). Auto-detection costs one single-placement
-                     O(n log n) device sort up front — at production scale
-                     pass an explicit True/False instead.
+                     detected and a tag fits), True = always, False = never
+                     (caller asserts distinct keys). Auto-detection runs only
+                     where a tag could be packed (key range bits + tag bits
+                     within 30, or 62 under jax x64): there it costs one
+                     single-placement O(n log n) device sort up front — at
+                     production scale pass an explicit True/False instead.
       seed           PRNG seed for the sampling rounds.
       initial_probes warm-start probes (the ChaNGa trick, paper Sec. 7.3).
       local_sort_fn  local-sort callable override; None routes the local sort

@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.sort import SortSpec, argsort, available_algorithms, sort, sort_kv
+from repro.sort import (SortSpec, adapters, argsort, available_algorithms,
+                        sort, sort_kv)
 from repro.parallel.compat import make_mesh
 
 # per-algorithm spec tweaks that make every baseline exact on 8 host shards
@@ -276,3 +277,152 @@ def test_nan_payload_sort_kv_audited(rng, algo):
     ref = np.argsort(keys, kind="stable")
     np.testing.assert_array_equal(k, keys[ref])
     np.testing.assert_array_equal(v, values[ref])
+
+
+# -- the plan's tagging decision, and when it pays for duplicate detection --
+
+I32 = np.iinfo(np.int32)
+N_PLAN = 4096
+
+
+def _full_i32(seed, shape=(N_PLAN,)):
+    """Full-range int32 keys without INT32_MAX: 32 key bits, never tagged
+    with x64 off."""
+    return np.random.default_rng(seed).integers(
+        I32.min, I32.max, size=shape, dtype=np.int32)
+
+
+def _narrow_dups(seed):
+    return np.random.default_rng(seed).integers(0, 100, size=N_PLAN,
+                                                dtype=np.int32)
+
+
+def _narrow_distinct(seed):
+    return np.random.default_rng(seed).permutation(N_PLAN).astype(np.int32)
+
+
+def _with_max(x):
+    x[7] = I32.max
+    return x
+
+
+def _batch_one_dup_row(seed):
+    x = _narrow_distinct(seed).reshape(4, N_PLAN // 4)
+    x[2, 5] = x[2, 9]
+    return x
+
+
+def _wide_dups(seed):
+    x = np.random.default_rng(seed).integers(0, 2**20, size=N_PLAN,
+                                             dtype=np.int32)
+    x[1] = x[0]
+    return x
+
+
+def _full_f32(seed):
+    return np.random.default_rng(seed).uniform(
+        -3e38, 3e38, size=N_PLAN).astype(np.float32)
+
+
+# id: (keys, p, SortSpec fields, want_indices, x64)
+PLAN_CASES = {
+    "full_i32_p4": ((lambda: _full_i32(1)), 4, {}, False, False),
+    "full_i32_p1": ((lambda: _full_i32(2)), 1, {}, False, False),
+    "narrow_dups": ((lambda: _narrow_dups(3)), 4, {}, False, False),
+    "narrow_distinct": ((lambda: _narrow_distinct(4)), 4, {}, False, False),
+    "max_forces_tag": ((lambda: _with_max(_narrow_distinct(5) + (
+        I32.max - N_PLAN))), 4, {}, False, False),
+    "max_required_no_fit": ((lambda: _with_max(_full_i32(6))), 4, {}, False,
+                            False),
+    "max_tag_false": ((lambda: _with_max(_narrow_dups(7))), 4,
+                      {"tag": False}, False, False),
+    "stable": ((lambda: _narrow_dups(8)), 4, {"stable": True}, False, False),
+    "stable_no_fit": ((lambda: _full_i32(9)), 4, {"stable": True}, False,
+                      False),
+    "tag_true": ((lambda: _narrow_distinct(10)), 4, {"tag": True}, False,
+                 False),
+    "tag_false": ((lambda: _narrow_dups(11)), 4, {"tag": False}, False,
+                  False),
+    "argsort_tag_false": ((lambda: _narrow_dups(12)), 4, {"tag": False},
+                          True, False),
+    "argsort": ((lambda: _narrow_dups(13)), 4, {}, True, False),
+    "full_f32": ((lambda: _full_f32(14)), 4, {}, False, False),
+    "batch_full_i32": ((lambda: _full_i32(15, (4, N_PLAN // 4))), 1, {},
+                       False, False),
+    "batch_dup_row": ((lambda: _batch_one_dup_row(16)), 1, {}, False, False),
+    "x64_full_i32_fits_int64": ((lambda: _full_i32(17)), 4, {}, False, True),
+    "x64_full_i64": ((lambda: np.random.default_rng(18).integers(
+        np.iinfo(np.int64).min, np.iinfo(np.int64).max, size=N_PLAN)),
+        4, {}, False, True),
+    "x64_wide_dups_int64": ((lambda: _wide_dups(19)), 4, {}, False, True),
+}
+
+NO_FIT = ("key range needs 32 bits + 12 tag bits > 30: enable jax x64 for "
+          "int64 packing, or pass tag=False for known-distinct keys")
+UNTAGGED = (False, 0, 0, 0, None, False)
+# id: (the plan's fields (tagged, tag_b, key_min, key_max, pack dtype,
+# bijection cached) or the ValueError it raises, whether duplicate
+# detection ran). The fields are what make_plan gave when it detected
+# before it read the key range; detection runs only where a tag fits.
+PLAN_WANT = {
+    "full_i32_p4": (UNTAGGED, False),
+    "full_i32_p1": (UNTAGGED, False),
+    "narrow_dups": ((True, 12, 0, 99, "int32", True), True),
+    "narrow_distinct": (UNTAGGED, True),
+    "max_forces_tag": ((True, 12, 2147479551, 2147483647, "int32", True),
+                       False),
+    "max_required_no_fit": (NO_FIT, False),
+    "max_tag_false": ("keys contain the int32 sentinel value (dtype max, or "
+                      "a NaN payload mapping onto it) reserved by the "
+                      "untagged path (tag=False): remove those keys or drop "
+                      "tag=False", False),
+    "stable": ((True, 12, 0, 99, "int32", True), False),
+    "stable_no_fit": (NO_FIT, False),
+    "tag_true": ((True, 12, 0, 4095, "int32", True), False),
+    "tag_false": (UNTAGGED, False),
+    "argsort_tag_false": ("argsort/sort_kv require tagging (tag=False set)",
+                          False),
+    "argsort": ((True, 12, 0, 99, "int32", True), False),
+    "full_f32": ((False, 0, 0, 0, None, True), False),
+    "batch_full_i32": (UNTAGGED, False),
+    "batch_dup_row": ((True, 10, 0, 4095, "int32", True), True),
+    "x64_full_i32_fits_int64": (UNTAGGED, True),
+    "x64_full_i64": (UNTAGGED, False),
+    "x64_wide_dups_int64": ((True, 12, 102, 1046868, "int64", True), True),
+}
+
+
+def _plan_fields(plan):
+    return (plan.tagged, plan.tag_b, plan.key_min, plan.key_max,
+            None if plan.pack_dtype is None
+            else np.dtype(plan.pack_dtype).name, plan._enc is not None)
+
+
+def _make_plan_case(case):
+    keys, p, fields, want_indices, _ = PLAN_CASES[case]
+    return adapters.make_plan(jnp.asarray(keys()), SortSpec(**fields), p,
+                     want_indices=want_indices)
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_CASES))
+def test_plan_detects_only_where_a_tag_fits(monkeypatch, case):
+    """The plan (or the error) for each input, and whether it paid for a
+    duplicate-detection sort: only where a tag could be packed."""
+    calls = []
+    real = adapters.detect_duplicates
+
+    def counting(x):
+        calls.append(x.shape)
+        return real(x)
+
+    monkeypatch.setattr(adapters, "detect_duplicates", counting)
+    want, detects = PLAN_WANT[case]
+    x64 = PLAN_CASES[case][-1]
+    with jax.enable_x64(x64):
+        if isinstance(want, str):
+            with pytest.raises(ValueError) as err:
+                _make_plan_case(case)
+            assert str(err.value) == want
+        else:
+            assert _plan_fields(_make_plan_case(case)) == want
+    assert bool(calls) is detects

@@ -18,7 +18,7 @@ from repro.runtime import compile_cache, spans
 from repro.serve import ServiceConfig, SortService
 from repro.serve.metrics import PHASES
 from repro.sort import SortSpec, driver, sort
-from repro.sort.adapters import detect_duplicates
+from repro.sort.adapters import detect_duplicates, key_range
 
 ROUND_ALGORITHMS = ("hss", "multistage")
 
@@ -66,6 +66,18 @@ def test_detect_duplicates_is_one_program(keys, want):
     x = jnp.asarray(np.asarray(keys, np.int32))
     assert bool(detect_duplicates(x)) is want
     assert f"@{spans.DETECT_MODULE}" in detect_duplicates.lower(x).as_text()
+
+
+@pytest.mark.parametrize("keys, dtype", [([3, -7, 2, 9], np.int32),
+                                         ([[1, 2], [5, -5]], np.int32),
+                                         ([0, 2**32 - 1, 7], np.uint32)])
+def test_key_range_is_one_program(keys, dtype):
+    """The plan reads min and max together: one program, one array."""
+    x = jnp.asarray(np.asarray(keys, dtype))
+    got = np.asarray(key_range(x))
+    assert got.shape == (2,) and got.dtype == dtype
+    assert [int(v) for v in got] == [int(np.min(keys)), int(np.max(keys))]
+    assert f"@{spans.RANGE_MODULE}" in key_range.lower(x).as_text()
 
 
 def test_compile_counter_counts_backend_compiles():
@@ -160,3 +172,41 @@ def test_serve_batch_span_encloses_front_door_and_results(tmp_path):
                     spans.H2D, spans.LAUNCH, spans.GATHER} <= inner, inner
             checked += 1
     assert checked == len(reqs)
+
+
+def test_detect_span_nests_in_plan_only_where_it_detects(tmp_path):
+    """`sort.detect` is a host span inside `sort.plan`, and a plan records
+    it only where a tag fits: narrow-range keys detect, full-range int32
+    keys (32 key bits, x64 off) never do."""
+    assert spans.DETECT in spans.HOST_SPANS
+    rng = np.random.default_rng(7)
+    inputs = {
+        "narrow": rng.integers(0, 64, size=1024, dtype=np.int32),
+        "full": rng.integers(np.iinfo(np.int32).min, np.iinfo(np.int32).max,
+                             size=1024, dtype=np.int32),
+    }
+    spec = SortSpec(mesh=_one_chip_spec().mesh)   # tag=None: auto
+    for x in inputs.values():                # compile outside the trace
+        sort(jnp.asarray(x), spec)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for name, x in inputs.items():
+            with spans.span(f"test.{name}"):
+                np.testing.assert_array_equal(
+                    sort(jnp.asarray(x), spec).gather(), np.sort(x))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    detects = {}
+    for evs in _events_by_thread(path).values():
+        windows = {n: (s, e) for n, s, e in evs if n.startswith("test.")}
+        plans = [(s, e) for n, s, e in evs if n == spans.PLAN]
+        for n, s, e in evs:
+            if n != spans.DETECT:
+                continue
+            assert any(ps <= s and e <= pe for ps, pe in plans)
+            for w, (ws, we) in windows.items():
+                if ws <= s and e <= we:
+                    detects[w] = detects.get(w, 0) + 1
+    assert detects == {"test.narrow": 1}
