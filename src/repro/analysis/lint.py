@@ -78,8 +78,7 @@ def run_contracts(results) -> None:
     from repro.analysis.contracts import CommsContract
     from repro.analysis.programs import (
         make_topk_program, partitioner_program, splitters_program)
-    from repro.core.exchange import (
-        BATCH_FUSED_STRATEGIES, EXCHANGE_COLLECTIVES)
+    from repro.core.exchange import EXCHANGE_COLLECTIVES
     from repro.sort.partitioners import MULTISTAGE_BASE_COLLECTIVES
 
     exchanges = tuple(EXCHANGE_COLLECTIVES)
@@ -116,12 +115,11 @@ def run_contracts(results) -> None:
                    contracts.check_jaxpr(jx, full))
             reports.append(comms.analyze_jaxpr(
                 jx, label=f"{algo}+{exchange}").to_json())
-            if exchange in BATCH_FUSED_STRATEGIES:
-                _check(results, "contracts", f"{algo}+{exchange}[batch]",
-                       contracts.check_batch_invariance(
-                           lambda b, a=algo, e=exchange: partitioner_program(
-                               a, exchange=e, batch=b, p=P, n_local=N_LOCAL),
-                           full, batches=BATCHES))
+            _check(results, "contracts", f"{algo}+{exchange}[batch]",
+                   contracts.check_batch_invariance(
+                       lambda b, a=algo, e=exchange: partitioner_program(
+                           a, exchange=e, batch=b, p=P, n_local=N_LOCAL),
+                       full, batches=BATCHES))
 
     print("contracts: multistage (base + 2 exchanges)")
     for exchange in exchanges:
@@ -137,6 +135,12 @@ def run_contracts(results) -> None:
                contracts.check_jaxpr(jx, full))
         reports.append(comms.analyze_jaxpr(
             jx, label=f"multistage+{exchange}").to_json())
+        _check(results, "contracts", f"multistage+{exchange}[batch]",
+               contracts.check_batch_invariance(
+                   lambda b, e=exchange: partitioner_program(
+                       "multistage", exchange=e, batch=b, p=P,
+                       n_local=N_LOCAL),
+                   full, batches=BATCHES))
 
     print("contracts: kernel-policy independence (hss+dense)")
     from repro.sort.spec import SortSpec

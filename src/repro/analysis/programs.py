@@ -51,7 +51,8 @@ def partitioner_program(algo: str, *, exchange: str = "dense",
 
     Returns ``(fn, args)`` ready for ``jax.make_jaxpr(fn)(*args)``;
     ``batch=None`` builds the single-request program, an int builds the
-    batched one.
+    batched one: the same pipeline under ``jax.vmap``, as
+    ``driver.run_batched`` runs it.
     """
     part = get_partitioner(algo)
     spec = spec or SortSpec(algorithm=algo, exchange=exchange)
@@ -61,27 +62,20 @@ def partitioner_program(algo: str, *, exchange: str = "dense",
 
     lead = (1,) * naxes   # the driver's leading shard dims (one per axis)
 
-    if batch is None:
-        def per_shard(block, key):
-            rng = jr.fold_in(key, jax.lax.axis_index(names[0]))
+    def per_shard(block, key):
+        rng = jr.fold_in(key, jax.lax.axis_index(names[0]))
+        if batch is None:
             out = part.sharded(block.reshape(-1), rng, ctx)[0]
             return out.reshape(lead + out.shape)
+        out = jax.vmap(lambda row: part.sharded(row, rng, ctx)[0])(
+            block.reshape(batch, n_local))
+        return out.reshape((batch,) + lead + out.shape[1:])
 
-        sharded = P(*names)
-        fn = shard_map(per_shard, mesh=mesh, in_specs=(sharded, P()),
-                       out_specs=sharded)
-        shape = sizes + (n_local,)
-    else:
-        def per_shard(block, key):
-            rng = jr.fold_in(key, jax.lax.axis_index(names[0]))
-            out = part.sharded_batched(block.reshape(batch, n_local),
-                                       rng, ctx)[0]
-            return out.reshape((batch,) + lead + out.shape[1:])
-
-        sharded = P(None, *names)
-        fn = shard_map(per_shard, mesh=mesh, in_specs=(sharded, P()),
-                       out_specs=sharded)
-        shape = (batch,) + sizes + (n_local,)
+    sharded = P(*names) if batch is None else P(None, *names)
+    fn = shard_map(per_shard, mesh=mesh, in_specs=(sharded, P()),
+                   out_specs=sharded)
+    shape = sizes + (n_local,) if batch is None else (
+        (batch,) + sizes + (n_local,))
     return fn, (jax.ShapeDtypeStruct(shape, dtype), jr.key(0))
 
 
@@ -94,30 +88,20 @@ def splitters_program(algo: str, *, batch: Optional[int] = None, p: int = 8,
     part = get_partitioner(algo)
     spec = spec or SortSpec(algorithm=algo)
     mesh, names, sizes = _mesh_for(part, spec, p)
-    if batch is None:
-        def per_shard(block, key):
-            rng = jr.fold_in(key, jax.lax.axis_index(names[0]))
-            ls = jnp.sort(block.reshape(-1))
-            keys, _, _, _ = part.splitters(
-                ls, ShardCtx(spec=spec, axis_names=names, sizes=sizes,
-                             rng=rng))
-            return keys
 
-        fn = shard_map(per_shard, mesh=mesh, in_specs=(P(*names), P()),
-                       out_specs=P())
-        shape = sizes + (n_local,)
-    else:
-        def per_shard(block, key):
-            rng = jr.fold_in(key, jax.lax.axis_index(names[0]))
-            ls = jnp.sort(block.reshape(batch, n_local), axis=-1)
-            keys, _, _, _ = part.splitters_batched(
-                ls, ShardCtx(spec=spec, axis_names=names, sizes=sizes,
-                             rng=rng))
-            return keys
+    def per_shard(block, key):
+        rng = jr.fold_in(key, jax.lax.axis_index(names[0]))
+        ctx = ShardCtx(spec=spec, axis_names=names, sizes=sizes, rng=rng)
+        keys = lambda row: part.splitters(jnp.sort(row), ctx)[0]
+        if batch is None:
+            return keys(block.reshape(-1))
+        return jax.vmap(keys)(block.reshape(batch, n_local))
 
-        fn = shard_map(per_shard, mesh=mesh, in_specs=(P(None, *names), P()),
-                       out_specs=P())
-        shape = (batch,) + sizes + (n_local,)
+    sharded = P(*names) if batch is None else P(None, *names)
+    fn = shard_map(per_shard, mesh=mesh, in_specs=(sharded, P()),
+                   out_specs=P())
+    shape = sizes + (n_local,) if batch is None else (
+        (batch,) + sizes + (n_local,))
     return fn, (jax.ShapeDtypeStruct(shape, dtype), jr.key(0))
 
 

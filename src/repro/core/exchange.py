@@ -54,11 +54,9 @@ from repro.runtime import spans
 #: fused away by construction) + spill payload/count all_gathers + one
 #: truncation psum; allgather: payload + counts all_gather + truncation
 #: psum; ragged: counts + offsets all_to_all around one ragged_all_to_all
-#: (only XLA:TPU compiles it; the lint traces it anywhere). The batched
-#: variants fuse the same collectives across B
-#: for dense/allgather (B-invariant, also proven by the lint);
-#: dense_spill_batched and ragged_batched run per-row loops (documented
-#: above) and are exempt from batch invariance.
+#: (only XLA:TPU compiles it; the lint traces it anywhere). A batched
+#: launch is `jax.vmap` of these, which issues the same collectives over
+#: the whole batch (B-invariant, proven by the lint for every strategy).
 EXCHANGE_COLLECTIVES = {
     "dense": {"all_to_all": 2, "all_gather": 0, "psum": 2},
     "dense_spill": {"all_to_all": 2, "all_gather": 2, "psum": 1},
@@ -66,9 +64,6 @@ EXCHANGE_COLLECTIVES = {
     "ragged": {"all_to_all": 2, "all_gather": 0, "ragged_all_to_all": 1,
                "psum": 0},
 }
-
-#: Batched exchange strategies whose collective count is B-invariant.
-BATCH_FUSED_STRATEGIES = ("dense", "allgather")
 
 
 def _kernels():
@@ -358,149 +353,11 @@ def exchange_ragged(local_sorted, splitter_keys, *, axis_name, p, cfg, eps,
     return out, n_valid, jnp.zeros((), jnp.int32)
 
 
-# ---------------------------------------------------------------------------
-# Batched exchange: B independent requests, one collective per phase.
-#
-# The batched sort engine (repro.sort.api.sort_batched) runs B requests
-# through a single shard_map launch; the exchange is where the per-request
-# collectives would otherwise multiply. Each strategy's batched variant
-# moves the whole (B, ...) payload in ONE collective:
-#   dense      one all_to_all over a (p, B, cap) buffer (+ one for counts);
-#   allgather  one all_gather of the (B, n_local) shard;
-#   ragged     per-request ragged_all_to_all loop (TPU-only; the opcode
-#              takes one chunk per peer, so fusing B requests would need a
-#              repacked staging buffer — future work, documented in
-#              DESIGN.md Section 6).
-# Per-request results are bit-identical to the unbatched strategy run on
-# that request's row.
-# ---------------------------------------------------------------------------
-
-
-def _rows_valid(n_valid, b, n):
-    """Normalize the batched n_valid parameter to a (B,) vector: None means
-    every slot is real; a scalar applies to every request; (B,) per-request
-    counts pass through."""
-    if n_valid is None:
-        return jnp.full((b,), n, jnp.int32)
-    return jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), (b,))
-
-
-def exchange_dense_batched(local_sorted, splitter_keys, *, axis_name, p, cfg,
-                           eps, n_valid=None):
-    """Batched capacity-padded all-to-all: local_sorted (B, n_local),
-    splitter_keys (B, p-1) -> (out (B, out_cap), n_valid (B,), ovf (B,))."""
-    b, n = local_sorted.shape
-    cap = cfg.pair_cap(n, p)
-    out_cap = cfg.out_cap(n, p, eps)
-
-    dispatch, gather_runs = _kernels()
-    with spans.scope(spans.EXCHANGE):
-        starts, counts = jax.vmap(destination_slices)(
-            local_sorted, splitter_keys, _rows_valid(n_valid, b, n))  # (B, p)
-        sent_counts = jnp.minimum(counts, cap)
-        overflow = jax.lax.psum(
-            jnp.sum(counts - sent_counts, axis=1), axis_name)  # (B,)
-        buf = jax.vmap(gather_runs, in_axes=(0, 0, 0, None))(
-            local_sorted, starts, sent_counts, cap)          # (B, p, cap)
-
-        # ONE all_to_all for the whole batch: shard axis leading.
-        recv = jax.lax.all_to_all(jnp.swapaxes(buf, 0, 1), axis_name, 0, 0,
-                                  tiled=False)               # (p, B, cap)
-        recv = jnp.swapaxes(recv, 0, 1)                      # (B, p, cap)
-        recv_counts = jax.lax.all_to_all(
-            sent_counts.T[..., None], axis_name, 0, 0, tiled=False)[..., 0].T
-
-    with spans.scope(spans.MERGE):
-        merged = dispatch.merge_runs_batched(recv, policy=cfg.kernel_policy)
-        out = _cap_to(merged, out_cap)
-    n_recv = jnp.sum(recv_counts, axis=1)                # (B,)
-    trunc = jnp.maximum(n_recv - out_cap, 0)
-    overflow = overflow + jax.lax.psum(trunc, axis_name)
-    return out, n_recv - trunc, overflow
-
-
-def exchange_allgather_batched(local_sorted, splitter_keys, *, axis_name, p,
-                               cfg, eps, n_valid=None):
-    b, n = local_sorted.shape
-    out_cap = cfg.out_cap(n, p, eps)
-    me = jax.lax.axis_index(axis_name)
-
-    dispatch, gather_runs = _kernels()
-    with spans.scope(spans.EXCHANGE):
-        # ONE all_gather of the whole (B, n_local) shard.
-        everything = jax.lax.all_gather(local_sorted, axis_name)  # (p, B, n)
-        nv = jax.lax.all_gather(_rows_valid(n_valid, b, n),
-                                axis_name)                        # (p, B)
-        lo = splitter_keys[:, jnp.maximum(me - 1, 0)]             # (B,)
-        hi = splitter_keys[:, jnp.minimum(me, p - 2)]             # (B,)
-        search = jax.vmap(jax.vmap(
-            lambda r, q: jnp.searchsorted(r, q, side="left"),
-            in_axes=(0, 0)), in_axes=(0, None))
-        a = search(everything, lo)                                # (p, B)
-        bq = search(everything, hi)
-        a = jnp.where(me > 0, a.astype(jnp.int32), 0)
-        bq = jnp.where(me < p - 1, bq.astype(jnp.int32), n)
-        ends = jnp.minimum(bq, nv)
-        starts = jnp.minimum(a, ends)
-        counts = ends - starts                                    # (p, B)
-        n_out = jnp.sum(counts, axis=0)                           # (B,)
-        flat = jnp.swapaxes(everything, 0, 1).reshape(b, p * n)   # (B, p*n)
-        flat_starts = (jnp.arange(p, dtype=jnp.int32)[:, None] * n
-                       + starts).T
-        runs = jax.vmap(gather_runs, in_axes=(0, 0, 0, None))(
-            flat, flat_starts, counts.T, n)                       # (B, p, n)
-
-    with spans.scope(spans.MERGE):
-        merged = dispatch.merge_runs_batched(runs, policy=cfg.kernel_policy)
-        vals = _cap_to(merged, out_cap)
-    trunc = jnp.maximum(n_out - out_cap, 0)
-    return vals, n_out - trunc, jax.lax.psum(trunc, axis_name)
-
-
-def exchange_ragged_batched(local_sorted, splitter_keys, *, axis_name, p,
-                            cfg, eps, n_valid=None):
-    """Per-request ragged_all_to_all loop (see module note above): still one
-    *launch* for the batch, B exact alltoallv collectives inside it."""
-    b, n = local_sorted.shape
-    rows_valid = _rows_valid(n_valid, b, n)
-    outs, nvs, ovfs = [], [], []
-    for i in range(b):
-        o, nv, ov = exchange_ragged(
-            local_sorted[i], splitter_keys[i], axis_name=axis_name, p=p,
-            cfg=cfg, eps=eps, n_valid=rows_valid[i])
-        outs.append(o), nvs.append(nv), ovfs.append(ov)
-    return jnp.stack(outs), jnp.stack(nvs), jnp.stack(ovfs)
-
-
-def exchange_dense_spill_batched(local_sorted, splitter_keys, *, axis_name,
-                                 p, cfg, eps, n_valid=None):
-    """Per-request dense_spill loop: still ONE launch for the batch, B x
-    the collectives of a single request inside it (the spill channel's
-    per-row windows do not batch-fuse yet — same status as the ragged
-    strategy; DESIGN.md Section 6 tracks the fusion)."""
-    b, n = local_sorted.shape
-    rows_valid = _rows_valid(n_valid, b, n)
-    outs, nvs, ovfs = [], [], []
-    for i in range(b):
-        o, nv, ov = exchange_dense_spill(
-            local_sorted[i], splitter_keys[i], axis_name=axis_name, p=p,
-            cfg=cfg, eps=eps, n_valid=rows_valid[i])
-        outs.append(o), nvs.append(nv), ovfs.append(ov)
-    return jnp.stack(outs), jnp.stack(nvs), jnp.stack(ovfs)
-
-
 _STRATEGIES = {
     "dense": exchange_dense,
     "dense_spill": exchange_dense_spill,
     "ragged": exchange_ragged,
     "allgather": exchange_allgather,
-}
-
-_STRATEGIES_BATCHED = {
-    "dense": exchange_dense_batched,
-    "dense_spill": exchange_dense_spill_batched,
-    "ragged": exchange_ragged_batched,
-    "allgather": exchange_allgather_batched,
 }
 
 
@@ -510,22 +367,6 @@ def exchange(local_sorted, splitter_keys, *, axis_name, p,
     cfg = cfg or ExchangeConfig()
     try:
         fn = _STRATEGIES[cfg.strategy]
-    except KeyError:
-        raise ValueError(f"unknown exchange strategy {cfg.strategy!r}") from None
-    return fn(local_sorted, splitter_keys, axis_name=axis_name, p=p,
-              cfg=cfg, eps=eps, n_valid=n_valid)
-
-
-def exchange_batched(local_sorted, splitter_keys, *, axis_name, p,
-                     cfg: ExchangeConfig | None = None, eps: float = 0.05,
-                     n_valid=None):
-    """Redistribute B requests at once: local_sorted (B, n_local),
-    splitter_keys (B, p-1) -> (out (B, out_cap), n_valid (B,), ovf (B,)).
-    The `n_valid` parameter may be None (all slots real), a scalar shared
-    by every request, or a per-request (B,) vector."""
-    cfg = cfg or ExchangeConfig()
-    try:
-        fn = _STRATEGIES_BATCHED[cfg.strategy]
     except KeyError:
         raise ValueError(f"unknown exchange strategy {cfg.strategy!r}") from None
     return fn(local_sorted, splitter_keys, axis_name=axis_name, p=p,

@@ -77,6 +77,13 @@ class SplitterStats(NamedTuple):
     rounds_used: jax.Array     # scalar: first round after which all satisfied (1-based)
 
 
+#: Where a batched launch (`repro.sort.driver.run_batched`, a `jax.vmap`
+#: of the single-request pipeline) puts the batch axis of each stats field:
+#: per-round arrays become (k, B), `rounds_used` (B,).
+BATCH_AXES = SplitterStats(gamma_size=1, sample_count=1, overflow=1,
+                           n_satisfied=1, rounds_used=0)
+
+
 def splitter_targets(n: int, p: int) -> jax.Array:
     """Target ranks t_i = N*i/p for i = 1..p-1."""
     import numpy as np
@@ -418,122 +425,6 @@ def hss_splitters_lex(planes, *, axis_name: str, p: int, cfg: HSSConfig,
         ranks=ranks)
     return hss_splitters(planes[0], axis_name=axis_name, p=p, cfg=cfg,
                          rng=rng, shard_keys=keys)
-
-
-def hss_splitters_batched(
-    local_sorted: jax.Array,
-    *,
-    axis_name: str,
-    p: int,
-    cfg: HSSConfig,
-    rng: jax.Array,
-    initial_probes: jax.Array | None = None,
-):
-    """Splitter determination for B independent sorts in one pipeline.
-
-    local_sorted is (B, n_local): row b is request b's shard, sorted. The
-    splitter-interval state is stacked (B, p-1) and every pure helper
-    (membership, refine, choose) is vmapped over it; the *collectives* are
-    not vmapped but fused — per round, the B per-request sample buffers are
-    concatenated into one (B, cap) buffer so the round issues exactly one
-    `all_gather` and one `psum` regardless of B (the batched amortization
-    this engine exists for; DESIGN.md Section 6).
-
-    Every request draws from the same per-shard rng stream, which is
-    exactly what B sequential `hss_splitters` calls with the same seed do —
-    so the result is bit-identical to the per-request loop.
-
-    initial_probes: optional (B, m) per-request sorted probe rows to
-    warm-start round 1 with (the unbatched path's ChaNGa trick; the
-    overflow-retry policy feeds the failed attempt's splitters back in
-    here so a re-launch starts from converged partition state).
-
-    Returns (splitter_keys (B, p-1), splitter_ranks (B, p-1), SplitterStats
-    with per-round arrays of shape (k, B) and rounds_used of shape (B,)).
-    """
-    batch, n_local = local_sorted.shape
-    n = n_local * p
-    dtype = local_sorted.dtype
-    k = cfg.resolved_rounds(p)
-    cap = cfg.resolved_sample_cap(p)
-    tol = jnp.int32(max(1, int(n * cfg.eps / (2 * p))))
-    targets = splitter_targets(n, p)
-    f_total = float(cap * p) / 2.0
-    ratios = jnp.asarray(sampling_ratios(p, cfg.eps, k), jnp.float32)
-
-    s0 = init_state(p, n, dtype)
-    state0 = SplitterState(
-        *(jnp.broadcast_to(a, (batch,) + a.shape) for a in s0))
-    vm_union = jax.vmap(active_union_size, in_axes=(0, None))
-    vm_members = jax.vmap(gamma_membership)
-    vm_refine = jax.vmap(refine, in_axes=(0, 0, 0, None, None))
-
-    if initial_probes is not None:
-        # Free warm-start (batched): rank every request's probe row with
-        # one batched probe-rank pass + one psum, then refine per row.
-        lr = dispatch.probe_ranks_batched(
-            local_sorted, initial_probes, policy=cfg.kernel_policy,
-            assume_sorted=True)
-        pr = jax.lax.psum(lr, axis_name)
-        state0 = vm_refine(state0, initial_probes, pr, targets, tol)
-
-    def round_body(carry, j):
-        state, key = carry
-        key, sub = jr.split(key)
-        gamma = vm_union(state, targets)                        # (B,)
-        if cfg.adaptive:
-            prob = jnp.minimum(
-                1.0, f_total / jnp.maximum(gamma, 1).astype(jnp.float32))
-        else:
-            prob = jnp.full((batch,),
-                            jnp.minimum(1.0, ratios[j] / float(n_local)))
-
-        def do_round(state):
-            in_g = vm_members(local_sorted, state)              # (B, n_local)
-            u = jr.uniform(sub, (n_local,))  # one stream, all requests —
-            # matches B sequential same-seed calls (bit-identity contract)
-            mask = in_g & (u[None, :] < prob[:, None])
-            n_hit = jnp.sum(mask.astype(jnp.int32), axis=1)
-            vals = jnp.where(mask, local_sorted, hi_sentinel(dtype))
-            vals = dispatch.local_sort_batched(
-                vals, policy=cfg.kernel_policy)[:, :cap]
-            ovf = jnp.maximum(n_hit - cap, 0)
-            n_samp = n_hit - ovf
-            g = jax.lax.all_gather(vals, axis_name)   # ONE gather: (p, B, cap)
-            probes = dispatch.local_sort_batched(
-                jnp.transpose(g, (1, 0, 2)).reshape(batch, p * cap),
-                policy=cfg.kernel_policy)
-            local_ranks = dispatch.probe_ranks_batched(
-                local_sorted, probes, policy=cfg.kernel_policy,
-                assume_sorted=True)
-            packed = jax.lax.psum(                    # ONE fused reduction
-                jnp.concatenate(
-                    [local_ranks,
-                     jnp.stack([n_samp, ovf], axis=1).astype(jnp.int32)],
-                    axis=1),
-                axis_name)
-            state = vm_refine(state, probes, packed[:, :-2], targets, tol)
-            return state, packed[:, -2], packed[:, -1]
-
-        def skip_round(state):
-            z = jnp.zeros((batch,), jnp.int32)
-            return state, z, z
-
-        state, cnt, ovf = jax.lax.cond(
-            jnp.all(state.satisfied), skip_round, do_round, state)
-        stats = (gamma, cnt, ovf,
-                 jnp.sum(state.satisfied.astype(jnp.int32), axis=1))
-        return (state, key), stats
-
-    with spans.scope(spans.SPLITTER_ROUND):   # the rounds and their loop
-        (state, _), (gam, cnt, ovf, nsat) = jax.lax.scan(
-            round_body, (state0, rng), jnp.arange(k))
-    keys, ranks = jax.vmap(choose_splitters, in_axes=(0, None))(state, targets)
-    all_sat = nsat >= (p - 1)                                   # (k, B)
-    rounds_used = jnp.where(jnp.any(all_sat, axis=0),
-                            1 + jnp.argmax(all_sat, axis=0), jnp.int32(k))
-    stats = SplitterStats(gam, cnt, ovf, nsat, rounds_used)
-    return keys, ranks, stats
 
 
 def heavy_candidates(sample_sorted: jax.Array, *, max_heavy: int,

@@ -117,24 +117,6 @@ def probe_ranks(keys, probes, *, policy: str = "auto",
     return hops.probe_ranks(keys, probes)
 
 
-def probe_ranks_batched(keys, probes, *, policy: str = "auto",
-                        assume_sorted: bool = False):
-    """Per-request ranks: rank[b, m] = #{keys[b] < probes[b, m]} as int32.
-
-    keys (B, n), probes (B, M) -> (B, M). The Pallas histogram kernel runs
-    the whole batch on one (B, tiles) grid; the XLA path vmaps the same
-    primitives the unbatched dispatch uses (bit-identical)."""
-    if probes.shape[1] == 0:
-        return jnp.zeros(probes.shape, jnp.int32)
-    if resolve_policy(policy, keys.dtype) == "xla":
-        if assume_sorted:
-            return jax.vmap(
-                lambda k, q: jnp.searchsorted(k, q, side="left")
-            )(keys, probes).astype(jnp.int32)
-        return jax.vmap(href.probe_ranks_ref)(keys, probes)
-    return hops.probe_ranks_batched(keys, probes)
-
-
 def merge_runs(runs, *, policy: str = "auto", vmem_block: int | None = None):
     """Merge the k sorted rows of a (k, r) array -> (k*r,) sorted.
 
@@ -147,16 +129,6 @@ def merge_runs(runs, *, policy: str = "auto", vmem_block: int | None = None):
     return mops.merge_sorted_runs(runs, vmem_block=vmem_block)
 
 
-def merge_runs_batched(runs, *, policy: str = "auto",
-                       vmem_block: int | None = None):
-    """Per-request k-way merge: (B, k, r) sorted rows -> (B, k*r) sorted
-    rows, bit-identical per row to `merge_runs` on that row. One cascade
-    pass per level covers the whole batch (batch grid dimension)."""
-    if resolve_policy(policy, runs.dtype) == "xla":
-        return jnp.sort(runs.reshape(runs.shape[0], -1), axis=-1)
-    return mops.merge_sorted_runs_batched(runs, vmem_block=vmem_block)
-
-
 def merge_ragged(buf, starts, counts, *, policy: str = "auto",
                  slot: int | None = None, vmem_block: int | None = None):
     """Sort a flat buffer holding sorted runs at traced offsets (sentinel
@@ -166,17 +138,6 @@ def merge_ragged(buf, starts, counts, *, policy: str = "auto",
         return jnp.sort(buf)
     return mops.merge_ragged_runs(buf, starts, counts, slot=slot,
                                   vmem_block=vmem_block)
-
-
-def merge_ragged_batched(buf, starts, counts, *, policy: str = "auto",
-                         slot: int | None = None,
-                         vmem_block: int | None = None):
-    """Batched `merge_ragged`: (B, cap) buffers, (B, k) traced offsets and
-    counts. Bit-identical to `jnp.sort(buf, axis=-1)`."""
-    if resolve_policy(policy, buf.dtype) == "xla":
-        return jnp.sort(buf, axis=-1)
-    return mops.merge_ragged_runs_batched(buf, starts, counts, slot=slot,
-                                          vmem_block=vmem_block)
 
 
 def sort_planes(planes, num_keys: int):

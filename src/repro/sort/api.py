@@ -17,6 +17,7 @@ handle for you.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -220,21 +221,28 @@ def _sort_batched_impl(xs, spec: SortSpec,
                    initial_probes=probes)
     p1_sort = (jax.vmap(spec.local_sort_fn) if spec.local_sort_fn is not None
                else dispatch.local_sort_batched_fn(spec.kernel_policy))
-    sort_fn = lambda local, rng: part.sharded_batched(local, rng, ctx)
+    sort_fn = lambda local, rng: part.sharded(local, rng, ctx)
+    row_args = ()
+    if probes is not None and probes.ndim == 2:   # one probe row a request
+        sort_fn = lambda local, rng, pr: part.sharded(
+            local, rng, dataclasses.replace(ctx, initial_probes=pr))
+        row_args = (probes,)
     cache_key = _cache_key(spec, names, sizes, enc, kind="batched")
-    audit = spec.verify != "off" and p > 1
-    if audit:
+    wrap = None
+    if spec.verify != "off" and p > 1:
         corrupt = chaos.corrupt_now()
         if corrupt is not None:
             cache_key = None   # a corrupted executable must never be cached
-        sort_fn = verify.audited(sort_fn, tier=spec.verify, axis_names=names,
-                                 sizes=sizes, batched=True, corrupt=corrupt)
+        wrap = functools.partial(
+            verify.audited, tier=spec.verify, axis_names=names, sizes=sizes,
+            batched=True, corrupt=corrupt)
     raw = driver.run_batched(
         sort_fn,
         enc, mesh=spec.mesh, axis_names=names, sizes=sizes, seed=spec.seed,
-        n_real=plan.n, local_sort_fn=p1_sort, cache_key=cache_key)
+        n_real=plan.n, local_sort_fn=p1_sort, cache_key=cache_key,
+        row_args=row_args, wrap=wrap)
     audit_vec = None
-    if audit:
+    if wrap is not None:
         raw, audit_vec = verify.split_raw(raw)
     elif spec.verify != "off":   # p == 1 short-circuit bypasses sort_fn
         audit_vec = verify.audit_p1(enc, raw[0], raw[1], spec.verify)

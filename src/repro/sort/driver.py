@@ -22,10 +22,10 @@ The shard-level contract: `sort_fn(local, rng)` returns
 is the shard's sentinel-padded sorted slice of static shape and `stats` is a
 `SplitterStats` (or any fixed pytree, replicated across shards).
 
-`run_batched` is the same skeleton with a leading batch dimension: B
-equal-length requests in one shard_map launch (DESIGN.md Section 6), with
-`sort_fn` receiving this shard's (B, n_local) block. Both entry points
-take a `cache_key` that opts into the compiled-executable cache
+`run_batched` launches B equal-length requests in one shard_map launch
+(DESIGN.md Section 6): it takes the same single-request `sort_fn` and maps
+it over this shard's (B, n_local) block with one `jax.vmap`. Both entry
+points take a `cache_key` that opts into the compiled-executable cache
 (`exec_cache`) so steady-state serving never re-traces.
 """
 from __future__ import annotations
@@ -41,6 +41,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.common import hi_sentinel, lo_sentinel
+from repro.core.splitters import BATCH_AXES
 from repro.parallel.compat import make_mesh, shard_map
 from repro.runtime import compile_cache, spans
 
@@ -333,21 +334,27 @@ def run(sort_fn, x, *, mesh=None, axis_names=("sort",), sizes=None, seed=0,
 
 
 def run_batched(sort_fn, xs, *, mesh=None, axis_names=("sort",), sizes=None,
-                seed=0, n_real=None, local_sort_fn=None, cache_key=None):
+                seed=0, n_real=None, local_sort_fn=None, cache_key=None,
+                stats_axes=BATCH_AXES, row_args=(), wrap=None):
     """Run B independent shard-level sorts in ONE shard_map launch.
 
-    xs is (B, n): B equal-length key arrays. `sort_fn(local, rng)` receives
-    this shard's (B, n_local) slice of every request and must return the
-    batched 6-tuple ((B, out_cap), (B,), (B, p-1), (B, p-1), (B,), stats)
-    — i.e. a `Partitioner.sharded_batched`. Returns the raw batched tuple
-    (shards (B, p, out_cap), counts (B, p), keys (B, p-1), ranks (B, p-1),
-    overflow (B,), stats).
+    xs is (B, n): B equal-length key arrays. `sort_fn(local, rng)` is the
+    single-request shard function `run` takes; inside the launch it is
+    mapped over this shard's (B, n_local) block with one `jax.vmap`. The
+    rng is not mapped, so every request draws the same samples as a
+    `sort()` of that row alone, and each row of the result is
+    bit-identical to it. Returns the raw batched tuple (shards (B, p,
+    out_cap), counts (B, p), keys (B, p-1), ranks (B, p-1), overflow (B,),
+    stats), the stats laid out by `stats_axes` (the vmap's out_axes for
+    them: per-round `SplitterStats` arrays (k, B), `rounds_used` (B,)).
 
-    Layout: each shard holds a contiguous (B, n_local) column block, so
-    request b's keys land on the same shards as an unbatched sort of row b
-    — which is what makes the batched result bit-identical per request.
-    `local_sort_fn` here is the *batched* (B, n) -> (B, n) local sort for
-    the p == 1 short-circuit. `cache_key`: see `run`.
+    `row_args`: per-request operands with a leading batch axis, mapped
+    with the keys and passed to `sort_fn` after the rng. `wrap`, if
+    given, wraps the mapped (B, n_local) function, as the batched audit
+    does. Layout: each shard holds a contiguous (B, n_local) column
+    block, so request b's keys land on the same shards as an unbatched
+    sort of row b. `local_sort_fn` here is the *batched* (B, n) -> (B, n)
+    local sort for the p == 1 short-circuit. `cache_key`: see `run`.
     """
     plan = resolve_mesh(mesh, axis_names, sizes)
     p = plan.p
@@ -372,6 +379,15 @@ def run_batched(sort_fn, xs, *, mesh=None, axis_names=("sort",), sizes=None,
     xsr = xs.reshape((batch,) + plan.sizes + (n_local,))
     naxes = len(plan.axis_names)
 
+    rows = jax.vmap(sort_fn, in_axes=(0, None) + (0,) * len(row_args),
+                    out_axes=(0, 0, 0, 0, 0, stats_axes))
+
+    def batch_fn(local, rng):
+        return rows(local, rng, *row_args)
+
+    if wrap is not None:
+        batch_fn = wrap(batch_fn)
+
     def build():
         def per_shard(block, key):
             exec_cache.traces += 1
@@ -380,7 +396,7 @@ def run_batched(sort_fn, xs, *, mesh=None, axis_names=("sort",), sizes=None,
             for name, size in zip(plan.axis_names, plan.sizes):
                 me = me * size + jax.lax.axis_index(name)
             rng = jr.fold_in(key, me)
-            out, n_valid, keys, ranks, ovf, stats = sort_fn(local, rng)
+            out, n_valid, keys, ranks, ovf, stats = batch_fn(local, rng)
             lead = (1,) * naxes
             return (out.reshape((batch,) + lead + out.shape[1:]),
                     jnp.asarray(n_valid, jnp.int32).reshape((batch,) + lead),

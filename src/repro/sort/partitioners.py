@@ -26,19 +26,17 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import jax.random as jr
 
 from repro.analysis.contracts import CommsContract, register_contract
-from repro.core.ams import ams_sample_size, ams_splitters, scanning_splitters
-from repro.core.common import RECORD_KEY_WORDS, hi_sentinel, round_up
-from repro.core.exchange import exchange, exchange_batched, exchange_records
+from repro.core.ams import ams_splitters
+from repro.core.common import RECORD_KEY_WORDS
+from repro.core.exchange import exchange, exchange_records
 from repro.core.multistage import two_stage_sort_sharded
 from repro.core.sample_sort import (
     default_regular_s, default_total_sample, random_sample_splitters,
     regular_sample_splitters)
 from repro.core.splitters import (
-    ROUND_COLLECTIVES, SplitterStats, hss_splitters, hss_splitters_batched,
-    hss_splitters_lex)
+    ROUND_COLLECTIVES, SplitterStats, hss_splitters, hss_splitters_lex)
 from repro.kernels import dispatch
 from repro.runtime import spans
 from repro.sort.driver import factor_stages
@@ -78,36 +76,6 @@ def null_stats(n_satisfied=None) -> SplitterStats:
     sat = z if n_satisfied is None else jnp.asarray(n_satisfied, jnp.int32)[None]
     return SplitterStats(gamma_size=z, sample_count=z, overflow=z,
                          n_satisfied=sat, rounds_used=jnp.int32(1))
-
-
-def null_stats_batched(batch: int, n_satisfied=None) -> SplitterStats:
-    """Batched placeholder stats: per-round arrays (1, B), rounds_used (B,)."""
-    z = jnp.zeros((1, batch), jnp.int32)
-    sat = (z if n_satisfied is None
-           else jnp.asarray(n_satisfied, jnp.int32).reshape(1, batch))
-    return SplitterStats(gamma_size=z, sample_count=z, overflow=z,
-                         n_satisfied=sat,
-                         rounds_used=jnp.ones((batch,), jnp.int32))
-
-
-def _bernoulli_sample_rows(local_sorted, prob, cap, rng, kernel_policy):
-    """Bernoulli-sample each row of (B, n_local) into a (B, cap) sorted,
-    sentinel-padded buffer. The sampled *positions* are shared across rows —
-    exactly what B sequential same-seed calls draw — so batched results stay
-    bit-identical to the per-request loop. Returns (vals, n_hit scalar)."""
-    u = jr.uniform(rng, (local_sorted.shape[1],))
-    mask = u < prob
-    n_hit = jnp.sum(mask.astype(jnp.int32))
-    vals = jnp.where(mask[None, :], local_sorted,
-                     hi_sentinel(local_sorted.dtype))
-    vals = dispatch.local_sort_batched(vals, policy=kernel_policy)[:, :cap]
-    return vals, n_hit
-
-
-def _gather_rows(vals, axis_name):
-    """all_gather a (B, cap) buffer once -> per-request (B, p*cap) concat."""
-    g = jax.lax.all_gather(vals, axis_name)              # (p, B, cap)
-    return jnp.transpose(g, (1, 0, 2)).reshape(vals.shape[0], -1)
 
 
 class Partitioner:
@@ -171,29 +139,6 @@ class Partitioner:
             cfg=ctx.ex_cfg, eps=ctx.spec.eps)
         return out, n_valid, keys, ranks, s_ovf + e_ovf, (stats, sent)
 
-    def splitters_batched(self, local_sorted, ctx: ShardCtx):
-        """Batched counterpart of `splitters`: (B, n_local) sorted rows ->
-        ((B, p-1) keys, (B, p-1) ranks, (B,) overflow, batched stats).
-        Collectives must be batch-fused (one per phase), not per-request."""
-        raise NotImplementedError(
-            f"partitioner {self.name!r} does not support batched execution")
-
-    def sharded_batched(self, local, rng, ctx: ShardCtx):
-        """Batched shard-level sort: (B, n_local) rows through one pipeline.
-        Bit-identical per request to `sharded` on that request's row."""
-        sort_local = (dispatch.local_sort_batched_fn(ctx.spec.kernel_policy)
-                      if ctx.spec.local_sort_fn is None
-                      else jax.vmap(ctx.spec.local_sort_fn))
-        with spans.scope(spans.LOCAL_SORT):
-            local_sorted = sort_local(local)
-        with spans.scope(spans.SPLITTERS):
-            keys, ranks, s_ovf, stats = self.splitters_batched(
-                local_sorted, dataclasses.replace(ctx, rng=rng))
-        out, n_valid, e_ovf = exchange_batched(
-            local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
-            cfg=ctx.ex_cfg, eps=ctx.spec.eps)
-        return out, n_valid, keys, ranks, s_ovf + e_ovf, stats
-
     def partition_sorted(self, local_sorted, rng, ctx: ShardCtx, *,
                          n_valid=None, ex_cfg=None):
         """Splitters + exchange over an already-sorted shard — the relaxed
@@ -212,20 +157,6 @@ class Partitioner:
             cfg=ex_cfg if ex_cfg is not None else ctx.ex_cfg,
             eps=ctx.spec.eps, n_valid=n_valid)
         return out, n_out, keys, ranks, s_ovf + e_ovf, stats
-
-    def partition_sorted_batched(self, local_sorted, rng, ctx: ShardCtx, *,
-                                 n_valid=None, ex_cfg=None):
-        """Batched `partition_sorted`: (B, n_local) sorted rows, n_valid
-        None | scalar | (B,)."""
-        with spans.scope(spans.SPLITTERS):
-            keys, ranks, s_ovf, stats = self.splitters_batched(
-                local_sorted, dataclasses.replace(ctx, rng=rng))
-        out, n_out, e_ovf = exchange_batched(
-            local_sorted, keys, axis_name=ctx.axis_name, p=ctx.p,
-            cfg=ex_cfg if ex_cfg is not None else ctx.ex_cfg,
-            eps=ctx.spec.eps, n_valid=n_valid)
-        return out, n_out, keys, ranks, s_ovf + e_ovf, stats
-
 
 _REGISTRY: dict[str, Partitioner] = {}
 
@@ -304,14 +235,6 @@ class HSSPartitioner(Partitioner):
             rng=ctx.rng)
         return keys, ranks, jnp.zeros((), jnp.int32), stats
 
-    def splitters_batched(self, local_sorted, ctx):
-        keys, ranks, stats = hss_splitters_batched(
-            local_sorted, axis_name=ctx.axis_name, p=ctx.p, cfg=ctx.hss_cfg,
-            rng=ctx.rng, initial_probes=ctx.initial_probes)
-        return (keys, ranks,
-                jnp.zeros((local_sorted.shape[0],), jnp.int32), stats)
-
-
 @register_partitioner("sample_random")
 class RandomSamplePartitioner(Partitioner):
     """Random-sampling sample sort (Blelloch et al.; Theorem 3.1)."""
@@ -325,25 +248,6 @@ class RandomSamplePartitioner(Partitioner):
             kernel_policy=ctx.spec.kernel_policy)
         return keys, jnp.zeros_like(keys, jnp.int32), ovf, null_stats()
 
-    def splitters_batched(self, local_sorted, ctx):
-        b, n_local = local_sorted.shape
-        p, policy = ctx.p, ctx.spec.kernel_policy
-        total = ctx.spec.total_sample or default_total_sample(
-            p, n_local, ctx.spec.eps)
-        cap = round_up(max(8, int(3.0 * total / p)), 8)
-        prob = min(1.0, total / float(n_local * p))
-        vals, n_hit = _bernoulli_sample_rows(local_sorted, prob, cap,
-                                             ctx.rng, policy)
-        overflow = jax.lax.psum(jnp.maximum(n_hit - cap, 0), ctx.axis_name)
-        probes = dispatch.local_sort_batched(
-            _gather_rows(vals, ctx.axis_name), policy=policy)
-        n_valid = jax.lax.psum(jnp.minimum(n_hit, cap), ctx.axis_name)
-        idx = (jnp.arange(1, p, dtype=jnp.int32) * n_valid) // p
-        keys = probes[:, idx]
-        return (keys, jnp.zeros_like(keys, jnp.int32),
-                jnp.broadcast_to(overflow, (b,)), null_stats_batched(b))
-
-
 @register_partitioner("sample_regular")
 class RegularSamplePartitioner(Partitioner):
     """Regular-sampling sample sort (PSRS; Theorem 3.2). Deterministic."""
@@ -355,20 +259,6 @@ class RegularSamplePartitioner(Partitioner):
             kernel_policy=ctx.spec.kernel_policy)
         return (keys, jnp.zeros_like(keys, jnp.int32),
                 jnp.zeros((), jnp.int32), null_stats())
-
-    def splitters_batched(self, local_sorted, ctx):
-        b, n_local = local_sorted.shape
-        p, policy = ctx.p, ctx.spec.kernel_policy
-        s = ctx.spec.s or default_regular_s(p, ctx.spec.eps)
-        idx = ((jnp.arange(s, dtype=jnp.int32) + 1) * n_local) // (s + 1)
-        vals = local_sorted[:, idx]
-        probes = dispatch.local_sort_batched(
-            _gather_rows(vals, ctx.axis_name), policy=policy)
-        sidx = (jnp.arange(1, p, dtype=jnp.int32) * (s * p)) // p
-        keys = probes[:, sidx]
-        return (keys, jnp.zeros_like(keys, jnp.int32),
-                jnp.zeros((b,), jnp.int32), null_stats_batched(b))
-
 
 @register_partitioner("ams")
 class AMSPartitioner(Partitioner):
@@ -382,36 +272,11 @@ class AMSPartitioner(Partitioner):
         return keys, ranks, ovf, null_stats(
             jnp.where(ok, ctx.p - 1, 0))
 
-    def splitters_batched(self, local_sorted, ctx):
-        b, n_local = local_sorted.shape
-        p, eps, policy = ctx.p, ctx.spec.eps, ctx.spec.kernel_policy
-        n = n_local * p
-        total = ctx.spec.total_sample or ams_sample_size(p, eps, n)
-        cap = round_up(max(8, int(3.0 * total / p)), 8)
-        prob = min(1.0, total / float(n))
-        vals, n_hit = _bernoulli_sample_rows(local_sorted, prob, cap,
-                                             ctx.rng, policy)
-        ovf = jax.lax.psum(jnp.maximum(n_hit - cap, 0), ctx.axis_name)
-        probes = dispatch.local_sort_batched(
-            _gather_rows(vals, ctx.axis_name), policy=policy)
-        ranks = jax.lax.psum(
-            dispatch.probe_ranks_batched(local_sorted, probes, policy=policy,
-                                         assume_sorted=True),
-            ctx.axis_name)
-        keys, kranks, ok = jax.vmap(
-            lambda pr, rk: scanning_splitters(pr, rk, p=p, n=n, eps=eps)
-        )(probes, ranks)
-        return (keys, kranks, jnp.broadcast_to(ovf, (b,)),
-                null_stats_batched(b, jnp.where(ok, p - 1, 0)))
-
-
 #: Collectives of the two-stage pipeline *outside* its two exchanges: the
 #: group-split and intra-group splitter phases plus group-size bookkeeping
 #: psums. The lint's expected totals for a multistage program are this
 #: base plus 2 x `EXCHANGE_COLLECTIVES[strategy]` (one exchange per
-#: stage). Batched multistage runs a per-row trace loop (B x these
-#: counts — documented in `sharded_batched` below), so it is exempt from
-#: the batch-invariance contract.
+#: stage).
 MULTISTAGE_BASE_COLLECTIVES = {"all_gather": 2, "psum": 7, "all_to_all": 0}
 
 
@@ -437,22 +302,3 @@ class MultistagePartitioner(Partitioner):
         m = jnp.zeros((0,), jnp.int32)
         return (out, n_valid, jnp.zeros((0,), local.dtype), m, ovf,
                 null_stats())
-
-    def sharded_batched(self, local, rng, ctx):
-        # Two nested exchanges with per-group traced valid counts do not
-        # batch-fuse yet: run the rows through a trace-time Python loop —
-        # still ONE shard_map launch for the batch (B x the collectives of
-        # a single request; DESIGN.md Section 6 tracks the fusion).
-        outs, nvs, ovfs = [], [], []
-        for b in range(local.shape[0]):
-            out, n_valid, ovf = two_stage_sort_sharded(
-                local[b], outer_axis=ctx.axis_names[0],
-                inner_axis=ctx.axis_names[1], r1=ctx.sizes[0],
-                r2=ctx.sizes[1], rng=rng, hss_cfg=ctx.hss_cfg,
-                ex_cfg=ctx.ex_cfg)
-            outs.append(out), nvs.append(n_valid), ovfs.append(ovf)
-        batch = local.shape[0]
-        m = jnp.zeros((batch, 0), jnp.int32)
-        return (jnp.stack(outs), jnp.stack(nvs),
-                jnp.zeros((batch, 0), local.dtype), m, jnp.stack(ovfs),
-                null_stats_batched(batch))

@@ -46,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.analysis.contracts import CommsContract, register_contract
 from repro.core.common import hi_sentinel, lo_sentinel, round_up
-from repro.core.splitters import heavy_candidates
+from repro.core.splitters import BATCH_AXES, heavy_candidates
 from repro.core.tagging import (
     float32_to_sortable_int32, float64_to_sortable_int64,
     sortable_int32_to_float32, sortable_int64_to_float64)
@@ -234,46 +234,31 @@ def _heavy_sizing(spec: SortSpec, n_local: int, p: int):
 
 
 def _semisort_shard_fn(part, ctx, n_local, s_loc, min_count, max_heavy,
-                       ex_cfg, fallback, batch=None):
-    """Shard-resident semisort pipeline for `driver.run`/`run_batched`:
-    local sort -> heavy detection (all_gather'd regular sample ->
-    `heavy_candidates` -> exact psum counts) -> mask heavies to sentinel
-    -> light partition. `fallback` partitioners (multistage) own the whole
-    shard pipeline and take no n_valid, so their sentinel tail travels as
-    real max keys and the valid count is re-cut at the first sentinel."""
+                       ex_cfg, fallback):
+    """Shard-resident semisort pipeline for `driver.run` (and, mapped over
+    the batch, `run_batched`): local sort -> heavy detection (all_gather'd
+    regular sample -> `heavy_candidates` -> exact psum counts) -> mask
+    heavies to sentinel -> light partition. `fallback` partitioners
+    (multistage) own the whole shard pipeline and take no n_valid, so
+    their sentinel tail travels as real max keys and the valid count is
+    re-cut at the first sentinel."""
     spec = ctx.spec
     names = ctx.axis_names
     samp_idx = jnp.asarray((np.arange(s_loc) * n_local) // s_loc, jnp.int32)
-    if batch is None:
-        sort_local = (spec.local_sort_fn
-                      or dispatch.local_sort_fn(spec.kernel_policy))
-    else:
-        sort_local = (dispatch.local_sort_batched_fn(spec.kernel_policy)
-                      if spec.local_sort_fn is None
-                      else jax.vmap(spec.local_sort_fn))
+    sort_local = (spec.local_sort_fn
+                  or dispatch.local_sort_fn(spec.kernel_policy))
 
     def heavy_split(ls):
         sent = hi_sentinel(ls.dtype)
         samp = jnp.take(ls, samp_idx, axis=-1)
-        g = jax.lax.all_gather(samp, names)          # (p, s) | (p, B, s)
-        if batch is None:
-            pooled = jnp.sort(g.reshape(-1))
-            hkeys = heavy_candidates(pooled, max_heavy=max_heavy,
-                                     min_count=min_count)
-            llo = jnp.searchsorted(ls, hkeys, side="left")
-            lhi = jnp.searchsorted(ls, hkeys, side="right")
-            pos = jnp.clip(jnp.searchsorted(hkeys, ls), 0, max_heavy - 1)
-            member = jnp.take(hkeys, pos) == ls
-        else:
-            pooled = jnp.sort(
-                jnp.transpose(g, (1, 0, 2)).reshape(batch, -1), axis=-1)
-            hkeys = jax.vmap(lambda s: heavy_candidates(
-                s, max_heavy=max_heavy, min_count=min_count))(pooled)
-            ss = lambda side: jax.vmap(
-                lambda a, v: jnp.searchsorted(a, v, side=side))
-            llo, lhi = ss("left")(ls, hkeys), ss("right")(ls, hkeys)
-            pos = jnp.clip(ss("left")(hkeys, ls), 0, max_heavy - 1)
-            member = jnp.take_along_axis(hkeys, pos, axis=-1) == ls
+        g = jax.lax.all_gather(samp, names)          # (p, s)
+        pooled = jnp.sort(g.reshape(-1))
+        hkeys = heavy_candidates(pooled, max_heavy=max_heavy,
+                                 min_count=min_count)
+        llo = jnp.searchsorted(ls, hkeys, side="left")
+        lhi = jnp.searchsorted(ls, hkeys, side="right")
+        pos = jnp.clip(jnp.searchsorted(hkeys, ls), 0, max_heavy - 1)
+        member = jnp.take(hkeys, pos) == ls
         cnt = jnp.where(hkeys == sent, 0, lhi - llo).astype(jnp.int32)
         hcnt = jax.lax.psum(cnt, names)
         is_heavy = member & (ls != sent)
@@ -288,18 +273,12 @@ def _semisort_shard_fn(part, ctx, n_local, s_loc, min_count, max_heavy,
         sent = hi_sentinel(ls.dtype)
         hkeys, hcnt, lights, n_light = heavy_split(ls)
         if fallback:
-            run = part.sharded if batch is None else part.sharded_batched
-            out, n_out, keys, ranks, ovf, sstats = run(lights, rng, ctx)
-            if batch is None:
-                cut = jnp.searchsorted(out, sent).astype(jnp.int32)
-            else:
-                cut = jax.vmap(lambda a: jnp.searchsorted(a, sent))(
-                    out).astype(jnp.int32)
+            out, n_out, keys, ranks, ovf, sstats = part.sharded(
+                lights, rng, ctx)
+            cut = jnp.searchsorted(out, sent).astype(jnp.int32)
             n_out = jnp.minimum(jnp.asarray(n_out, jnp.int32), cut)
         else:
-            run = (part.partition_sorted if batch is None
-                   else part.partition_sorted_batched)
-            out, n_out, keys, ranks, ovf, sstats = run(
+            out, n_out, keys, ranks, ovf, sstats = part.partition_sorted(
                 lights, rng, ctx, n_valid=n_light, ex_cfg=ex_cfg)
         return out, n_out, keys, ranks, ovf, SemisortStats(sstats, hkeys,
                                                            hcnt)
@@ -316,14 +295,13 @@ def _semisort_fast(x, spec: SortSpec):
     plan = make_plan(x, spec, p)
     enc = plan.encode(x)
     batched = enc.ndim == 2
-    batch = enc.shape[0] if batched else None
     n_local = (plan.n + plan.n_pad) // p
     s_loc, min_count, max_heavy, out_extra = _heavy_sizing(spec, n_local, p)
     ctx = ShardCtx(spec=spec, axis_names=names, sizes=sizes, rng=None)
     ex_cfg = dataclasses.replace(spec.exchange_config(), out_extra=out_extra)
     fallback = type(part).sharded is not Partitioner.sharded
     shard_fn = _semisort_shard_fn(part, ctx, n_local, s_loc, min_count,
-                                  max_heavy, ex_cfg, fallback, batch=batch)
+                                  max_heavy, ex_cfg, fallback)
     base = _cache_key(spec, names, sizes, enc,
                       kind="batched" if batched else "single")
     cache_key = (None if base is None
@@ -334,7 +312,8 @@ def _semisort_fast(x, spec: SortSpec):
         raw = driver.run_batched(
             shard_fn, enc, mesh=spec.mesh, axis_names=names, sizes=sizes,
             seed=spec.seed, n_real=plan.n, local_sort_fn=p1_sort,
-            cache_key=cache_key)
+            cache_key=cache_key,
+            stats_axes=SemisortStats(BATCH_AXES, 0, 0))
         light = plan.decode_batched(raw)
     else:
         p1_sort = (spec.local_sort_fn
@@ -353,7 +332,7 @@ def _semisort_fast(x, spec: SortSpec):
         return cls.deferred(light, stats.heavy_keys, stats.heavy_counts,
                             plan.n, plan._decode_keys)
     # p == 1 short-circuit: fully sorted output, nothing was split
-    lead = (batch, 0) if batched else (0,)
+    lead = (enc.shape[0], 0) if batched else (0,)
     hk = np.zeros(lead, x.dtype)
     hc = np.zeros(lead, np.int32)
     if batched:
@@ -482,26 +461,23 @@ def topk_program(mesh_plan, n_local: int, c: int, k: int,
     prunes to its top-c local suffix (threshold pruning: a key below the
     local (n_local - c)-rank cannot be in the global top k <= c), then ONE
     all_gather of (p, c) suffixes feeds a replicated merge. No all_to_all,
-    and the gather moves p*c keys instead of the full-sort exchange's N."""
+    and the gather moves p*c keys instead of the full-sort exchange's N.
+    With `batch`, the program maps that per-shard function over the
+    (batch, n_local) rows of each shard."""
     p = mesh_plan.p
     names = mesh_plan.axis_names
 
     def per_shard(block):
-        if batch is None:
-            ls = dispatch.local_sort(block.reshape(-1), policy=kernel_policy)
-            g = jax.lax.all_gather(ls[n_local - c:], names)      # (p, c)
-            merged = dispatch.merge_runs(g, policy=kernel_policy)
-            return merged[p * c - k:][::-1]
-        ls = dispatch.local_sort_batched(block.reshape(batch, n_local),
-                                         policy=kernel_policy)
-        g = jax.lax.all_gather(ls[:, n_local - c:], names)       # (p, B, c)
-        merged = dispatch.merge_runs_batched(jnp.transpose(g, (1, 0, 2)),
-                                             policy=kernel_policy)
-        return merged[:, p * c - k:][:, ::-1]
+        ls = dispatch.local_sort(block.reshape(-1), policy=kernel_policy)
+        g = jax.lax.all_gather(ls[n_local - c:], names)      # (p, c)
+        merged = dispatch.merge_runs(g, policy=kernel_policy)
+        return merged[p * c - k:][::-1]
 
-    in_specs = (P(*names) if batch is None else P(None, *names),)
-    return shard_map(per_shard, mesh=mesh_plan.mesh, in_specs=in_specs,
-                     out_specs=P())
+    if batch is None:
+        return shard_map(per_shard, mesh=mesh_plan.mesh,
+                         in_specs=(P(*names),), out_specs=P())
+    return shard_map(jax.vmap(per_shard), mesh=mesh_plan.mesh,
+                     in_specs=(P(None, *names),), out_specs=P())
 
 
 # The wire contract of `topk_program`, proven by the analysis lint on every

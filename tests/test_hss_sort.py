@@ -1,4 +1,6 @@
 """End-to-end distributed HSS sort correctness on host devices."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -185,8 +187,7 @@ def test_dense_exchange_matches_send_side_model(rng, strategy, split):
     excess, dense_spill carries it on its spill channel)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.exchange import (exchange_dense, exchange_dense_batched,
-                                     exchange_dense_spill)
+    from repro.core.exchange import exchange_dense, exchange_dense_spill
     from repro.parallel.compat import shard_map
 
     p, n, rows = 4, 256, 2 if strategy == "dense_batched" else 1
@@ -202,13 +203,13 @@ def test_dense_exchange_matches_send_side_model(rng, strategy, split):
     cap = n if strategy == "dense_spill" else cfg.pair_cap(n, p)
     out_cap = cfg.out_cap(n, p, 0.05)
     fn = {"dense": exchange_dense, "dense_spill": exchange_dense_spill,
-          "dense_batched": exchange_dense_batched}[strategy]
+          "dense_batched": jax.vmap(functools.partial(
+              exchange_dense, axis_name="sort", p=p, cfg=cfg, eps=0.05,
+              n_valid=n_valid))}[strategy]
 
     def body(shard, spl):
         if strategy == "dense_batched":
-            return tuple(v[None] for v in fn(
-                shard[0], spl, axis_name="sort", p=p, cfg=cfg, eps=0.05,
-                n_valid=n_valid))
+            return tuple(v[None] for v in fn(shard[0], spl))
         return tuple(v[None] for v in fn(
             shard[0, 0], spl[0], axis_name="sort", p=p, cfg=cfg, eps=0.05,
             n_valid=n_valid))

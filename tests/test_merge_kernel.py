@@ -5,6 +5,8 @@ the same entries (sentinel padding included), across dtypes, degenerate run
 shapes, and both the equal-capacity and ragged layouts — plus the dispatch
 layer that selects between the kernels and the XLA primitives.
 """
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -296,11 +298,10 @@ def test_batched_merge_ragged_matches_oracle(rng, slot, spills):
         interpret=True)
     assert spills == any(max(c) > slot for c in per_row)
     np.testing.assert_array_equal(np.asarray(got), np.sort(buf, axis=1))
-    # dispatch wrapper parity against the XLA path
-    np.testing.assert_array_equal(
-        np.asarray(dispatch.merge_ragged_batched(
-            jnp.asarray(buf), jnp.stack(starts), jnp.stack(cnts),
-            policy="pallas", slot=slot)),
-        np.asarray(dispatch.merge_ragged_batched(
-            jnp.asarray(buf), jnp.stack(starts), jnp.stack(cnts),
-            policy="xla")))
+    # the dispatch wrapper under vmap, as a batched launch runs it:
+    # parity against the XLA path
+    merged = {policy: np.asarray(jax.vmap(functools.partial(
+        dispatch.merge_ragged, policy=policy, slot=slot))(
+            jnp.asarray(buf), jnp.stack(starts), jnp.stack(cnts)))
+        for policy in ("pallas", "xla")}
+    np.testing.assert_array_equal(merged["pallas"], merged["xla"])
